@@ -19,9 +19,22 @@ Spark-semantics fidelity (LongType/DoubleType/Decimal/hash parity) requires
 kernels deliberately stay in 32-bit lanes where Spark semantics allow.
 """
 
+import os as _os
+
 import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
+
+#: Persistent compile cache. `JAX_COMPILATION_CACHE_DIR` places it from
+#: outside (jax reads the variable itself; nothing is set here). Unset,
+#: it is ONE fixed directory in the checkout: the path is part of the
+#: cache key, so never a temp dir, a pid or the time.
+DEFAULT_COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache")
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update("jax_compilation_cache_dir",
+                       DEFAULT_COMPILE_CACHE_DIR)
 
 from . import types  # noqa: E402
 from .columnar.column import Column, StringColumn, bucket_capacity  # noqa: E402

@@ -57,6 +57,29 @@ def _expand0(tree):
     return jax.tree_util.tree_map(lambda x: x[None], tree)
 
 
+#: where the collective steps' output shards sat, process-cumulative:
+#: device id -> start of that shard's slice of the leading (device) axis.
+#: Uploads and `Column.from_numpy` place on device 0, so this is how a run
+#: on real chips shows an exchange left it (chip_smoke.py --chips 4).
+_PLACEMENT = {"exchanges": 0, "shards": {}}
+
+
+def _note_exchange_placement(out) -> None:
+    leaf = jax.tree_util.tree_leaves(out)[0]
+    _PLACEMENT["exchanges"] += 1
+    _PLACEMENT["shards"].update(
+        (s.device.id, s.index[0].start or 0)
+        for s in leaf.addressable_shards)
+
+
+def exchange_placement(reset: bool = False) -> dict:
+    out = {"exchanges": _PLACEMENT["exchanges"],
+           "shards": dict(_PLACEMENT["shards"])}
+    if reset:
+        _PLACEMENT["shards"].clear()
+    return out
+
+
 def _host_key_array(col, n: int, idx=None):
     """Vectorized host materialization of a range-partition sort key
     (ISSUE 9 satellite): fixed-width columns become an object array via
@@ -216,6 +239,7 @@ class ShuffleExchangeExec(TpuExec):
         width = max(8, (int(max_len) + 7) // 8 * 8)
 
         out = self._get_step(cap, slot_cap, width)(stacked)
+        _note_exchange_placement(out)
         import numpy as _np
         return list(unstack_batches(out, n)), _np.asarray(totals)
 
@@ -1114,6 +1138,7 @@ class HostShuffleExchangeExec(TpuExec):
                 self._ici_width_hint, (int(max_len) + 7) // 8 * 8)
             width = self._ici_width_hint
             out = self._get_ici_step(cap, slot_cap, width)(stacked, rr)
+            _note_exchange_placement(out)
             shards = unstack_batches(out, n)
         collective_ns = _time.perf_counter_ns() - t0
         per_map = _np.asarray(per_map)

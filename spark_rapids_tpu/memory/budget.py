@@ -17,7 +17,7 @@ from typing import Optional
 from ..config import HBM_BUDGET_BYTES, HBM_POOL_FRACTION, active_conf
 from .retry import TpuRetryOOM
 
-_DEFAULT_HBM = 16 << 30  # v5e/v5p chips have 16 GiB HBM per core
+_DEFAULT_HBM = 16 << 30  # CPU backend only: stands for one v5e chip's HBM
 
 
 class MemoryBudget:
@@ -126,15 +126,19 @@ class MemoryBudget:
 
 
 def _detect_hbm() -> int:
-    try:
-        import jax
-        dev = jax.devices()[0]
-        stats = dev.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return _DEFAULT_HBM
+    """HBM of device 0 as the runtime reports it. The constant stands in
+    on the CPU backend only (no device memory to size against); a TPU
+    that does not report `bytes_limit` is an error, not 16 GiB."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return _DEFAULT_HBM
+    stats = dev.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no "
+            f"bytes_limit in memory_stats(): {stats!r}")
+    return int(stats["bytes_limit"])
 
 
 _budget: Optional[MemoryBudget] = None

@@ -31,7 +31,11 @@ class DeviceManager:
             if self.initialized:
                 return self
             devices = jax.devices()
-            self.device = devices[min(device_ordinal, len(devices) - 1)]
+            if not 0 <= device_ordinal < len(devices):
+                raise ValueError(
+                    f"device ordinal {device_ordinal} out of range: "
+                    f"{len(devices)} device(s) visible")
+            self.device = devices[device_ordinal]
             memory_budget()  # force budget sizing against this device
             reset_tpu_semaphore()
             if mesh_axes:

@@ -116,10 +116,14 @@ class _Pending:
     """Per-call trace capture: the traced function body sets these when
     jax actually traces (on a cache hit it never runs)."""
 
-    __slots__ = ("traced", "trace_ns", "donated", "retained", "depth")
+    __slots__ = ("traced", "trace_ns", "donated", "retained", "depth",
+                 "inlined")
 
     def __init__(self):
         self.traced = False
+        #: labels of instrumented programs traced INLINE into this one
+        #: (the murmur3 Pallas kernels inside a join's build program)
+        self.inlined: set = set()
         self.trace_ns = 0
         self.donated = 0
         self.retained = 0
@@ -137,7 +141,7 @@ class ProgramStats:
     __slots__ = ("label", "bucket", "platform", "dispatches", "traces",
                  "cache_hits", "compile_ns", "trace_ns", "donated_bytes",
                  "retained_bytes", "trace_times", "storms",
-                 "storm_open_until")
+                 "storm_open_until", "inlined")
 
     def __init__(self, label: str, bucket, platform: str):
         self.label = label
@@ -155,6 +159,9 @@ class ProgramStats:
         self.storms = 0
         #: suppress repeat storm events until the window rolls past
         self.storm_open_until = 0
+        #: instrumented programs this one's traces inlined — how a run
+        #: shows WHICH kernel tier a program was built from
+        self.inlined: set = set()
 
     def to_dict(self) -> Dict[str, Any]:
         return {"label": self.label, "bucket": list(self.bucket),
@@ -165,7 +172,8 @@ class ProgramStats:
                 "trace_ns": self.trace_ns,
                 "donated_bytes": self.donated_bytes,
                 "retained_bytes": self.retained_bytes,
-                "storms": self.storms}
+                "storms": self.storms,
+                "inlined": sorted(self.inlined)}
 
 
 class DispatchLedger:
@@ -234,6 +242,7 @@ class DispatchLedger:
                 prog.traces += 1
                 prog.compile_ns += wall_ns
                 prog.trace_ns += pend.trace_ns
+                prog.inlined |= pend.inlined
                 # arg bytes are a per-program-shape PROPERTY, not a
                 # counter: the latest trace's aval sizes (re-traces
                 # inside one bucket differ only marginally)
@@ -508,13 +517,16 @@ def reset_site_cache() -> None:
         _site_cache_misses = 0
 
 
-def _trace_state_clean() -> bool:
-    """Resolved once — the per-dispatch path must not pay import
-    machinery (jax is necessarily imported before any site is built)."""
-    global _trace_state_clean
+def _no_trace_in_progress() -> bool:
+    """True when no trace is in progress on this thread: a call made
+    while another program is being traced (jit, eval_shape, a pallas
+    kernel body) is inlined into that trace, not dispatched."""
+    global _no_trace_in_progress
     import jax.core
-    _trace_state_clean = jax.core.trace_state_clean
-    return _trace_state_clean()
+    # Bound method resolved once — the per-dispatch path must not pay
+    # import machinery (jax is imported before any site is built).
+    _no_trace_in_progress = jax.core.trace_ctx.is_top_level
+    return _no_trace_in_progress()
 
 
 class InstrumentedJit:
@@ -621,10 +633,12 @@ class InstrumentedJit:
         led = _ledger
         if led is None:
             return self._jit(*args, **kwargs)
-        if getattr(_tls, "pending", None) is not None:
+        pend = getattr(_tls, "pending", None)
+        if pend is not None:
             # nested under another instrumented dispatch's trace
+            pend.inlined.add(self.label)
             return self._jit(*args, **kwargs)
-        if not _trace_state_clean():
+        if not _no_trace_in_progress():
             # traced inline into an un-instrumented outer program, or
             # abstractly evaluated (eval_shape) — not a device dispatch
             return self._jit(*args, **kwargs)

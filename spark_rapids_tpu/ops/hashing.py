@@ -67,19 +67,25 @@ def _use_pallas() -> bool:
     entry for the `murmur3` family, ISSUE 8) demotes NEW traces to the
     XLA formulation like the fused-tier families."""
     from ..config import PALLAS_ENABLED, active_conf
+    from ..parallel.mesh import active_mesh
     from .pallas_kernels import on_tpu
-    try:
-        if not (on_tpu() and active_conf().get(PALLAS_ENABLED)):
-            return False
-        # one implementation of breaker-consult + engagement noting
-        # (shared with the fused-tier families)
-        from .pallas_tier import _breaker_allows, _note_engaged
-        if not _breaker_allows("murmur3"):
-            return False
-        _note_engaged("murmur3")
-        return True
-    except Exception:  # noqa: BLE001 — conf unavailable during early init
+    if not (on_tpu() and active_conf().get(PALLAS_ENABLED)):
         return False
+    # A mesh session's per-partition programs take shards of mesh-sharded
+    # arrays, so XLA partitions them — and "Mosaic kernels cannot be
+    # automatically partitioned" (the four-chip run of PR 23 died in the
+    # shuffled join's build). Until shards are single-device arrays, a
+    # mesh of more than one device hashes with the XLA formulation.
+    mesh = active_mesh()
+    if mesh is not None and mesh.size > 1:
+        return False
+    # one implementation of breaker-consult + engagement noting
+    # (shared with the fused-tier families)
+    from .pallas_tier import _breaker_allows, _note_engaged
+    if not _breaker_allows("murmur3"):
+        return False
+    _note_engaged("murmur3")
+    return True
 
 
 def murmur3_int(v, seed):

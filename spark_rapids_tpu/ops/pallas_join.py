@@ -126,7 +126,7 @@ def fused_probe_verify(lo, counts, bk_lanes, bvalid, sk_lanes, svalid,
     over the flat candidate layout of `expand_candidates` — bit-identical
     to the XLA expand+verify pipeline for integer keys.
     """
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from .join import candidate_fill_inputs

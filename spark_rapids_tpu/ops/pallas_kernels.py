@@ -122,7 +122,7 @@ _tile_spec = tile_spec
                    static_argnames=("interpret",))
 def murmur3_long_lanes(data_i64, seeds_u32, interpret: bool = False):
     """Per-row murmur3 update over int64 lanes; seeds/result uint32."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from jax.experimental import pallas as pl
 
     pair = jax.lax.bitcast_convert_type(
@@ -150,7 +150,7 @@ def murmur3_long_lanes(data_i64, seeds_u32, interpret: bool = False):
 @functools.partial(_instrument, label="pallas.murmur3_int",
                    static_argnames=("interpret",))
 def murmur3_int_lanes(data_i32, seeds_u32, interpret: bool = False):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from jax.experimental import pallas as pl
 
     w, n = _pad_to_tiles(jax.lax.bitcast_convert_type(
@@ -172,7 +172,7 @@ def murmur3_int_lanes(data_i32, seeds_u32, interpret: bool = False):
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001 — backend probe only
-        return False
+    """Is the default device a TPU? A backend that cannot start raises
+    here — answering False would send every Pallas call site to
+    interpret mode and hide the failure."""
+    return jax.devices()[0].platform == "tpu"

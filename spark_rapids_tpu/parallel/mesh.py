@@ -19,17 +19,10 @@ DATA_AXIS = "data"
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: newer jax exposes top-level
-    `jax.shard_map` (replication check kwarg `check_vma`); 0.4.x — this
-    image — has `jax.experimental.shard_map.shard_map` (`check_rep`).
-    The engine disables the replication check either way (exchange
-    bodies intentionally produce per-shard-distinct outputs)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """`jax.shard_map` with the replication check off (exchange bodies
+    intentionally produce per-shard-distinct outputs)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def device_mesh(n_devices: Optional[int] = None,

@@ -80,6 +80,23 @@ def test_nested_instrumented_call_is_not_a_second_dispatch():
     assert dispatch.counters()["dispatches"] == 1
 
 
+def test_ledger_records_what_a_program_inlined():
+    """Each outer trace names the instrumented programs it inlined — on
+    every trace, not only the first (jax caches the inner jit's trace,
+    the ledger must not depend on it). This is how a run shows which
+    kernel tier a program was built from (chip_smoke.py's join-hash
+    proof)."""
+    inner = dispatch.instrument(lambda x: x + 1, label="t.kernel")
+    a = dispatch.instrument(lambda x: inner(x) * 2, label="t.build")
+    b = dispatch.instrument(lambda x: inner(x) * 3, label="t.probe")
+    plain = dispatch.instrument(lambda x: x * 5, label="t.plain")
+    x = jnp.arange(64, dtype=jnp.int32)
+    a(x), b(x), plain(x), a(x)
+    inlined = {p["label"]: p["inlined"] for p in dispatch.programs()}
+    assert inlined == {"t.build": ["t.kernel"], "t.probe": ["t.kernel"],
+                       "t.plain": []}
+
+
 def test_eval_shape_is_not_a_dispatch():
     site = dispatch.instrument(lambda x: x * 2, label="t.abstract")
     out = jax.eval_shape(site, jax.ShapeDtypeStruct((16,), jnp.int32))
