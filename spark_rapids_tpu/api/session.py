@@ -558,12 +558,14 @@ class DataFrame:
         from ..exec import lifecycle
         from ..exec.task_metrics import query_snapshot, query_summary
         from ..obs import events as obs_events
+        from ..obs import op_span
         from ..obs.profile import QueryProfile
         from ..obs.stats import RuntimeStats
         with obs_events.query_scope() as qid:
             # conversion inside the scope: plan_fallback / plan_not_on_tpu
             # events must carry this query's id
-            plan = self._exec()
+            with op_span("session.plan", phase="plan"):
+                plan = self._exec()
             # runtime statistics + live progress (ISSUE 11): a fresh
             # RuntimeStats per attempt (a failed attempt's partial
             # distributions must not pollute the retry's), and the root

@@ -87,12 +87,13 @@ class ColumnarBatch:
         (default), one per buffer otherwise. `fault_key` is the batch's
         chaos work-item key (the scan chunk offset)."""
         from ..types import from_arrow as type_from_arrow
+        from ..obs import op_span
         from .column import host_build
         from .upload import to_device_batch
         n = table.num_rows
         cap = bucket_capacity(n)
         fields, cols = [], []
-        with host_build():
+        with op_span("upload.pack", phase="upload"), host_build():
             for name in table.column_names:
                 arr = table.column(name)
                 col = column_from_arrow(arr)

@@ -115,6 +115,7 @@ def _pack_impl(batch) -> jnp.ndarray:
     return jnp.concatenate(pieces)
 
 
+from ..obs import op_span
 from ..obs.dispatch import instrument as _instrument
 
 _pack_jit = _instrument(_pack_impl, label="transfer.pack_batch")
@@ -236,8 +237,11 @@ def fetch_batch_host(batch) -> Tuple[List[Column], int]:
     if batch._host_rows is not None and all(
             isinstance(x, np.ndarray) for x in leaves):
         return list(batch.columns), batch._host_rows
-    packed = _pack_jit(batch)
-    buf = np.asarray(packed)  # the single transfer
+    # the one place the driving thread waits for the device: every
+    # program the query enqueued has to finish before these bytes exist
+    with op_span("result.fetch", phase="device-wait"):
+        packed = _pack_jit(batch)
+        buf = np.asarray(packed)  # the single transfer
     note_d2h(buf.nbytes)
     n = int(buf[:4].view(np.int32)[0])
     pos = 4

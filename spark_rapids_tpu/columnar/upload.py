@@ -59,6 +59,7 @@ from .column import (ArrayColumn, Column, Decimal128Column, MapColumn,
                      StringColumn, StructColumn)
 from .encoded import DictionaryColumn
 from . import transfer as _transfer
+from ..obs import op_span
 
 __all__ = [
     "StagingPool", "staging_pool", "reset_staging_pool", "counters",
@@ -698,14 +699,16 @@ def packed_upload_batch(cols: Sequence[Column], n: int, schema,
     dd = _transfer._dd_split()
     specs = tuple(_col_spec(c) for c in cols)
     pool = staging_pool()
-    buf, total = pack_host_batch(cols, n, pool, specs=specs)
+    with op_span("upload.pack", phase="upload"):
+        buf, total = pack_host_batch(cols, n, pool, specs=specs)
     try:
         # ship only the used bytes, not the pool bucket: the bucket can
         # be ~2x the payload, and on a remote-attached link that halves
         # effective ingest bandwidth (the specs fix `total`, so the
         # unpack still traces once per layout — the view adds no keys)
-        dev = _one_transfer(buf[:total], fault_key)
-        num_rows, out_cols = _unpack_batch_jit(dev, specs, dd)
+        with op_span("upload.put", phase="upload"):
+            dev = _one_transfer(buf[:total], fault_key)
+            num_rows, out_cols = _unpack_batch_jit(dev, specs, dd)
     except BaseException:
         pool.discard(buf)
         raise
@@ -729,16 +732,18 @@ def _per_buffer_batch(cols: Sequence[Column], n: int, schema,
     transfers = 0
     nbytes = 0
     dev_leaves = []
-    for leaf in leaves:
-        if isinstance(leaf, np.ndarray):
-            transfers += 1
-            nbytes += leaf.nbytes
-            dev_leaves.append(jnp.asarray(leaf))
-        else:
-            # already on device, or an unregistered-pytree column that
-            # flattened as one opaque leaf — pass through untouched
-            # (exactly the pre-ISSUE-10 behavior for such trees)
-            dev_leaves.append(leaf)
+    with op_span("upload.put", phase="upload"):
+        for leaf in leaves:
+            if isinstance(leaf, np.ndarray):
+                transfers += 1
+                nbytes += leaf.nbytes
+                dev_leaves.append(jnp.asarray(leaf))
+            else:
+                # already on device, or an unregistered-pytree column
+                # that flattened as one opaque leaf — pass through
+                # untouched (exactly the pre-ISSUE-10 behavior for such
+                # trees)
+                dev_leaves.append(leaf)
     out_cols = jax.tree_util.tree_unflatten(treedef, dev_leaves)
     batch = ColumnarBatch(out_cols, n, schema)  # +1: the row-count scalar
     _record("per_buffer", seam, nbytes, n, len(cols), transfers + 1,

@@ -389,17 +389,6 @@ SPILL_ASYNC_WRITE = conf_bool(
     "until its writeback completes, so results are identical with the "
     "writer on or off. False restores fully synchronous spilling.")
 
-PROFILE_ENABLED = conf_bool(
-    "spark.rapids.tpu.profile.enabled", False,
-    "Capture jax profiler traces (xprof/TensorBoard) around driven "
-    "queries; operator names appear as trace annotations over their XLA "
-    "ops (reference spark.rapids.profile.* NVTX integration).")
-
-PROFILE_DIR = conf_str(
-    "spark.rapids.tpu.profile.dir", "",
-    "Output directory for captured profiler traces; empty = "
-    "/tmp/spark_rapids_tpu_trace.")
-
 METRICS_LEVEL = conf_str(
     "spark.rapids.sql.metrics.level", "MODERATE",
     "ESSENTIAL | MODERATE | DEBUG (reference GpuExec.scala:36-47): "

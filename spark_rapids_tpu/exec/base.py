@@ -556,6 +556,7 @@ class TpuExec:
         run their fast masked-bucket tier and flag overflow on device; the
         flag costs one extra host read here, and a trip re-runs the plan
         with every operator on its exact tier."""
+        from ..obs import op_span
         from .speculation import force_exact, speculation_scope
 
         # late materialization (ISSUE 18): collect consumes root batches
@@ -572,7 +573,10 @@ class TpuExec:
 
         with speculation_scope() as scope:
             out = run()
-            if scope.tripped():
+            # the flag's host read waits for the device like a fetch
+            with op_span("result.fetch", phase="device-wait"):
+                tripped = scope.tripped()
+            if tripped:
                 with force_exact():
                     out = run()
         return out

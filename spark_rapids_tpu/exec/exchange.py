@@ -35,6 +35,7 @@ from ..parallel.mesh import DATA_AXIS, active_mesh, mesh_axis_size
 from ..types import Schema
 from ..obs import events as obs_events
 from ..obs import phase as obs_phase
+from ..obs import op_span
 from ..obs.dispatch import instrument
 from .base import (BROADCAST_TIME, DEBUG, DISPATCH_METRICS, ESSENTIAL,
                    GATHER_METRICS,
@@ -807,8 +808,9 @@ class HostShuffleExchangeExec(TpuExec):
                 # host-pack/serialize except the writer's file-IO share,
                 # which the nested add() carves out as shuffle-io (and
                 # the span excludes from its own exclusive time)
-                with self.metrics[SHUFFLE_WRITE_TIME].ns_timer(), \
-                        obs_phase.span("host-pack-serialize"):
+                with op_span("exchange.map_write",
+                             phase="host-pack-serialize",
+                             metric=self.metrics[SHUFFLE_WRITE_TIME]):
                     writer, lane, pack_ns, rows_pp = self._write_map(
                         b, n, bounds, handle, mgr, map_id)
                     obs_phase.add("shuffle-io", writer.io_ns)
@@ -1121,7 +1123,7 @@ class HostShuffleExchangeExec(TpuExec):
         # a wedged all-to-all degrades to the host lane like any other
         # classified-transient round failure (ISSUE 20)
         from . import speculation_shield
-        with obs_phase.span("ici-collective"), \
+        with op_span("exchange.ici_round", phase="ici-collective"), \
                 speculation_shield.dispatch_domain("ici_exchange"), \
                 self.batch_harness(fault_point="shuffle.ici_exchange",
                                    fault_key=f"r{round_idx}",

@@ -95,19 +95,36 @@ def threaded_chunks(tasks: Sequence[Callable[[], "object"]],
     object-store hiccup, an injected `io.multifile_read` fault — backs
     off and re-reads instead of killing the scan."""
     from .retrying import with_io_retry
+    from ..exec import lifecycle
     from ..obs import events as obs_events
+    from ..obs import op_span
     conf = active_conf()  # captured HERE: pool threads see default conf
     # the query id too (ISSUE 12): the shared pool serves every query,
     # so io_retry events from a decode task must carry the SUBMITTING
     # thread's attribution, not the pool thread's empty TLS
     qid = obs_events.current_query_id()
+    # and the lifecycle context, per job like the query id: it carries
+    # the query's phase ledger, so a pool thread's scan-decode time
+    # reaches the query's books (folded) and not only the global ones
+    lctx = lifecycle.current_context()
+
+    def decode(t: Callable[[], "object"], i: int) -> "object":
+        prev = lifecycle.current_context()
+        lifecycle.adopt_context(lctx)
+        try:
+            # the span covers every attempt: a retry's backoff and
+            # re-read are time this chunk's decode took
+            with op_span("scan.decode", phase="scan-decode"):
+                # per-chunk jitter salt: concurrent decode tasks on one
+                # flaky mount must not back off in lockstep
+                return with_io_retry(
+                    t, "multifile_read", conf=conf,
+                    fault_point="io.multifile_read", salt=str(i))
+        finally:
+            lifecycle.adopt_context(prev)
 
     def retrying(t: Callable[[], "object"], i: int) -> "object":
-        # per-chunk jitter salt: concurrent decode tasks on one flaky
-        # mount must not back off in lockstep
-        return obs_events.with_query_id(
-            qid, with_io_retry, t, "multifile_read", conf=conf,
-            fault_point="io.multifile_read", salt=str(i))
+        return obs_events.with_query_id(qid, decode, t, i)
 
     if num_threads <= 1 or len(tasks) <= 1:
         for i, t in enumerate(tasks):

@@ -6,9 +6,16 @@ GpuMetric/GpuTaskMetrics/NVTX stack joined into one subsystem (ISSUE 2):
     plan fallbacks, exchange volumes), gated by the
     spark.rapids.tpu.eventLog.{enabled,dir,level} confs and costing one
     pointer check per batch when disabled.
-  * `span` — op_span(): the NvtxWithMetrics analog — one context manager
-    that emits the xprof TraceAnnotation, bumps a TpuMetric, and appends
-    an event record.
+  * `span` — op_span(name, phase=, metric=): the ONE span primitive —
+    one context manager that writes the host event into the profiler's
+    trace (TraceAnnotation: the device trace's clock, any thread),
+    accrues the block's exclusive time to a wall-clock phase, bumps a
+    TpuMetric, and appends a DEBUG `span` event record. The ingest
+    path's boundaries open it: `session.plan` (phase `plan`),
+    `scan.decode` (`scan-decode`), `upload.pack` / `upload.put`
+    (`upload`), `result.fetch` (`device-wait`).
+  * `phase` — the closed wall-clock phase ledger under `op_span`
+    (ISSUE 17): `sum(phases) == wall_ns` per governed query.
   * `profile` — QueryProfile: the executed plan tree annotated with
     per-operator metrics, with text (explain-with-metrics) and JSON
     renderers plus `.statistics()`; surfaced as
@@ -29,7 +36,10 @@ GpuMetric/GpuTaskMetrics/NVTX stack joined into one subsystem (ISSUE 2):
     and donated/retained bytes; emits `program_compile` per fresh trace
     and `recompile_storm` on shape-bucket churn. The whole-stage-
     compilation baseline (ROADMAP 2) reads
-    QueryProfile.dispatch_summary() on top of it.
+    QueryProfile.dispatch_summary() on top of it. Each program record
+    names the XLA `module` it carries in a device trace, and
+    `dispatch.module_labels()` maps module -> labels, so device time
+    joins to the engine's own names.
 
 Render an event-log file with tools/profile_report.py (`--format json`
 for the machine-readable summary) and telemetry samples with

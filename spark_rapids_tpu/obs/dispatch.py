@@ -49,6 +49,7 @@ computation.
 from __future__ import annotations
 
 import functools
+import re
 import threading
 import time
 from collections import deque
@@ -59,7 +60,7 @@ __all__ = [
     "DispatchLedger", "InstrumentedJit", "instrument", "active_ledger",
     "configure", "reset_dispatch_ledger", "counters", "programs",
     "health_section", "metric_scope", "site_cache_counters",
-    "reset_site_cache",
+    "reset_site_cache", "module_labels",
 ]
 
 #: canonical per-exec metric names (exec/base.py re-exports them into
@@ -68,6 +69,18 @@ NUM_DISPATCHES = "numDispatches"
 COMPILE_TIME = "compileTimeNs"
 
 _tls = threading.local()
+
+#: jax's own rule for a module name (interpreters/mlir.sanitize_name)
+_NOT_IN_MODULE_NAME = re.compile(r"[^\w.-]")
+
+
+def _module_name(fn_name: str) -> str:
+    """The XLA module name of `jax.jit(fn)`, as JAX spells it and a
+    device trace shows it: `jit(<name>)` with every character XLA would
+    alter replaced and trailing underscores dropped — `_concat_pair`
+    reads `jit__concat_pair`, `<lambda>` reads `jit__lambda`."""
+    return _NOT_IN_MODULE_NAME.sub("_", f"jit({fn_name})").rstrip("_")
+
 
 #: backend platform, resolved once (it cannot change in-process)
 _platform_cache: Optional[str] = None
@@ -138,15 +151,19 @@ class ProgramStats:
 
     # counters accumulate; donated/retained_bytes hold the LATEST
     # trace's aval sizes (a shape property, not a running total)
-    __slots__ = ("label", "bucket", "platform", "dispatches", "traces",
-                 "cache_hits", "compile_ns", "trace_ns", "donated_bytes",
-                 "retained_bytes", "trace_times", "storms",
-                 "storm_open_until", "inlined")
+    __slots__ = ("label", "bucket", "platform", "module", "dispatches",
+                 "traces", "cache_hits", "compile_ns", "trace_ns",
+                 "donated_bytes", "retained_bytes", "trace_times",
+                 "storms", "storm_open_until", "inlined")
 
-    def __init__(self, label: str, bucket, platform: str):
+    def __init__(self, label: str, bucket, platform: str, module: str):
         self.label = label
         self.bucket = bucket
         self.platform = platform
+        #: the XLA module name this program carries in a device trace
+        #: (InstrumentedJit.module) — the join key from device time to
+        #: this label
+        self.module = module
         self.dispatches = 0
         self.traces = 0
         self.cache_hits = 0
@@ -165,7 +182,7 @@ class ProgramStats:
 
     def to_dict(self) -> Dict[str, Any]:
         return {"label": self.label, "bucket": list(self.bucket),
-                "platform": self.platform,
+                "platform": self.platform, "module": self.module,
                 "dispatches": self.dispatches, "traces": self.traces,
                 "cache_hits": self.cache_hits,
                 "compile_ns": self.compile_ns,
@@ -235,7 +252,8 @@ class DispatchLedger:
         with self._lock:
             prog = self._programs.get(key)
             if prog is None:
-                prog = self._programs[key] = ProgramStats(*key)
+                prog = self._programs[key] = ProgramStats(*key,
+                                                          site.module)
             prog.dispatches += 1
             self._dispatches += 1
             if pend.traced:
@@ -330,6 +348,14 @@ class DispatchLedger:
         with self._lock:
             return [p.to_dict() for p in self._programs.values()]
 
+    def module_labels(self) -> Dict[str, List[str]]:
+        with self._lock:
+            pairs = {(p.module, p.label) for p in self._programs.values()}
+        out: Dict[str, List[str]] = {}
+        for module, label in sorted(pairs):
+            out.setdefault(module, []).append(label)
+        return out
+
 
 def _timed_dispatch(site: "InstrumentedJit", args, kwargs,
                     pend: _Pending, timeout_ms: int):
@@ -418,6 +444,16 @@ def counters() -> Dict[str, int]:
 def programs() -> List[Dict[str, Any]]:
     led = _ledger
     return led.programs() if led is not None else []
+
+
+def module_labels() -> Dict[str, List[str]]:
+    """{XLA module name: sorted ledger labels} of every program the
+    ledger has seen: how a device trace's `XLA Modules` events (named
+    `<module>(<fingerprint>)`) join to the engine's own names. One
+    module can serve several labels (two sites jitting functions of one
+    name); device time outside this map bypassed `instrument`."""
+    led = _ledger
+    return led.module_labels() if led is not None else {}
 
 
 def health_section() -> Dict[str, Any]:
@@ -541,8 +577,8 @@ class InstrumentedJit:
 
     # __weakref__: jax.eval_shape weakly caches the callable it is
     # given — an un-weakref-able wrapper would reject abstract eval
-    __slots__ = ("label", "_owner", "_jit", "_donate", "_seen_buckets",
-                 "__weakref__")
+    __slots__ = ("label", "module", "_owner", "_jit", "_donate",
+                 "_seen_buckets", "__weakref__")
 
     def __init__(self, fn, label: str, owner=None, **jit_kwargs):
         import jax
@@ -575,6 +611,9 @@ class InstrumentedJit:
                 pend.donated, pend.retained = self._arg_bytes(a, k)
             return out
 
+        #: the XLA module name of this site's programs in a device
+        #: trace (functools.wraps gave `_traced` the name of `fn`)
+        self.module = _module_name(_traced.__name__)
         self._jit = jax.jit(_traced, **jit_kwargs)
         if owner is not None:
             # per-exec site registry: QueryProfile._node records these

@@ -11,7 +11,11 @@ pack/serialize/io, the ICI lane times its collective, the semaphore and
 workload governor time their waits, the pipelined iterator times its
 stalls, the retry layers time their backoffs.
 
-Two accounting surfaces, both fed by the same `add`/`span` calls:
+Two accounting surfaces, both fed by the same `add`/`span` calls
+(engine code opens a span through `obs.op_span(name, phase=...)`, which
+also writes the host event into the profiler's trace; `span()` here is
+the accounting under it, `add()` the form for after-the-fact durations
+a site measured with its own clock):
 
 * **Process-global cumulative counters** (`counters()`), always on —
   the obs/stats.py `_global_*` precedent. bench.py deltas them per
@@ -64,6 +68,12 @@ PHASES = (
     "retry-backoff",       # task-retry + OOM-retry backoff sleeps
     "spec-wait",           # post-bound straggler wait the speculation
                            # shield raced against (exec/speculation_shield)
+    "plan",                # logical plan -> exec tree (api/session.py)
+    "scan-decode",         # file decode tasks (io/multifile.py)
+    "upload",              # host column build, staging pack, device_put
+                           # and unpack enqueue (columnar/upload.py)
+    "device-wait",         # blocking device->host reads
+                           # (columnar/transfer.py, TpuExec.collect)
     "other",               # derived remainder — never negative
 )
 

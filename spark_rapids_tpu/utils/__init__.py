@@ -1,8 +1,1 @@
-from .tracing import annotate_op, profile_trace  # noqa: F401
-
-
-def __getattr__(name: str):
-    if name == "op_span":  # delegate to tracing's lazy hook (one shim)
-        from .tracing import op_span
-        return op_span
-    raise AttributeError(name)
+from .tracing import annotate_op  # noqa: F401

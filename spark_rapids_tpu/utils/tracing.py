@@ -1,38 +1,21 @@
-"""Tracing/profiling — the engine's xprof surface (reference analog:
-the NVTX ranges + profiler integration in GpuExec/RapidsConf
-spark.rapids.profile.*; SURVEY §5).
+"""Trace annotation — the engine's cheapest xprof surface (reference
+analog: the NVTX ranges in GpuExec; SURVEY §5).
 
-Three layers:
-  * `annotate_op(name)` — a jax.profiler.TraceAnnotation around each
-    operator's per-batch device work, so xprof timelines show
-    engine-level operator names (ProjectExec, AggregateExec, ...) over
-    the XLA ops they launched — the TPU equivalent of the reference's
-    NVTX ranges in Nsight.
-  * `op_span(name, metric=None, ...)` (re-exported from obs/span.py) —
-    the NvtxWithMetrics analog: the same TraceAnnotation plus TpuMetric
-    ns accumulation plus a structured event record when the
-    spark.rapids.tpu.eventLog confs are on. New metric-scoped call
-    sites should use this instead of pairing annotate_op with
-    ns_timer by hand.
-  * `profile_trace(out_dir)` — capture a full profiler trace of a code
-    region to `out_dir` for TensorBoard/xprof, gated by
-    spark.rapids.tpu.profile.enabled + .dir so production configs can
-    switch it on without code changes (reference profile.* confs).
+`annotate_op(name)` is a jax.profiler.TraceAnnotation around each
+operator's per-batch device work, so xprof timelines show engine-level
+operator names (ProjectExec, AggregateExec, ...) over the XLA ops they
+launched — the TPU equivalent of the reference's NVTX ranges in Nsight.
+It is the annotation-only form, for the per-batch loop of
+exec/base._drive; every other timed region opens
+`spark_rapids_tpu.obs.op_span`, which adds phase, metric and event
+accounting to the same annotation. A whole trace is taken from outside
+the engine (`jax.profiler`; the benchmark's `lib/trace.capture`).
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Iterator, Optional
-
-
-def __getattr__(name: str):
-    # lazy: obs.span imports annotate_op from here, so the re-export
-    # cannot be a top-level import
-    if name == "op_span":
-        from ..obs.span import op_span
-        return op_span
-    raise AttributeError(name)
+from typing import Iterator
 
 
 @contextlib.contextmanager
@@ -40,21 +23,4 @@ def annotate_op(name: str) -> Iterator[None]:
     """Named trace annotation (no-op cost when no trace is active)."""
     import jax
     with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-@contextlib.contextmanager
-def profile_trace(out_dir: Optional[str] = None) -> Iterator[None]:
-    """Capture a jax profiler trace around the body. With out_dir=None,
-    reads spark.rapids.tpu.profile.{enabled,dir}; a disabled conf makes
-    this a no-op so call sites can wrap unconditionally."""
-    from ..config import PROFILE_DIR, PROFILE_ENABLED, active_conf
-    conf = active_conf()
-    if out_dir is None:
-        if not conf.get(PROFILE_ENABLED):
-            yield
-            return
-        out_dir = conf.get(PROFILE_DIR) or "/tmp/spark_rapids_tpu_trace"
-    import jax
-    with jax.profiler.trace(out_dir):
         yield

@@ -140,8 +140,8 @@ def test_span_nesting_and_attribution(tmp_path):
     outer_m = TpuMetric("opTime")
     inner_m = TpuMetric("opTime")
     with events.query_scope(77):
-        with op_span("outer", outer_m, detail="a"):
-            with op_span("inner", inner_m):
+        with op_span("outer", metric=outer_m, detail="a"):
+            with op_span("inner", metric=inner_m):
                 pass
     assert outer_m.value >= inner_m.value > 0
     recs = _read_log(tmp_path)
@@ -158,7 +158,7 @@ def test_span_records_failure_and_still_bumps_metric(tmp_path):
     events.enable(str(tmp_path), "DEBUG")
     m = TpuMetric("opTime")
     with pytest.raises(ValueError):
-        with op_span("boom", m):
+        with op_span("boom", metric=m):
             raise ValueError("x")
     assert m.value > 0
     (rec,) = _read_log(tmp_path)

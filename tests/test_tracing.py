@@ -1,40 +1,15 @@
-"""Tracing/profiling surface (reference NVTX/profile.* integration)."""
-
-import glob
-import os
-
-import pytest
+"""Tracing surface (reference NVTX integration): operator annotations
+must not perturb results. The span primitive and its sites are tested in
+tests/test_spans.py."""
 
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.api.session import TpuSession
 from spark_rapids_tpu.types import LONG, Schema, StructField
-from spark_rapids_tpu.utils import profile_trace
 
 
 def _df(sess):
     sch = Schema((StructField("k", LONG), StructField("v", LONG)))
     return sess.from_pydict({"k": [1, 1, 2], "v": [10, 20, 30]}, sch)
-
-
-def test_profile_disabled_is_noop():
-    TpuSession()
-    with profile_trace():  # conf off -> no trace, no error
-        assert _df(TpuSession()).group_by("k").agg(
-            (F.sum("v"), "s")).count() == 2
-
-
-@pytest.mark.slow  # minute-scale on a single-core host; nightly tier
-def test_profile_captures_trace(tmp_path):
-    out = str(tmp_path / "trace")
-    sess = TpuSession({"spark.rapids.tpu.profile.enabled": True,
-                       "spark.rapids.tpu.profile.dir": out})
-    try:
-        with profile_trace():
-            _df(sess).group_by("k").agg((F.sum("v"), "s")).collect()
-        files = glob.glob(os.path.join(out, "**", "*"), recursive=True)
-        assert any(os.path.isfile(f) for f in files), files
-    finally:
-        TpuSession()
 
 
 def test_annotations_wrap_execution():

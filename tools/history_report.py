@@ -42,7 +42,8 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional
 PHASES = (
     "admission-wait", "compile", "device-compute", "host-pack-serialize",
     "shuffle-io", "ici-collective", "spill-wait", "semaphore-wait",
-    "pipeline-stall", "retry-backoff", "spec-wait", "other",
+    "pipeline-stall", "retry-backoff", "spec-wait", "plan", "scan-decode",
+    "upload", "device-wait", "other",
 )
 
 
