@@ -43,7 +43,11 @@ def concat_batches(batches: List[ColumnarBatch], schema: Schema
     is the bucket of the total. Tree-shaped pairwise reduction: each row is
     copied O(log k) times instead of the O(k) of a left fold, and each
     round runs ONE compiled concat program per capacity-shape pair (jit
-    cache keyed on shapes + static out capacity)."""
+    cache keyed on shapes + static out capacity). A pair's fixed-width
+    lanes are two contiguous block copies at a traced row offset
+    (ops.basic.concat_columns), so a round costs its bytes, not its rows.
+    Known host row counts take the exact lane: the output bucket is sized
+    to the rows and may be smaller than an input's capacity."""
     assert batches
     level = batches
     while len(level) > 1:

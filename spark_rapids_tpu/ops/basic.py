@@ -164,14 +164,16 @@ def concat_columns(a: Column, b: Column, a_rows, b_rows, out_capacity: int
                    ) -> Column:
     """Concatenate two columns' active rows (the coalesce primitive).
 
-    out_capacity must be >= a_rows+b_rows worst case (callers size it to the
-    bucket of a.capacity+b.capacity).
+    Fixed-width lanes (data, validity, dictionary codes, struct / decimal128
+    children) move as two contiguous blocks at a traced offset — no per-row
+    gather; strings and arrays keep their own bodies. The contract is
+    a_rows <= a.capacity, b_rows <= b.capacity and
+    a_rows + b_rows <= out_capacity; out_capacity may be smaller than either
+    input's capacity (concat_batches' exact lane buckets the known row
+    total). Rows at and past a_rows + b_rows come out zero / invalid /
+    NULL_CODE whatever the inputs' padding holds.
     """
-    idx = jnp.arange(out_capacity, dtype=jnp.int32)
-    from_b = idx >= a_rows
-    b_idx = idx - a_rows
-    total = a_rows + b_rows
-    out_valid = idx < total
+    out_valid = active_mask(a_rows + b_rows, out_capacity)
     if isinstance(a, DictionaryColumn):
         # coalesce inputs are materialized at the operator boundary
         # (exec/base.py), so this only fires for two views of the SAME
@@ -182,10 +184,10 @@ def concat_columns(a: Column, b: Column, a_rows, b_rows, out_capacity: int
             and a.dict_data is b.dict_data \
             and a.dict_offsets is b.dict_offsets, \
             "concat of distinct dictionaries — materialize first"
-        codes = _concat_fixed(a.codes, b.codes, from_b, b_idx, idx)
+        codes = _concat_fixed(a.codes, b.codes, a_rows, out_capacity)
         codes = jnp.where(out_valid, codes, jnp.int32(NULL_CODE))
-        valid = _concat_fixed(a.validity, b.validity, from_b, b_idx, idx) \
-            & out_valid
+        valid = _concat_fixed(a.validity, b.validity, a_rows,
+                              out_capacity) & out_valid
         return DictionaryColumn(codes, a.dict_data, a.dict_offsets,
                                 valid, a.dtype)
     if isinstance(a, StringColumn):
@@ -194,23 +196,32 @@ def concat_columns(a: Column, b: Column, a_rows, b_rows, out_capacity: int
     if isinstance(a, StructColumn):
         kids = tuple(concat_columns(ka, kb, a_rows, b_rows, out_capacity)
                      for ka, kb in zip(a.children, b.children))
-        valid = _concat_fixed(a.validity, b.validity, from_b, b_idx, idx) & out_valid
+        valid = _concat_fixed(a.validity, b.validity, a_rows,
+                              out_capacity) & out_valid
         return type(a)(kids, valid, a.dtype)  # incl. Decimal128
     if isinstance(a, ArrayColumn):
         # gather both sides' rows into the output slot order; gather_array
         # rebuilds offsets and compacts the child elements
         from .collection import concat_arrays
         return concat_arrays(a, b, a_rows, b_rows, out_capacity)
-    data = _concat_fixed(a.data, b.data, from_b, b_idx, idx)
-    valid = _concat_fixed(a.validity, b.validity, from_b, b_idx, idx) & out_valid
+    data = _concat_fixed(a.data, b.data, a_rows, out_capacity)
+    valid = _concat_fixed(a.validity, b.validity, a_rows,
+                          out_capacity) & out_valid
     data = jnp.where(out_valid, data, jnp.zeros((), data.dtype))
     return Column(data, valid, a.dtype)
 
 
-def _concat_fixed(a, b, from_b, b_idx, idx):
-    a_safe = jnp.where(idx < a.shape[0], idx, 0)
-    b_safe = jnp.clip(b_idx, 0, b.shape[0] - 1)
-    return jnp.where(from_b, b[b_safe], a[a_safe])
+def _concat_fixed(a, b, a_rows, out_capacity: int):
+    """(out_capacity,) lane: a[:a_rows], then b from row a_rows on. Rows past
+    the two active blocks hold padding of a or b: the caller masks them.
+
+    dynamic_update_slice clamps its start so the update fits, so b lands in
+    a buffer of out_capacity + b.capacity rows (a_rows <= out_capacity:
+    never clamped), of which the first out_capacity are kept."""
+    buf = jnp.zeros((out_capacity + b.shape[0],), a.dtype)
+    buf = jax.lax.dynamic_update_slice(buf, a[:out_capacity], (0,))
+    buf = jax.lax.dynamic_update_slice(buf, b, (a_rows,))
+    return buf[:out_capacity]
 
 
 def slice_rows(col: Column, start, length, out_capacity: int) -> Column:

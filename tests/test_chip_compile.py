@@ -132,6 +132,26 @@ def _compile_for_chip(site, args, kwargs, sharding):
     return site._jit.lower(*a, **k).compile()
 
 
+@pytest.fixture(scope="module")
+def largest_text(captured, one_chip):
+    """label -> text compiled at chip_smoke's size for the label's dispatch
+    that saw the most bytes (q1's fully coalesced 16M-row batch for the
+    shared upload/concat programs); each label compiles once. The caller
+    steers the backend questions first."""
+    texts = {}
+
+    def text_of(label):
+        if label not in texts:
+            assert label in captured, sorted(captured)
+            site, args, kwargs = max(captured[label], key=lambda c: sum(
+                leaf.nbytes for leaf in jax.tree_util.tree_leaves(c[1:])
+                if hasattr(leaf, "nbytes")))
+            texts[label] = _compile_for_chip(site, args, kwargs,
+                                             one_chip).as_text()
+        return texts[label]
+    return text_of
+
+
 # -- the murmur3 kernels: they gate the default main path on a TPU ----------
 
 @pytest.mark.parametrize("kernel,dtype", [("murmur3_long_lanes", jnp.int64),
@@ -162,16 +182,20 @@ PLANNED = {
 
 @pytest.mark.parametrize("label", sorted(PLANNED))
 def test_planned_program_compiles_at_chip_smoke_shapes(
-        one_chip, captured, monkeypatch, label):
-    assert label in captured, sorted(captured)
+        largest_text, monkeypatch, label):
     _steer(monkeypatch, on_tpu=True)
-    # of a label's dispatches, the one that saw the most bytes (q1's fully
-    # coalesced 16M-row batch for the shared upload/concat programs)
-    site, args, kwargs = max(captured[label], key=lambda c: sum(
-        leaf.nbytes for leaf in jax.tree_util.tree_leaves(c[1:])
-        if hasattr(leaf, "nbytes")))
-    text = _compile_for_chip(site, args, kwargs, one_chip).as_text()
+    text = largest_text(label)
     assert ("tpu_custom_call" in text) == PLANNED[label], label
+
+
+def test_concat_pair_compiles_without_a_gather(largest_text, monkeypatch):
+    """Coalesce copies two contiguous blocks a lane: a `gather(` in the
+    compiled program is the per-row index walk back (two a lane, ~30 ns a
+    row on the chip, 98.8% of a Q6 query's device time before PR 28)."""
+    _steer(monkeypatch, on_tpu=True)
+    text = largest_text("coalesce.concat_pair")
+    assert "gather(" not in text
+    assert "dynamic-update-slice(" in text
 
 
 def test_captured_shapes_are_chip_smokes(captured):

@@ -112,6 +112,58 @@ def test_coalesce_merges_batches():
     assert batches[0].to_pydict()["s"] == DATA["s"]
 
 
+#: a small target_bytes (three flushes) and the (active rows, capacity) of
+#: five scan batches; "sparse" holds what a selective filter leaves: a
+#: handful of rows in a large bucket
+_COALESCE_LAYOUTS = {
+    "dense": (9000, [(100, 128), (128, 128), (130, 256), (1, 128),
+                     (77, 128)]),
+    "sparse": (24000, [(100, 128), (3, 1024), (130, 256), (0, 512),
+                       (5, 1024)]),
+}
+
+
+@pytest.mark.parametrize("flushes", [3, 1])
+@pytest.mark.parametrize("layout", sorted(_COALESCE_LAYOUTS))
+def test_coalesce_exact_and_device_row_counts_agree(layout, flushes):
+    """concat_batches' exact lane (host row counts known: tight output
+    bucket, which can be smaller than an input's capacity) and its device
+    lane (row counts are device scalars: bucket of the capacities) give the
+    same rows in the same order."""
+    schema = Schema((StructField("a", INT), StructField("d", DOUBLE),
+                     StructField("s", STRING)))
+    target_bytes, shapes = _COALESCE_LAYOUTS[layout]
+    if flushes == 1:
+        target_bytes = 1 << 30
+    exact, expected, base = [], [], 0
+    for rows, cap in shapes:
+        data = {"a": [None if i % 7 == 3 else base + i for i in range(rows)],
+                "d": [None if i % 5 == 1 else (base + i) * 0.5
+                      for i in range(rows)],
+                "s": [None if i % 4 == 2 else f"r{base + i}"
+                      for i in range(rows)]}
+        exact.append(ColumnarBatch.from_pydict(data, schema, capacity=cap))
+        expected += list(zip(data["a"], data["d"], data["s"]))
+        base += rows
+    on_device = [ColumnarBatch(b.columns, jnp.asarray(b.num_rows), schema)
+                 for b in exact]
+    assert all(b._host_rows is not None for b in exact)
+    assert all(b._host_rows is None for b in on_device)
+
+    def run(batches):
+        plan = CoalesceBatchesExec(InMemoryScanExec(batches, schema),
+                                   target_bytes=target_bytes)
+        return list(plan.execute())
+
+    out_exact, out_device = run(exact), run(on_device)
+    assert len(out_exact) == len(out_device) == flushes
+    for e, d in zip(out_exact, out_device):
+        assert e.capacity <= d.capacity
+    got_exact = [r for b in out_exact for r in b.to_pylist()]
+    got_device = [r for b in out_device for r in b.to_pylist()]
+    assert got_exact == got_device == expected
+
+
 def test_coalesce_respects_target_bytes():
     scan = make_scan(DATA, SCHEMA, split=2)
     plan = CoalesceBatchesExec(scan, target_bytes=1)  # force no merging
