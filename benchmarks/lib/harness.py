@@ -136,18 +136,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         _say(t_process, f"window closed after {len(win.queries)} queries")
         # ---- window closed -------------------------------------------
 
-        plan_s = []
-        if trace:
-            for _ in win.completed:
-                df = query_mod.build(sess, paths, cfg)
-                t0 = time.perf_counter()
-                df._exec()
-                plan_s.append(time.perf_counter() - t0)
-            if keep_trace:
-                os.makedirs(keep_trace, exist_ok=True)
-                shutil.copy(trace_lib.find_xplane(tdir), keep_trace)
-            if device["platform"] == "tpu":
-                reduced = trace_lib.reduce(trace_lib.load(tdir))
+        if trace and keep_trace:
+            os.makedirs(keep_trace, exist_ok=True)
+            shutil.copy(trace_lib.find_xplane(tdir), keep_trace)
+        if trace and device["platform"] == "tpu":
+            reduced = trace_lib.reduce(trace_lib.load(tdir))
 
         proof_lines = proofs.run_proofs(
             cfg.get("proofs", []),
@@ -169,8 +162,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         obs = observe.Observation(
             queries=len(win.completed), window_s=win.end - win.start,
             window=window_delta, setup=setup_delta,
-            plan_s=plan_s, trace=reduced, work=ref_mod.work_model(cfg, tables),
-            peaks=peaks, memory_peak_bytes=peak)
+            trace=reduced, work=ref_mod.work_model(cfg, tables), peaks=peaks,
+            memory_peak_bytes=peak)
         values = {m["name"]: manifest.reader(m["name"])(obs)
                   for m in per_layer}
         wanted = per_layer
@@ -198,6 +191,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     result.update({
         "workload": workload, "seed": seed, "trace": int(trace),
         "window_s": win.end - win.start,
+        "queries": summary.get("queries", 0),
         "per_query_s": [q.end - q.start for q in win.completed],
         "errors": [q.error for q in win.queries if q.error][:3],
         "window_compiles": window_delta["counters"]["traces"],

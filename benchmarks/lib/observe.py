@@ -50,13 +50,12 @@ class Observation:
     """What a per-layer metric's reader is given. A reader that finds
     nothing to read returns None and the metric is left out of the line."""
 
-    def __init__(self, *, queries, window_s, window, setup, plan_s, trace,
-                 work, peaks, memory_peak_bytes):
+    def __init__(self, *, queries, window_s, window, setup, trace, work,
+                 peaks, memory_peak_bytes):
         self.queries = queries        # queries completed in the traced window
         self.window_s = window_s      # its length on the host's clock
         self.window = window          # ledger/phase delta over it
         self.setup = setup            # ledger/phase delta over set-up
-        self.plan_s = plan_s          # seconds of `df._exec()`, one per query
         self.trace = trace            # lib.trace.Reduced, or None
         self.work = work              # the configuration's work model
         self.peaks = peaks            # this device kind's peaks

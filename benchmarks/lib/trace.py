@@ -21,7 +21,7 @@ HOST_PLANE_PREFIX = "/host:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 WINDOW_MARK = "bench.window"
-NAMED_GAPS = 200
+NAMED_GAPS = 1000     # a traced window of 8 q6 queries leaves ~900 gaps (PR 30)
 _FINGERPRINT = re.compile(r"\(\d+\)$")
 
 

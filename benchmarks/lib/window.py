@@ -4,11 +4,16 @@ A traffic mix is a data file: `loop` (closed) and `clients` (1: a mix with
 more clients brings the code that drives them, with the cell that needs it).
 The client issues its next query as soon as its last returned, starts
 queries until `seconds` have passed and lets the one in flight finish.
-Nothing is quantised: the window ends when the last query ends.
+Nothing is quantised: the window ends when the last query ends. `query_s` is
+the window's length over the queries it completed (the window mean).
 """
 
+import statistics
 import time
 from dataclasses import dataclass, field
+
+#: a 90th percentile is read only from a window with ten samples beyond it
+P90_MIN_QUERIES = 100
 
 
 @dataclass
@@ -58,10 +63,26 @@ def run_window(issue, traffic: dict, seconds: float, max_queries=None,
     return Window(start, end, queries)
 
 
+def estimators(per_query_s: list, window_s: float) -> dict:
+    """One window's per-query times read every way the benchmark reads them
+    (`tools/spread_readings.py` reads recorded lines through this too):
+    `queries`, the sample count; `window_mean_s`, the whole window over its
+    queries, so a stall anywhere in it shows; `median_s`; and `p90_s`
+    (`statistics.quantiles`, n=10), only where ten samples lie beyond it."""
+    out = {"queries": len(per_query_s),
+           "window_mean_s": window_s / len(per_query_s),
+           "median_s": statistics.median(per_query_s)}
+    if len(per_query_s) >= P90_MIN_QUERIES:
+        out["p90_s"] = statistics.quantiles(per_query_s, n=10)[-1]
+    return out
+
+
 def summarize(w: Window) -> dict:
-    """`query_s`: the whole window over all its queries, so a stall anywhere
-    in it shows."""
+    """`query_s` and `queries`, its sample count. `query_s` is the window
+    mean: PR 30 read the cell's runs every way `estimators` offers and the
+    median spread no less from run to run (PERF.md section 2)."""
     done = w.completed
     if not done:
         return {}
-    return {"query_s": (w.end - w.start) / len(done)}
+    e = estimators([q.end - q.start for q in done], w.end - w.start)
+    return {"query_s": e["window_mean_s"], "queries": e["queries"]}

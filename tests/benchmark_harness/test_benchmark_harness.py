@@ -21,6 +21,7 @@ if str(ROOT) not in sys.path:
 from benchmarks.lib import harness, peaks, trace, window  # noqa: E402
 from benchmarks.lib.manifest import (Manifest, ManifestError,  # noqa: E402
                                      apply_rehearsal)
+from benchmarks.tools import spread_readings  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 DOC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -87,6 +88,23 @@ def test_every_cell_reports_setup_another_end_to_end_and_a_layer_metric(cell):
     assert m.traffic(c["traffic"])["loop"] == "closed"
 
 
+@pytest.mark.parametrize("metric", [m["name"] for m in DOC["per_layer"]])
+def test_every_per_layer_metric_has_a_reader(metric):
+    assert callable(Manifest().reader(metric))
+
+
+def test_plan_ms_is_retired_and_no_reader_is_left_without_an_entry():
+    """PR 30: `plan_span_ms` is the planner's metric; the outside reading,
+    its reader and the second planning after the window are gone."""
+    named = {m["name"] for m in DOC["per_layer"]}
+    assert "plan_ms" not in named and "plan_span_ms" in named
+    readers = {f.stem for f in (ROOT / "benchmarks" / "layer_metrics").glob("*.py")}
+    assert readers == named
+    assert not hasattr(harness.observe.Observation(
+        queries=1, window_s=1.0, window={}, setup={}, trace=None, work={},
+        peaks={}, memory_peak_bytes=0), "plan_s")
+
+
 def test_a_cell_the_manifest_does_not_name_is_refused():
     with pytest.raises(ManifestError, match="names no workload"):
         Manifest().cell("no_such_cell")
@@ -114,16 +132,53 @@ def _drive(durations, seconds):
                              seconds, clock=clock)
 
 
-def test_query_s_is_the_whole_window_over_its_queries_and_shows_a_stall():
-    steady = _drive([2.0] * 10, seconds=9.0)
-    assert len(steady.completed) == 5            # the one in flight finishes
-    assert steady.end - steady.start == pytest.approx(10.0)
-    assert window.summarize(steady)["query_s"] == pytest.approx(2.0)
-    stalled = _drive([2.0, 2.0, 7.0, 2.0, 2.0, 2.0], seconds=12.0)
-    s = window.summarize(stalled)
-    assert len(stalled.completed) == 4
-    assert s["query_s"] == pytest.approx(13.0 / 4)
-    assert s["query_s"] > 1.6 * 2.0              # the stall shows
+STEADY = ([2.0] * 10, 9.0)                        # the one in flight finishes
+STALLED = ([2.0, 2.0, 7.0, 2.0, 2.0, 2.0], 12.0)  # one stall, mid-window
+
+
+@pytest.mark.parametrize("drive,queries,window_s,mean,median", [
+    (STEADY, 5, 10.0, 2.0, 2.0),
+    (STALLED, 4, 13.0, 13.0 / 4, 2.0),
+], ids=["steady", "stalled"])
+def test_the_windows_estimators_and_which_of_them_shows_a_stall(
+        drive, queries, window_s, mean, median):
+    w = _drive(*drive)
+    assert len(w.completed) == queries
+    assert w.end - w.start == pytest.approx(window_s)
+    e = window.estimators([q.end - q.start for q in w.completed],
+                          w.end - w.start)
+    assert e["queries"] == queries                # the sample count
+    assert e["window_mean_s"] == pytest.approx(mean)
+    assert e["median_s"] == pytest.approx(median)
+    assert "p90_s" not in e                       # no ten samples beyond it
+
+
+@pytest.mark.parametrize("drive,queries,query_s", [
+    (STEADY, 5, 2.0),
+    (STALLED, 4, 13.0 / 4),
+    # a stall in the LAST query, which runs past the window's seconds
+    (([2.0, 2.0, 2.0, 9.0], 7.0), 4, 15.0 / 4),
+], ids=["steady", "stalled", "stalled_at_the_close"])
+def test_query_s_is_the_whole_window_over_its_queries_and_shows_a_stall(
+        drive, queries, query_s):
+    """The rule of PERF.md section 2 kept the window mean (PR 30): whatever
+    the window spent, wherever, is in `query_s`; `queries` is its sample
+    count."""
+    s = window.summarize(_drive(*drive))
+    assert s == {"query_s": pytest.approx(query_s), "queries": queries}
+    if query_s > 2.0:
+        assert s["query_s"] > 1.6 * 2.0           # the stall shows
+
+
+@pytest.mark.parametrize("n,has_p90", [(99, False), (100, True), (110, True)])
+def test_a_90th_percentile_is_read_only_with_ten_samples_beyond_it(n, has_p90):
+    times = [0.4 + 0.001 * i for i in range(n)]   # 0.400, 0.401, ...
+    e = window.estimators(times[::-1], sum(times))
+    assert ("p90_s" in e) is has_p90
+    if has_p90:
+        # statistics.quantiles, n=10, exclusive: the 0.9 * (n + 1)-th sample
+        assert e["p90_s"] == pytest.approx(0.4 + 0.001 * (0.9 * (n + 1) - 1))
+        assert sum(t > e["p90_s"] for t in times) >= 10
 
 
 def test_a_failed_query_is_counted_and_three_in_a_row_stop_the_client():
@@ -353,7 +408,9 @@ def _rehearse(cell, **kw):
 def test_a_sound_run_is_correct_and_prints_the_contracts_line(cell):
     res = _rehearse(cell)
     assert res["correct"] is True and res["failed"] == 0
-    assert res["attempted"] == len(res["per_query_s"]) >= 1
+    assert res["attempted"] == len(res["per_query_s"]) == res["queries"] >= 1
+    assert res["metrics"]["query_s"]["value"] == pytest.approx(
+        res["window_s"] / res["queries"])
     assert list(res)[:5] == ["correct", "attempted", "failed", "metrics",
                              "device"]
     assert list(res)[-1] == "checks"
@@ -509,3 +566,108 @@ def test_a_cell_config_traffic_and_metric_added_as_new_files_only(tmp_path):
     assert got["all_dispatches"]["value"] == pytest.approx(
         got["ingest_dispatches"]["value"] + got["stage_dispatches"]["value"])
     assert {p: p.read_bytes() for p in before} == before  # nothing edited
+
+
+# -- the tool that reads result lines the way the check does -------------------
+
+RECORDED = HERE / "recorded_q6_set_pr30.jsonl"
+# the six runs' `query_s` as the chip printed them (my chip runs, PR 30, set A:
+# PR 28's tree, window mean, 51 s, six seeds, one call)
+RECORDED_QUERY_S = [0.46057971536036035, 0.4448406172086956,
+                    0.45199132153097343, 0.4494282023333332,
+                    0.4600422864054051, 0.5009004195196076]
+
+
+def test_spread_readings_on_recorded_lines_gives_the_spread_by_hand():
+    tool = spread_readings
+    lines = tool.result_lines(str(RECORDED))
+    assert [r["metrics"]["query_s"]["value"] for r in lines] == RECORDED_QUERY_S
+    got = tool.read_set(lines)
+    mean = got["window_mean_s"]
+    assert mean["runs"] == pytest.approx(RECORDED_QUERY_S)
+    # by hand: the median lies between the third and fourth of the sorted six;
+    # 0.5009 is the farthest from it and is left out; the range of the other
+    # five is 0.46058 - 0.44484
+    median = (0.45199132153097343 + 0.4600422864054051) / 2
+    assert mean["median"] == pytest.approx(median)
+    assert mean["range_drop1"] == pytest.approx(
+        (0.46057971536036035 - 0.4448406172086956) / median)
+    assert mean["range_drop1"] == pytest.approx(0.034514294, rel=1e-6)
+    # the quartiles of all six, as statistics.quantiles(n=4) places them:
+    # 1.75th and 5.25th of the sorted runs
+    q1 = 0.4448406172086956 + 0.75 * (0.4494282023333332 - 0.4448406172086956)
+    q3 = 0.46057971536036035 + 0.25 * (0.5009004195196076 - 0.46057971536036035)
+    assert mean["iqr"] == pytest.approx((q3 - q1) / median)
+    assert got["queries"]["runs"] == [111, 115, 113, 114, 111, 102]
+    assert got["median_s"]["median"] < mean["median"]   # a long right tail
+    assert set(got) == {"queries", "window_mean_s", "median_s", "p90_s"}
+
+
+@pytest.mark.parametrize("values,want", [
+    ([1.0, 1.01, 0.99, 1.02, 1.5], 0.03 / 1.01),    # the far one is left out
+    ([1.0, 1.0, 1.0], 0.0),
+    ([1.0, 1.1], 0.1 / 1.05),                       # two runs: their range
+])
+def test_range_drop1_leaves_out_the_run_farthest_from_the_median(values, want):
+    assert spread_readings.range_drop1(values) == pytest.approx(want)
+
+
+def test_spread_readings_runs_on_the_cpu_on_recorded_lines(tmp_path):
+    """The command itself, on a directory of result files and on one file of
+    lines; a set without a result line is refused."""
+    d = tmp_path / "as_files"
+    d.mkdir()
+    for i, line in enumerate(RECORDED.read_text().splitlines()):
+        (d / f"s{i}.json").write_text("bench +1.0s progress\n" + line + "\n")
+    tool = str(ROOT / "benchmarks" / "tools" / "spread_readings.py")
+    r = subprocess.run([sys.executable, tool, str(d), str(RECORDED), "--decide"],
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    doc = json.loads(r.stdout.strip().splitlines()[-1])
+    a, b = doc["sets"]["as_files"], doc["sets"][RECORDED.name]
+    assert a == b and doc["medians_apart"]["window_mean_s"] == 0.0
+    assert doc["decision"]["query_s"] in ("window_mean_s", "median_s")
+    assert doc["decision"]["bound"] in spread_readings.STEPS
+    empty = tmp_path / "empty.jsonl"
+    empty.write_text("not a result\n")
+    r = subprocess.run([sys.executable, tool, str(empty)],
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 3 and "no result line" in r.stderr
+
+
+def _sets(mean, median, p90=None):
+    """Two or more sets with these spreads (farthest run left out)."""
+    out = {}
+    for i, (a, b) in enumerate(zip(mean, median)):
+        out[f"set{i}"] = {"window_mean_s": {"range_drop1": a},
+                          "median_s": {"range_drop1": b}}
+        if p90 is not None:
+            out[f"set{i}"]["p90_s"] = {"range_drop1": p90[i]}
+    return out
+
+
+@pytest.mark.parametrize("mean,median,p90,estimator,bound,twice,within,p90_bound", [
+    # the median spreads as much as the mean: the mean stays, bound from it
+    ([0.0345, 0.027], [0.0455, 0.03], [0.04, 0.04], "window_mean_s", 0.08, True, True, None),
+    # exactly 0.6 of the mean's: the median wins, and brings its tail along
+    ([0.02, 0.01], [0.012, 0.009], [0.04, 0.03], "median_s", 0.03, True, True, 0.08),
+    # ... but not a tail that would need more than 0.10
+    ([0.02, 0.01], [0.012, 0.009], [0.06, 0.03], "median_s", 0.03, True, True, None),
+    # just over 0.6: the mean stays
+    ([0.02, 0.02], [0.0121, 0.01], None, "window_mean_s", 0.05, True, True, None),
+    # the floor: never under 0.01, even where that is over 8 x the narrowest
+    ([0.002, 0.001], [0.002, 0.001], None, "window_mean_s", 0.01, True, False, None),
+    # no step reaches twice the widest: 0.08 all the same, and said so
+    ([0.05, 0.03], [0.05, 0.04], None, "window_mean_s", 0.08, False, False, None),
+])
+def test_the_rule_that_picks_the_estimator_and_the_bound(
+        mean, median, p90, estimator, bound, twice, within, p90_bound):
+    d = spread_readings.decide(_sets(mean, median, p90))
+    assert d["query_s"] == estimator and d["bound"] == bound
+    assert d["bound_reaches_twice_the_widest"] is twice
+    assert d["bound_within_8x_narrowest"] is within
+    if estimator == "median_s" and p90 is not None:
+        assert d["query_p90_s"]["bound"] == p90_bound
+        assert d["query_p90_s"]["added"] is (p90_bound is not None)
+    else:
+        assert "query_p90_s" not in d
