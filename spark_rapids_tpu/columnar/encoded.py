@@ -375,14 +375,35 @@ def encoded_equal_literal(col: DictionaryColumn, value) -> Column:
 # ---------------------------------------------------------------------------
 
 
+def _decoded_bytes(col: DictionaryColumn):
+    dlens = col.dict_offsets[1:] - col.dict_offsets[:-1]
+    safe = jnp.clip(col.codes, 0, col.dict_capacity - 1)
+    return jnp.sum(jnp.where(col.validity, dlens[safe], 0))
+
+
+def _decode(col: DictionaryColumn, byte_cap: int) -> StringColumn:
+    from ..ops.basic import gather_column
+    return gather_column(col.dict_view(), col.codes,
+                         out_valid=col.validity,
+                         out_byte_capacity=byte_cap)
+
+
+# through the dispatch ledger like every other program: run eagerly they
+# were device time under no label (a third of the chip's busy time in
+# TPC-H Q14, ISSUE 31)
+from ..obs.dispatch import instrument as _instrument
+
+_decoded_bytes_jit = _instrument(_decoded_bytes,
+                                 label="encoded.decoded_bytes")
+_decode_jit = _instrument(_decode, label="encoded.decode",
+                          static_argnums=(1,))
+
+
 def decoded_byte_bucket(col: DictionaryColumn) -> int:
     """Byte bucket a full decode of `col` needs (host sync — the
     materialize seams are host-level by design, so the decoded buffer
     is sized tight instead of to a static worst case)."""
-    dlens = col.dict_offsets[1:] - col.dict_offsets[:-1]
-    safe = jnp.clip(col.codes, 0, col.dict_capacity - 1)
-    total = jnp.sum(jnp.where(col.validity, dlens[safe], 0))
-    return bucket_capacity(max(int(total), 1))
+    return bucket_capacity(max(int(_decoded_bytes_jit(col)), 1))
 
 
 def materialize_column(col, fault_key: Optional[str] = None,
@@ -399,10 +420,7 @@ def materialize_column(col, fault_key: Optional[str] = None,
     from .. import faults
     faults.check("device.dispatch", key=fault_key)
     byte_cap = decoded_byte_bucket(col)
-    from ..ops.basic import gather_column
-    out = gather_column(col.dict_view(), col.codes,
-                        out_valid=col.validity,
-                        out_byte_capacity=byte_cap)
+    out = _decode_jit(col, byte_cap)
     _note(materializations=1, materialized_bytes=byte_cap)
     return out
 

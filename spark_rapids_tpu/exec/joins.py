@@ -34,7 +34,7 @@ from ..ops.strings import string_equal
 from ..ops.join import (
     BuildTable, cross_pairs, expand_candidates, gather_column_indices,
     inner_gather_maps, int_key_lanes, matched_flags, outer_extend_maps,
-    probe_counts, unmatched_indices, verify_pairs,
+    probe_ranges, unmatched_indices, verify_pairs,
 )
 from ..types import BooleanType, Schema, StructField
 from .base import (BUILD_TIME, DEBUG, DISPATCH_METRICS, GATHER_METRICS,
@@ -82,24 +82,22 @@ def _var_lengths(c: Column):
     return string_lengths(c)
 
 
-def _string_byte_needs(stream_columns, build: BuildTable, lo, counts, act):
+def _string_byte_needs(stream_columns, counts, act, range_sizes):
     """Exact output payload requirement per variable-size column of the
     join (string bytes / array elements), all on device — fetched together
     with the candidate total in the one host sync per stream batch.
 
     Stream side: row i is emitted count_i times (candidates) plus at most
     once more (outer-unmatched tail). Build side: candidate payload is the
-    per-row sorted-order prefix-sum ranges [lo, lo+count)."""
+    size of each row's sorted-order range [lo, lo+count), which
+    `probe_ranges` read with the range (`range_sizes`)."""
     cnt = counts.astype(jnp.int64)
     stream_needs = []
     for c in stream_columns:
         if _is_varsize(c):
             lens = jnp.where(act, _var_lengths(c), 0).astype(jnp.int64)
             stream_needs.append(jnp.sum(cnt * lens) + jnp.sum(lens))
-    build_needs = []
-    for prefix in build.payload_prefix:
-        # precomputed in BuildTable.build (invariant across stream batches)
-        build_needs.append(jnp.sum(prefix[lo + counts] - prefix[lo]))
+    build_needs = [jnp.sum(r.astype(jnp.int64)) for r in range_sizes]
     return tuple(stream_needs), tuple(build_needs)
 
 
@@ -416,12 +414,11 @@ class HashJoinExec(TpuExec):
             skey_cols = self._mask_keys(
                 skey_cols,
                 self._filter_mask(self._stream_filter, stream_batch))
-        lo, counts, _ = probe_counts(build, skey_cols,
-                                     stream_batch.num_rows,
-                                     stream_batch.capacity)
+        lo, counts, _, range_sizes = probe_ranges(
+            build, skey_cols, stream_batch.num_rows, stream_batch.capacity)
         act = active_mask(stream_batch.num_rows, stream_batch.capacity)
-        needs = _string_byte_needs(stream_batch.columns, build, lo, counts,
-                                   act)
+        needs = _string_byte_needs(stream_batch.columns, counts, act,
+                                   range_sizes)
         return lo, counts, skey_cols, jnp.sum(counts.astype(jnp.int64)), needs
 
     def _probe_kernel(self, build: BuildTable, build_batch: ColumnarBatch,

@@ -109,7 +109,11 @@ FUSABLE_OPS: Dict[str, str] = {
 
 _COUNTER_LOCK = threading.Lock()
 _COUNTERS = {"stages_fused": 0, "ops_fused": 0, "executions": 0,
-             "fallbacks": 0, "dispatches": 0, "batches": 0}
+             "fallbacks": 0, "dispatches": 0, "batches": 0,
+             # the join's size cache: a miss is a shape it never sized,
+             # a refresh an entry that expired (SPEC_REFRESH); each pays
+             # one sizing dispatch and its host sync
+             "size_cache_misses": 0, "size_cache_refreshes": 0}
 
 
 def _note(**deltas) -> None:
@@ -715,6 +719,7 @@ class CompiledStageExec(TpuExec):
         key = (stream_batch.capacity, build_batch.capacity)
         cached = self._size_cache.get(key)
         use_fused = fused_tier_enabled("join_probe", key)
+        known = cached is not None
         if cached is not None and speculation_allowed():
             cached[3] += 1
             if cached[3] > HashJoinExec.SPEC_REFRESH:
@@ -723,10 +728,16 @@ class CompiledStageExec(TpuExec):
             else:
                 return (cached[0], cached[1], cached[2], use_fused), \
                     True
-        total_dev, needs_dev = self._jit_sizing(build_batch,
-                                                stream_batch)
-        total, (s_needs, b_needs) = jax.device_get(
-            (total_dev, needs_dev))
+        if known:
+            _note(size_cache_refreshes=1)
+        else:
+            _note(size_cache_misses=1)
+        from ..obs import op_span
+        with op_span("join.sizing", phase="device-wait"):
+            total_dev, needs_dev = self._jit_sizing(build_batch,
+                                                    stream_batch)
+            total, (s_needs, b_needs) = jax.device_get(
+                (total_dev, needs_dev))
         cand_cap = bucket_capacity(max(int(total), 1))
         s_caps = _byte_cap_tuple(stream_batch.columns, s_needs)
         b_caps = _byte_cap_tuple(build_batch.columns, b_needs)
@@ -751,12 +762,14 @@ class CompiledStageExec(TpuExec):
         bi = 1 if join.build_side == "right" else 0
         build_child, stream_child = self.children[bi], \
             self.children[1 - bi]
-        batches = list(build_child.execute())
-        if batches:
-            build_batch = concat_batches(batches,
-                                         build_child.output_schema)
-        else:
-            build_batch = empty_batch(build_child.output_schema)
+        from ..obs import op_span
+        with op_span("join.build", phase="join-build"):
+            batches = list(build_child.execute())
+            if batches:
+                build_batch = concat_batches(batches,
+                                             build_child.output_schema)
+            else:
+                build_batch = empty_batch(build_child.output_schema)
         spec = self._spec_allowed()
         table = None
         state = flag = ev = None

@@ -14,6 +14,7 @@ logic explicitly on validity lanes.
 
 from __future__ import annotations
 
+import datetime
 from typing import List, Optional, Sequence
 
 import jax.numpy as jnp
@@ -162,8 +163,13 @@ class LeafExpression(Expression):
 
 class Literal(LeafExpression):
     def __init__(self, value, dtype: Optional[DataType] = None):
-        self.value = value
         self._dtype = dtype or _infer_literal_type(value)
+        if isinstance(value, datetime.date) \
+                and not isinstance(value, datetime.datetime):
+            # a DATE is days since the epoch (types.DateType): kept as
+            # the column holds it, so the literal can be evaluated
+            value = value.toordinal() - _EPOCH_ORDINAL
+        self.value = value
 
     @property
     def data_type(self):
@@ -203,6 +209,9 @@ class Literal(LeafExpression):
         return f"lit({self.value!r})"
 
 
+_EPOCH_ORDINAL = datetime.date(1970, 1, 1).toordinal()
+
+
 def _infer_literal_type(value) -> DataType:
     from ..types import (BOOLEAN, DOUBLE, INT, LONG, NULL, STRING)
     if value is None:
@@ -215,7 +224,6 @@ def _infer_literal_type(value) -> DataType:
         return DOUBLE
     if isinstance(value, (str, bytes)):
         return STRING
-    import datetime
     if isinstance(value, datetime.date) and not isinstance(value, datetime.datetime):
         from ..types import DATE
         return DATE

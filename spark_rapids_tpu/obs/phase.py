@@ -74,6 +74,8 @@ PHASES = (
                            # and unpack enqueue (columnar/upload.py)
     "device-wait",         # blocking device->host reads
                            # (columnar/transfer.py, TpuExec.collect)
+    "join-build",          # a fused join's build side: child drain,
+                           # concat, decode enqueue (exec/stage_compiler)
     "other",               # derived remainder — never negative
 )
 

@@ -138,8 +138,8 @@ class BuildTable:
 
     def __init__(self, bucket_table, perm, valid_count, num_rows,
                  key_cols: Sequence[Column], payload: Sequence[Column],
-                 capacity: int, payload_prefix: Sequence = (),
-                 pair_table=None, pack=None, key_lanes=None):
+                 capacity: int, pair_table=None, pack=None,
+                 key_lanes=None):
         self.bucket_table = bucket_table  # (2^B + 1,) int32 offsets
         self.perm = perm  # sorted position -> original build row
         self.valid_count = valid_count
@@ -147,12 +147,11 @@ class BuildTable:
         self.key_cols = list(key_cols)
         self.payload = list(payload)
         self.capacity = capacity
-        # per STRING payload column (payload order): (capacity+1,) int64
-        # prefix sum of row byte lengths in sorted order — sizes the join's
-        # string output buckets without per-stream-batch recomputation
-        self.payload_prefix = tuple(payload_prefix)
-        # (2^B, 2) int32 [lo, hi) per bucket: ONE row gather per probe
-        # instead of two offset-table gathers (round 4)
+        # (2^B, 2 + k) int32 per bucket: [lo, hi) and, for each of the k
+        # variable-size payload columns (payload order), the bytes or
+        # elements the range holds, which size the join's output buckets:
+        # ONE row gather per probe instead of two offset-table gathers
+        # (round 4) and 2k i64 prefix-sum gathers (ISSUE 31)
         self.pair_table = pair_table
         # (plan_k, kmat_sorted, kfmat_sorted, plan_p, pmat_sorted,
         #  pfmat_sorted, key_pack_idx, payload_pack_idx,
@@ -201,17 +200,29 @@ class BuildTable:
         bucket_table = jnp.concatenate(
             [jnp.zeros((1,), jnp.int32),
              jnp.cumsum(counts, dtype=jnp.int32)])
-        pair_table = jnp.stack([bucket_table[:-1], bucket_table[1:]], axis=1)
-        prefixes = []
+        # one row per bucket: [lo, hi) and, per variable-size payload
+        # column, the payload its range holds, from the prefix sum of the
+        # row sizes in sorted order (a column's offsets are i32, so a
+        # range's size fits one i32 lane). A probe sizes its output from
+        # the SAME row gather that finds its range: gathering i64 prefix
+        # sums at both ends of every stream row's range instead was four
+        # u32 gathers of the stream's width, one of them from a table the
+        # compiler left in HBM whose time differed from process to
+        # process (TPC-H Q14, ISSUE 31)
+        lanes = [bucket_table[:-1], bucket_table[1:]]
         for c in payload:
             if isinstance(c, (StringColumn, ArrayColumn)):
                 if isinstance(c, ArrayColumn):
-                    lens = (c.offsets[1:] - c.offsets[:-1]).astype(jnp.int64)
+                    lens = c.offsets[1:] - c.offsets[:-1]
                 else:
-                    lens = string_lengths(c).astype(jnp.int64)
+                    lens = string_lengths(c)
                 sorted_lens = jnp.where(iota < valid_count, lens[perm], 0)
-                prefixes.append(jnp.concatenate(
-                    [jnp.zeros((1,), jnp.int64), jnp.cumsum(sorted_lens)]))
+                prefix = jnp.concatenate(
+                    [jnp.zeros((1,), jnp.int32),
+                     jnp.cumsum(sorted_lens, dtype=jnp.int32)])
+                lanes.append(prefix[bucket_table[1:]]
+                             - prefix[bucket_table[:-1]])
+        pair_table = jnp.stack(lanes, axis=1)
         # pack fixed-width keys and payload into SEPARATE sorted-order
         # matrices (round 8): the key pack serves the candidate-level
         # verify, the payload pack is gathered once at output level.
@@ -237,7 +248,7 @@ class BuildTable:
             lanes, kvalid = kl
             key_lanes = (tuple(ln[perm] for ln in lanes), kvalid[perm])
         return BuildTable(bucket_table, perm, valid_count,
-                          num_rows, key_cols, payload, capacity, prefixes,
+                          num_rows, key_cols, payload, capacity,
                           pair_table, pack, key_lanes)
 
 
@@ -245,7 +256,7 @@ def _bt_flatten(bt: BuildTable):
     (plan_k, kmat_s, kfmat_s, plan_p, pmat_s, pfmat_s,
      kpi, ppi, poi) = bt.pack
     return ((bt.bucket_table, bt.perm, bt.valid_count, bt.num_rows,
-             tuple(bt.key_cols), tuple(bt.payload), bt.payload_prefix,
+             tuple(bt.key_cols), tuple(bt.payload),
              bt.pair_table, kmat_s, kfmat_s, pmat_s, pfmat_s,
              bt.key_lanes),
             (bt.capacity, plan_k, plan_p, kpi, ppi, poi))
@@ -254,11 +265,11 @@ def _bt_flatten(bt: BuildTable):
 def _bt_unflatten(aux, children):
     capacity, plan_k, plan_p, kpi, ppi, poi = aux
     (bucket_table, perm, valid_count, num_rows, key_cols, payload,
-     payload_prefix, pair_table, kmat_s, kfmat_s, pmat_s, pfmat_s,
+     pair_table, kmat_s, kfmat_s, pmat_s, pfmat_s,
      key_lanes) = children
     return BuildTable(bucket_table, perm, valid_count, num_rows,
                       list(key_cols), list(payload), capacity,
-                      payload_prefix, pair_table,
+                      pair_table,
                       (plan_k, kmat_s, kfmat_s, plan_p, pmat_s, pfmat_s,
                        kpi, ppi, poi), key_lanes)
 
@@ -271,21 +282,29 @@ def probe_counts(build: BuildTable, stream_keys: Sequence[Column],
     """Per-stream-row candidate range (lo, hi) in the bucketed build
     table: two offset-table gathers; bucket-mates with different keys
     are dropped by the key-verify pass downstream."""
+    return probe_ranges(build, stream_keys, stream_rows, stream_cap)[:3]
+
+
+def probe_ranges(build: BuildTable, stream_keys: Sequence[Column],
+                 stream_rows, stream_cap: int):
+    """`probe_counts` plus, per variable-size payload column of the
+    build side, each stream row's candidate payload size (i32; 0 for a
+    row without candidates): (lo, counts, valid, range_sizes). ONE row
+    gather of the pair table finds it all."""
     valid = _keys_valid(stream_keys, stream_rows, stream_cap)
     h_hi, _ = join_hash_pair(stream_keys, lo_too=False)
     B = _bucket_bits(build.capacity)
     b = (h_hi >> jnp.uint32(32 - B)).astype(jnp.int32)
-    if build.pair_table is not None:
-        # ONE row gather for [lo, hi) (round 4; two offset gathers before)
-        pair = build.pair_table[b]
-        lo = pair[:, 0]
-        hi = jnp.minimum(pair[:, 1], build.valid_count)
-    else:
-        lo = build.bucket_table[b]
-        hi = jnp.minimum(build.bucket_table[b + 1], build.valid_count)
+    pair = build.pair_table[b]
+    lo = pair[:, 0]
+    hi = jnp.minimum(pair[:, 1], build.valid_count)
+    # buckets hold valid rows only, so hi <= valid_count and the range's
+    # size is the bucket's
+    sizes = tuple(jnp.where(valid, pair[:, 2 + i], 0)
+                  for i in range(pair.shape[1] - 2))
     lo = jnp.minimum(lo, hi)
     counts = jnp.where(valid, hi - lo, 0)
-    return lo, counts, valid
+    return lo, counts, valid, sizes
 
 
 def expand_candidates(lo, counts, out_capacity: int):
