@@ -244,9 +244,7 @@ def gather_attribution():
     """{"gather": ...} block for each BENCH record: materializing row
     gathers this lane dispatched, how many rode a packed (multi-column)
     row gather, and the estimated bytes moved (ops/gather.py counters,
-    as deltas since the previous record). pallas_count distinguishes
-    DMA-kernel-served gathers from the XLA fallback — without it a
-    throughput delta can't be attributed."""
+    as deltas since the previous record)."""
     from spark_rapids_tpu.ops import gather as gather_engine
     return _delta_since("gather", gather_engine.counters())
 

@@ -223,35 +223,18 @@ def dictionary_from_arrow(arr, dt: DataType) -> Optional[DictionaryColumn]:
 
 
 # ---------------------------------------------------------------------------
-# code-indexed gather of a per-dictionary precomputed table — the
-# `dict_gather` measured-tier lane (kern_bench family; the Pallas side
-# reuses the ops/pallas_gather DMA row-gather with the table as a
-# one-lane matrix)
+# code-indexed gather of a per-dictionary precomputed table
 # ---------------------------------------------------------------------------
 
 
 def dict_take(table, codes):
     """out[i] = table[clip(codes[i])] for a per-dictionary table
-    (precomputed hashes, a literal's hit mask). Tier-selected between
-    the XLA take and the Pallas DMA gather; accounted on the gather
+    (precomputed hashes, a literal's hit mask), accounted on the gather
     engine (a code-indexed take IS a row gather)."""
-    n = int(table.shape[0])
-    rows = int(codes.shape[0])
-    safe = jnp.clip(codes, 0, n - 1)
-    use_pallas = False
-    if rows and n:
-        from ..ops.pallas_tier import fused_tier_enabled
-        use_pallas = fused_tier_enabled("dict_gather", (rows, n))
     from ..ops import gather as gather_engine
-    gather_engine.record(1, pallas=use_pallas,
-                         nbytes=rows * int(np.dtype(table.dtype).itemsize))
-    if use_pallas:
-        from ..ops.pallas_gather import dma_row_gather
-        from ..ops.pallas_kernels import on_tpu
-        mat = table.astype(jnp.uint32).reshape(n, 1)
-        out = dma_row_gather(mat, safe, interpret=not on_tpu())[:, 0]
-        return out.astype(table.dtype)
-    return table[safe]
+    gather_engine.record(1, nbytes=int(codes.shape[0])
+                         * int(np.dtype(table.dtype).itemsize))
+    return table[jnp.clip(codes, 0, int(table.shape[0]) - 1)]
 
 
 def dictionary_hashes(col: DictionaryColumn, seed: int):

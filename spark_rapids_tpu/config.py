@@ -89,11 +89,6 @@ SQL_ENABLED = conf_bool(
     "Master toggle: when false every operator stays on the CPU path "
     "(reference RapidsConf.scala SQL_ENABLED).", commonly_used=True)
 
-EXPLAIN = conf_str(
-    "spark.rapids.sql.explain", "NOT_ON_GPU",
-    "Explain mode: NONE, NOT_ON_GPU (log why operators fell back), ALL "
-    "(reference sql.explain).", commonly_used=True)
-
 BATCH_SIZE_BYTES = conf_bytes(
     "spark.rapids.sql.batchSizeBytes", 1 << 30,
     "Target output batch size; on TPU this is the target *padded capacity "
@@ -106,10 +101,6 @@ EXCHANGE_ROUND_BYTES = conf_bytes(
     "spillable staging instead of materializing the whole stage input "
     "(round-2 verdict item 6; reference bounds the same path with "
     "spillable shuffle buffers).")
-
-MAX_READER_BATCH_SIZE_ROWS = conf_int(
-    "spark.rapids.sql.reader.batchSizeRows", 1 << 20,
-    "Soft cap on rows per scan batch (reference reader.batchSizeRows).")
 
 CONCURRENT_TPU_TASKS = conf_int(
     "spark.rapids.sql.concurrentGpuTasks", 2,
@@ -180,23 +171,6 @@ PALLAS_ENABLED = conf_bool(
     "Off-TPU backends always use the XLA path; tests drive the kernel "
     "via the Pallas interpreter for bit-exactness.")
 
-PALLAS_FUSED_TIER = conf_str(
-    "spark.rapids.tpu.pallas.fusedTier", "auto",
-    "Fused Pallas kernel tier for the join-probe and scan-aggregate hot "
-    "paths: 'off' keeps the XLA formulations, 'on' forces the fused "
-    "kernels (interpret-mode off-TPU — the correctness/test setting), "
-    "'auto' (default) consults the per-shape-bucket XLA-vs-Pallas "
-    "timings recorded by tools/kern_bench.py and picks the measured "
-    "winner; with no recorded measurement for a shape the XLA tier "
-    "stays — the tier choice is a measurement, not a guess.",
-    commonly_used=True)
-
-PALLAS_FUSED_BENCH_FILE = conf_str(
-    "spark.rapids.tpu.pallas.fusedTier.benchFile", "",
-    "Path of the kernel-microbenchmark record file driving "
-    "fusedTier=auto (written by tools/kern_bench.py). Empty = "
-    "tools/kern_bench.json next to the package if present.")
-
 DEBUG_DUMP_PATH = conf_str(
     "spark.rapids.sql.debug.dumpPath", "",
     "When set, operators wrapped in dump_on_error write their input "
@@ -246,10 +220,9 @@ SHUFFLE_DEVICE_PARTITION = conf_bool(
     "shuffle writer (exec/exchange.py + ops/partition_split.py): the "
     "hash/roundrobin/single lanes compute per-partition counts and a "
     "pid-stable permutation on device, reorder the batch into "
-    "partition-major order through the gather engine (ops/gather.py — "
-    "tier-aware: the Pallas DMA gather when the `gather` family has a "
-    "recorded win, the XLA packed row gather otherwise), land it on the "
-    "host as ONE packed D2H copy (columnar/transfer.py) and serialize "
+    "partition-major order through the gather engine (ops/gather.py: "
+    "one packed row gather), land it on the host as ONE packed D2H copy "
+    "(columnar/transfer.py) and serialize "
     "each partition directly from a row-range slice "
     "(shuffle/serializer.serialize_slice) — zero host-side row gathers "
     "per written batch (the reference's GpuHashPartitioning + "
@@ -402,7 +375,7 @@ EVENT_LOG_ENABLED = conf_bool(
     "Write the structured JSONL query event log (obs/events.py): query "
     "begin/end, per-operator open/batch/close spans with wall-ns and "
     "row/byte counts, semaphore waits, spill and OOM-retry events, "
-    "Pallas tier decisions, plan fallback reasons, exchange transfer "
+    "plan fallback reasons, exchange transfer "
     "volumes. Off (default) costs one pointer check per batch — the "
     "analog of the reference's Spark-event/NVTX metric stream.",
     commonly_used=True)
@@ -763,12 +736,12 @@ ADAPTIVE_COALESCE_TARGET_BYTES = conf_bytes(
 BREAKER_ENABLED = conf_bool(
     "spark.rapids.tpu.breaker.enabled", False,
     "Degradation circuit breakers (exec/lifecycle.py): track classified-"
-    "transient failures per fault domain (pallas_fused / pallas_join / "
-    "device_dispatch); after breaker.threshold failures inside "
+    "transient failures per fault domain (pallas_hash / device_dispatch "
+    "/ ici_exchange / adaptive); after breaker.threshold failures inside "
     "breaker.windowMs a domain's breaker opens and the domain is "
-    "demoted to its safe path (the XLA kernel tier) for "
+    "demoted to its safe path (docs/robustness.md lists each) for "
     "breaker.cooldownMs, then half-opens for one probe. Off (default): "
-    "failure recording is skipped entirely and every tier consult is "
+    "failure recording is skipped entirely and every breaker consult is "
     "one empty-dict check.")
 
 BREAKER_THRESHOLD = conf_int(
@@ -866,9 +839,9 @@ STAGE_FUSION_ENABLED = conf_bool(
     "sites drawn from the plan-fingerprint program cache so a reused "
     "plan's second collect() is all jit cache hits. Non-whitelisted "
     "operators (exchanges, sorts, UDFs, windows) break the stage and "
-    "keep their per-operator execs. An open device_dispatch / "
-    "pallas_fused circuit breaker demotes a stage back to per-operator "
-    "execution. Off: the converted tree runs unchanged and exec "
+    "keep their per-operator execs. An open device_dispatch circuit "
+    "breaker demotes a stage back to per-operator execution. Off: the "
+    "converted tree runs unchanged and exec "
     "program sites stay per-instance — CPU results are identical "
     "either way (tier-1 asserted).", commonly_used=True)
 

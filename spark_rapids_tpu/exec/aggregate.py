@@ -143,27 +143,6 @@ class AggregateExec(TpuExec):
             self._fused_steps = list(reversed(steps))
             self._source = node
 
-        # fused Pallas tier (ISSUE 1): compile the absorbed operator
-        # chain for the one-kernel scan-filter-project-partial-aggregate
-        # when every expression is in the whitelisted elementwise subset;
-        # the measured tier selector decides per shape at trace time
-        self._pallas_agg_spec = None
-        if mode != "final" and self._masked_ok and self.group_exprs:
-            try:
-                from ..ops.pallas_fused import compile_scan_agg_spec
-                agg_op_slots = []
-                for i, (fn, _) in enumerate(self.aggregates):
-                    for (op, slot) in fn.update_ops():
-                        agg_op_slots.append(
-                            (op, self._input_slots[i][slot]
-                             if slot is not None else None))
-                self._pallas_agg_spec = compile_scan_agg_spec(
-                    self._fused_steps, self._pre_bound, self._pre_schema,
-                    self._key_count, agg_op_slots,
-                    self._source.output_schema)
-            except Exception:  # noqa: BLE001 — tier is best-effort
-                self._pallas_agg_spec = None
-
         # round 5: when the child contract (output_grouped_by) already
         # groups rows by this aggregate's keys — e.g. the inner join's
         # key-grouped emission — the exact tier skips its batch sort
@@ -172,7 +151,7 @@ class AggregateExec(TpuExec):
 
         # program sites, built LAST (ISSUE 14): the plan fingerprint
         # the site cache keys on must see the final semantic fields
-        # (fused steps, pallas spec, pre-grouped contract) — a site
+        # (fused steps, pre-grouped contract) — a site
         # built earlier would fingerprint a half-constructed node.
         # Compiled-kernel jit caches key on capacity bucket + string
         # words; the site cache keys whole instances across collects.
@@ -242,7 +221,7 @@ class AggregateExec(TpuExec):
                 self._slots, self._rounds, self._spec_enabled,
                 self._fusion_enabled,
                 tuple(step_key(s) for s in self._fused_steps),
-                self._pallas_agg_spec is not None, self._pre_grouped)
+                self._pre_grouped)
 
     def _input_pre_grouped(self) -> bool:
         from ..expr.core import UnresolvedAttribute
@@ -382,26 +361,7 @@ class AggregateExec(TpuExec):
         from ..ops.maskedagg import masked_groupby, masked_reduce
         out_cap = self._small_cap()
 
-        use_pallas = False
-        if self.mode != "final" and self._pallas_agg_spec is not None:
-            from ..ops.pallas_tier import fused_tier_enabled
-            use_pallas = fused_tier_enabled("scan_agg", (batch.capacity,))
-
-        if use_pallas:
-            # ONE Pallas kernel: scan tiles -> filter -> project ->
-            # masked-bucket partials, no intermediate column in HBM
-            # (ops/pallas_fused.py); dirty buckets raise the same
-            # speculation flag as the XLA masked tier
-            from ..ops.pallas_fused import fused_scan_agg_update
-            from ..ops.pallas_kernels import on_tpu
-            out_keys, results, num_groups, leftover = \
-                fused_scan_agg_update(
-                    self._pallas_agg_spec, batch,
-                    min(32, self._slots), out_cap,
-                    interpret=not on_tpu())
-            flag = flag | leftover
-            part = self._build_small_batch(out_keys, results, num_groups)
-        elif self.mode == "final":
+        if self.mode == "final":
             cur, mask = batch, None
             keys, agg_inputs = self._merge_inputs(batch)
         else:
@@ -410,9 +370,7 @@ class AggregateExec(TpuExec):
             keys, agg_inputs = self._update_inputs(pre)
             cur = pre
 
-        if use_pallas:
-            pass
-        elif not keys:
+        if not keys:
             results = [("raw", r) for r in masked_reduce(
                 agg_inputs, cur.num_rows, mask, out_cap)]
             part = self._build_small_batch([], results, jnp.int32(1))

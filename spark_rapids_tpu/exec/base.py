@@ -305,8 +305,8 @@ class TpuExec:
         points, module-site dispatch metric attribution — bind HERE,
         around the one program call, at the stage boundary. Lifecycle
         cancellation ticks already live at the TpuExec._drive batch
-        boundary, and breaker engagement is noted at trace time by the
-        tier selector, so the PR 5/6 contracts hold at stage
+        boundary, and breaker engagement is noted at trace time where a
+        domain is consulted, so the PR 5/6 contracts hold at stage
         granularity. Returns a context manager; plain per-op paths and
         CompiledStageExec route through the same helper so every wired
         boundary changes together."""

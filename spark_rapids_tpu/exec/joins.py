@@ -33,7 +33,7 @@ from ..ops.basic import active_mask, compaction_order, gather_column
 from ..ops.strings import string_equal
 from ..ops.join import (
     BuildTable, cross_pairs, expand_candidates, gather_column_indices,
-    inner_gather_maps, int_key_lanes, matched_flags, outer_extend_maps,
+    inner_gather_maps, matched_flags, outer_extend_maps,
     probe_ranges, unmatched_indices, verify_pairs,
 )
 from ..types import BooleanType, Schema, StructField
@@ -59,8 +59,8 @@ def _gather_batch(columns: Sequence[Column], idx, n,
     Fixed-width columns ride ONE packed row gather (XLA's per-gather
     loop cost dwarfs its per-byte cost on v5e), varlen columns keep the
     per-column path — both routed through the gather engine
-    (ops/gather.gather_batch_columns) so the measured Pallas tier and
-    the structural numGathers accounting cover every join emit."""
+    (ops/gather.gather_batch_columns) so the structural numGathers
+    accounting covers every join emit."""
     from ..ops.gather import gather_batch_columns
     return gather_batch_columns(columns, idx, num_rows=n,
                                 byte_caps=byte_caps)
@@ -183,7 +183,7 @@ class HashJoinExec(TpuExec):
                                       label="HashJoinExec.counts")
         self._jit_probe = self._site(self._probe_kernel,
                                      label="HashJoinExec.probe",
-                                     static_argnums=(5, 6, 7, 8))
+                                     static_argnums=(5, 6, 7))
 
     @property
     def consumes_encoded(self) -> bool:
@@ -341,14 +341,8 @@ class HashJoinExec(TpuExec):
         if self._build_filter is not None:
             key_cols = self._mask_keys(
                 key_cols, self._filter_mask(self._build_filter, batch))
-        # prepare the fused probe's key-lane tables only when the tier
-        # selector could ever pick the Pallas kernel (off / auto-without-
-        # a-recorded-win joins pay nothing for them)
-        from ..ops.pallas_tier import family_may_engage
         return BuildTable.build(key_cols, list(batch.columns),
-                                batch.num_rows, batch.capacity,
-                                with_key_lanes=family_may_engage(
-                                    "join_probe"))
+                                batch.num_rows, batch.capacity)
 
     def _build(self) -> Tuple[BuildTable, ColumnarBatch]:
         build_child = self.children[1] if self.build_side == "right" \
@@ -423,27 +417,19 @@ class HashJoinExec(TpuExec):
 
     def _probe_kernel(self, build: BuildTable, build_batch: ColumnarBatch,
                       stream_batch: ColumnarBatch, lo_counts, build_matched,
-                      cand_cap: int, s_caps: Tuple = (), b_caps: Tuple = (),
-                      use_fused: bool = False):
+                      cand_cap: int, s_caps: Tuple = (), b_caps: Tuple = ()):
         """Packed-row probe (round 4): the build side's fixed-width
         keys+payload live in ONE sorted u32 matrix (+ f64 matrix), so the
         whole candidate-verify-compact-emit pipeline is a handful of row
         gathers instead of 2 gathers per column (reference JoinGatherer
         gathers; measured ~20x on the q3 shape, tools/exp_gather.py).
 
-        use_fused (static, chosen by the measured tier selector): the
-        expand+verify stage runs as ONE Pallas kernel streaming candidate
-        tiles through VMEM (ops/pallas_join.fused_probe_verify) instead
-        of separate XLA programs with candidate-level full-width
-        intermediates.
-
-        Gather elimination (round 8): BOTH tiers now defer the payload
-        to ONE output-level packed gather per side after compaction —
-        the candidate level touches only key lanes (XLA tier) or
-        nothing (fused tier). Per iteration the emit is one index
-        materialization + one packed payload gather per side, counted
-        structurally by the gather engine (ops/gather) into the
-        numGathers metric."""
+        Gather elimination (round 8): the payload is deferred to ONE
+        output-level packed gather per side after compaction — the
+        candidate level touches only key lanes. Per iteration the emit
+        is one index materialization + one packed payload gather per
+        side, counted structurally by the gather engine (ops/gather)
+        into the numGathers metric."""
         from ..ops import gather as G
         from ..ops.rowpack import pack_rows, unpack_rows
         lo, counts, skey_cols = lo_counts
@@ -454,95 +440,70 @@ class HashJoinExec(TpuExec):
         (plan_k, kmat_b, kfmat_b, plan_p, pmat_b, pfmat_b,
          kpi, ppi, poi) = build.pack
 
-        # structural eligibility is static per trace: integer keys on
-        # both sides with matching lane widths, i32 candidate space
-        sk_lanes_v = int_key_lanes(skey_cols) if use_fused else None
-        fused = (use_fused and build.key_lanes is not None
-                 and sk_lanes_v is not None
-                 and len(sk_lanes_v[0]) == len(build.key_lanes[0])
-                 and cand_cap < (1 << 31))
+        s_idx, b_pos, total_dev = expand_candidates(lo, counts, cand_cap)
+        pair_valid = s_idx >= 0
+        b_pos_m = jnp.where(pair_valid, b_pos, -1)
 
-        if fused:
-            from ..ops.pallas_join import fused_probe_verify
-            from ..ops.pallas_kernels import on_tpu
-            bk_lanes, bvalid = build.key_lanes
-            sk_lanes, svalid = sk_lanes_v
-            verified, s_idx, b_pos, b_row = fused_probe_verify(
-                lo, counts, bk_lanes, bvalid, sk_lanes, svalid,
-                build.perm, cand_cap, interpret=not on_tpu())
-            total_dev = jnp.sum(counts.astype(jnp.int64)) \
-                if counts.shape[0] else jnp.int64(0)
-            pair_valid = s_idx >= 0
-            b_pos_m = jnp.where(pair_valid, b_pos, -1)
-            need_b_row = True  # the kernel emits it in the same pass
-            ki_c = kf_c = None
-        else:
-            s_idx, b_pos, total_dev = expand_candidates(lo, counts,
-                                                        cand_cap)
-            pair_valid = s_idx >= 0
-            b_pos_m = jnp.where(pair_valid, b_pos, -1)
+        # --- verify: keys packable on BOTH sides compare via
+        # KEY-ONLY candidate-level row gathers (the payload no
+        # longer rides them), the rest via the per-column path ---
+        from ..ops.rowpack import is_packable
+        kpi_pos = {ki: pos for pos, ki in enumerate(kpi)}
+        pk = [ki for ki in kpi if is_packable(skey_cols[ki])]
 
-            # --- verify: keys packable on BOTH sides compare via
-            # KEY-ONLY candidate-level row gathers (the payload no
-            # longer rides them), the rest via the per-column path ---
-            from ..ops.rowpack import is_packable
-            kpi_pos = {ki: pos for pos, ki in enumerate(kpi)}
-            pk = [ki for ki in kpi if is_packable(skey_cols[ki])]
-
-            # sorted position -> original build row; only needed for
-            # varlen columns, fallback keys and residual conditions
-            need_b_row = bool(poi) or self.condition is not None or \
-                len(pk) < len(skey_cols)
-            b_row = gather_column_indices(build.perm, b_pos_m) \
-                if need_b_row else None
-            ok = pair_valid
-            ki_c = kf_c = None
-            if pk:
-                ki_c, kf_c = G.gather_rows(plan_k, kmat_b, kfmat_b,
-                                           b_pos_m)
-                bk_cand = unpack_rows(plan_k, ki_c, kf_c,
-                                      only=[kpi_pos[ki] for ki in pk])
-                plan_sk, imat_sk, fmat_sk = pack_rows(
-                    [skey_cols[ki] for ki in pk])
-                ski_c, skf_c = G.gather_rows(
-                    plan_sk, imat_sk, fmat_sk,
+        # sorted position -> original build row; only needed for
+        # varlen columns, fallback keys and residual conditions
+        need_b_row = bool(poi) or self.condition is not None or \
+            len(pk) < len(skey_cols)
+        b_row = gather_column_indices(build.perm, b_pos_m) \
+            if need_b_row else None
+        ok = pair_valid
+        ki_c = kf_c = None
+        if pk:
+            ki_c, kf_c = G.gather_rows(plan_k, kmat_b, kfmat_b, b_pos_m)
+            bk_cand = unpack_rows(plan_k, ki_c, kf_c,
+                                  only=[kpi_pos[ki] for ki in pk])
+            plan_sk, imat_sk, fmat_sk = pack_rows(
+                [skey_cols[ki] for ki in pk])
+            ski_c, skf_c = G.gather_rows(
+                plan_sk, imat_sk, fmat_sk,
+                jnp.where(pair_valid, s_idx, -1))
+            sk_cand = unpack_rows(plan_sk, ski_c, skf_c)
+            for b, s in zip(bk_cand, sk_cand):
+                ok = ok & (b.data == s.data) & b.validity & s.validity
+        pk_set = set(pk)
+        for ki in range(len(skey_cols)):
+            if ki in pk_set:
+                continue
+            bk = build.key_cols[ki]
+            sk = skey_cols[ki]
+            if isinstance(bk, DictionaryColumn) or \
+                    isinstance(sk, DictionaryColumn):
+                # encoded key (ISSUE 18): byte-compare through
+                # spans into the ORIGINAL buffers — no decode, and
+                # no materialized candidate gather (whose byte
+                # bucket a join fan-out overflows)
+                from ..columnar.encoded import bytes_equal_at
+                ok = ok & bytes_equal_at(
+                    bk, b_row, sk,
                     jnp.where(pair_valid, s_idx, -1))
-                sk_cand = unpack_rows(plan_sk, ski_c, skf_c)
-                for b, s in zip(bk_cand, sk_cand):
-                    ok = ok & (b.data == s.data) & b.validity & s.validity
-            pk_set = set(pk)
-            for ki in range(len(skey_cols)):
-                if ki in pk_set:
-                    continue
-                bk = build.key_cols[ki]
-                sk = skey_cols[ki]
-                if isinstance(bk, DictionaryColumn) or \
-                        isinstance(sk, DictionaryColumn):
-                    # encoded key (ISSUE 18): byte-compare through
-                    # spans into the ORIGINAL buffers — no decode, and
-                    # no materialized candidate gather (whose byte
-                    # bucket a join fan-out overflows)
-                    from ..columnar.encoded import bytes_equal_at
-                    ok = ok & bytes_equal_at(
-                        bk, b_row, sk,
-                        jnp.where(pair_valid, s_idx, -1))
-                    continue
-                b = gather_column(bk, b_row)
-                s = gather_column(sk, jnp.where(pair_valid, s_idx, -1))
-                if isinstance(bk, StringColumn):
-                    eq = string_equal(b, s)
-                    ok = ok & eq.data & eq.validity
+                continue
+            b = gather_column(bk, b_row)
+            s = gather_column(sk, jnp.where(pair_valid, s_idx, -1))
+            if isinstance(bk, StringColumn):
+                eq = string_equal(b, s)
+                ok = ok & eq.data & eq.validity
+            else:
+                from ..columnar.column import Decimal128Column
+                if isinstance(bk, Decimal128Column):
+                    # two-limb equality (round 5: decimal128 keys)
+                    ok = ok & (b.hi.data == s.hi.data) \
+                        & (b.lo.data == s.lo.data) \
+                        & b.validity & s.validity
                 else:
-                    from ..columnar.column import Decimal128Column
-                    if isinstance(bk, Decimal128Column):
-                        # two-limb equality (round 5: decimal128 keys)
-                        ok = ok & (b.hi.data == s.hi.data) \
-                            & (b.lo.data == s.lo.data) \
-                            & b.validity & s.validity
-                    else:
-                        ok = ok & (b.data == s.data) \
-                            & b.validity & s.validity
-            verified = ok
+                    ok = ok & (b.data == s.data) \
+                        & b.validity & s.validity
+        verified = ok
         if self.condition is not None:
             verified = verified & self._eval_condition(
                 stream_batch, build_batch, s_idx, b_row, cand_cap,
@@ -575,7 +536,7 @@ class HashJoinExec(TpuExec):
         # (pk == kpi whenever every key is fixed-width, the same
         # condition output_grouped_by promises grouping under)
         grouped_emit = jt == INNER and len(kpi) == len(skey_cols) \
-            and (fused or len(pk) == len(kpi))
+            and len(pk) == len(kpi)
         if grouped_emit:
             # key-grouped emission (round 5): carry the packed build-key
             # lanes as extra sort keys so equal join keys land contiguous
@@ -586,29 +547,19 @@ class HashJoinExec(TpuExec):
             # sharing a 64-bit hash could interleave by position.
             act_c = active_mask(total_dev, cand_cap)
             kflag = verified & act_c
-            if fused:
-                # the fused probe never materialized candidate-level key
-                # gathers; the sort lanes come straight from the
-                # VMEM-resident u32 key-lane tables (any consistent total
-                # order over key bit patterns groups equal keys)
-                safe_c = jnp.clip(b_pos_m, 0,
-                                  build.key_lanes[0][0].shape[0] - 1)
-                klanes = [jnp.where(kflag, ln[safe_c], jnp.uint32(0))
-                          for ln in build.key_lanes[0]]
-            else:
-                # key lanes from the candidate-level KEY pack (already
-                # gathered for the verify above)
-                nvl = plan_k.n_valid_lanes
-                klanes = []
-                for pos in range(len(kpi)):
-                    kind, lane = plan_k.kinds[pos]
-                    if kind == "f64":
-                        klanes.append(kf_c[:, lane])
-                    elif kind == "w2":
-                        klanes.append(ki_c[:, nvl + lane])
-                        klanes.append(ki_c[:, nvl + lane + 1])
-                    else:
-                        klanes.append(ki_c[:, nvl + lane])
+            # key lanes from the candidate-level KEY pack (already
+            # gathered for the verify above)
+            nvl = plan_k.n_valid_lanes
+            klanes = []
+            for pos in range(len(kpi)):
+                kind, lane = plan_k.kinds[pos]
+                if kind == "f64":
+                    klanes.append(kf_c[:, lane])
+                elif kind == "w2":
+                    klanes.append(ki_c[:, nvl + lane])
+                    klanes.append(ki_c[:, nvl + lane + 1])
+                else:
+                    klanes.append(ki_c[:, nvl + lane])
             iota_c = jnp.arange(cand_cap, dtype=jnp.int32)
             res = jax.lax.sort(
                 ((~kflag).astype(jnp.uint32), *klanes, iota_c),
@@ -743,15 +694,12 @@ class HashJoinExec(TpuExec):
                 b_caps = tuple(None if c is None else max(c, o)
                                for c, o in zip(b_caps, ob))
             self._size_cache[key] = (cand_cap, s_caps, b_caps)
-        from ..ops.pallas_tier import fused_tier_enabled
-        use_fused = build.key_lanes is not None and fused_tier_enabled(
-            "join_probe", (stream_batch.capacity, build.capacity))
         with self._gather_track.observe(
                 (stream_batch.capacity, build.capacity, cand_cap,
-                 s_caps, b_caps, use_fused)):
+                 s_caps, b_caps)):
             return self._jit_probe(build, build_batch, stream_batch,
                                    (lo, counts, skey_cols), build_matched,
-                                   cand_cap, s_caps, b_caps, use_fused)
+                                   cand_cap, s_caps, b_caps)
 
     def _emit_build_unmatched(self, build: BuildTable,
                               build_batch: ColumnarBatch, build_matched):

@@ -35,11 +35,11 @@ those contracts for the single-process multi-thread engine:
 * **Circuit breakers** — a sliding failure window per fault domain
   (`BREAKER_DOMAINS`). `exec/task_retry.py` records every
   classified-transient attempt failure against the domains the attempt
-  engaged (the Pallas tiers note engagement at trace time; device-ish
-  errors always implicate `device_dispatch`); at
+  engaged (the murmur3 Pallas kernels note engagement at trace time;
+  device-ish errors always implicate `device_dispatch`); at
   `spark.rapids.tpu.breaker.threshold` failures inside `windowMs` the
-  breaker opens and `ops/pallas_tier.py` demotes the domain to its XLA
-  safe path until a post-cooldown half-open probe succeeds. One
+  breaker opens and the domain's consult (`breaker_allows`) demotes it
+  to its safe path until a post-cooldown half-open probe succeeds. One
   persistently bad kernel path degrades one domain instead of spending
   all of `task.maxAttempts` on every query. `TpuSession.health()`
   surfaces the whole state.
@@ -146,8 +146,8 @@ class QueryContext:
         self._ticks = 0
         self._emit_lock = threading.Lock()
         self._emitted = False
-        #: fault domains this attempt engaged (pallas tiers note at
-        #: trace time); cleared per task attempt by begin_attempt()
+        #: fault domains this attempt engaged (noted at trace or
+        #: dispatch time); cleared per task attempt by begin_attempt()
         self.engaged_domains: set = set()
         #: workload-governor admission ticket (exec/workload.py) —
         #: rides the context so producer threads that adopt_context
@@ -415,12 +415,6 @@ def active_queries(owner: Any = None) -> List[Dict[str, Any]]:
 #: docs/robustness.md domain table is lint-checked against this
 #: registry (tests/test_docs_lint.py), like the fault-point table.
 BREAKER_DOMAINS: Dict[str, str] = {
-    "pallas_fused": "fused scan-filter-project-aggregate Pallas tier "
-                    "(ops/pallas_fused.py) -> XLA formulation",
-    "pallas_join": "fused join-probe Pallas tier (ops/pallas_join.py) "
-                   "-> XLA formulation",
-    "pallas_gather": "DMA row-gather Pallas tier (ops/pallas_gather.py) "
-                     "-> XLA packed row gather (ops/rowpack.py)",
     "pallas_hash": "murmur3 Pallas kernels (ops/pallas_kernels.py) "
                    "-> XLA elementwise murmur3 (ops/hashing.py)",
     "device_dispatch": "guarded device dispatch (memory/retry.py "
@@ -435,28 +429,10 @@ BREAKER_DOMAINS: Dict[str, str] = {
                 "right-sizing) are skipped while open",
 }
 
-#: Pallas kernel family (ops/pallas_tier.PALLAS_FAMILIES) -> breaker
-#: domain; test_docs_lint asserts every family has an entry
+#: Pallas kernel family -> breaker domain (ops/hashing._use_pallas
+#: consults and engages through it)
 FAMILY_DOMAINS: Dict[str, str] = {
-    "scan_agg": "pallas_fused",
-    "join_probe": "pallas_join",
-    "gather": "pallas_gather",
-    # the device shuffle partition split's tiered step IS the packed
-    # row gather (ops/partition_split.py routes through ops/gather), so
-    # it degrades with the same breaker domain
-    "partition_split": "pallas_gather",
     "murmur3": "pallas_hash",
-    # the packed upload's single device copy is a guarded device
-    # dispatch (it rides the device.dispatch fault point); repeated
-    # upload failures implicate the device itself
-    "h2d_upload": "device_dispatch",
-    # the ICI lane degrades as a whole (to the host serialize path),
-    # not kernel-by-kernel: its bench family maps onto its own domain
-    "ici_all_to_all": "ici_exchange",
-    # the encoded lane's code-indexed take (columnar/encoded.dict_take)
-    # is a row gather over the per-dictionary lookup table — it rides
-    # the same Pallas DMA kernel and degrades with the same breaker
-    "dict_gather": "pallas_gather",
 }
 
 BREAKER_STATES = ("closed", "open", "half_open")
@@ -617,7 +593,7 @@ def open_breakers() -> List[str]:
 # -- attempt attribution (exec/task_retry.py hooks) -------------------------
 
 def note_engagement(family: str) -> None:
-    """Trace-time note from ops/pallas_tier.py that a fused kernel
+    """Trace-time note from ops/hashing.py that a Pallas kernel
     family engaged for the current attempt; maps the family onto its
     breaker domain. Lands on the QueryContext when one is governed
     (shared across producer threads), else on a thread-local attempt
@@ -639,8 +615,8 @@ def engage_domain(domain: str) -> None:
     notes `device_dispatch` at its stage boundary so a classified-
     transient failure of the fused execution counts against the domain
     and PR 5 degradation demotes the stage back to per-operator
-    execution. The family-keyed twin (note_engagement) stays the tier
-    selector's surface; this one is for callers that ARE a domain."""
+    execution. The family-keyed twin (note_engagement) is the Pallas
+    kernels' surface; this one is for callers that ARE a domain."""
     if domain not in BREAKER_DOMAINS:
         return
     ctx = getattr(_tls, "ctx", None)

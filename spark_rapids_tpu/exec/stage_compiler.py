@@ -180,7 +180,6 @@ def _digest_entries():
     from .. import config as C
     return (C.FUSION_ENABLED, C.STAGE_FUSION_ENABLED, C.AGG_SPECULATIVE,
             C.AGG_GROUP_SLOTS, C.AGG_ROUNDS, C.PALLAS_ENABLED,
-            C.PALLAS_FUSED_TIER, C.PALLAS_FUSED_BENCH_FILE,
             C.IMPROVED_FLOAT_OPS, C.STABLE_SORT, C.SORT_OOC_ENABLED,
             C.DECIMAL_ENABLED, C.SHUFFLE_DEVICE_PARTITION,
             C.UPLOAD_PACKED, C.BATCH_SIZE_BYTES, C.SCAN_ENCODED)
@@ -205,10 +204,9 @@ def fingerprint_node(node: TpuExec, extras) -> Optional[str]:
     node's semantic extras + output-schema signature + every child's
     fingerprint + the conf digest. Equal fingerprints MUST imply
     byte-identical traces — that is the program cache's soundness
-    contract (trace-time tier consults that read mutable state outside
-    the digest — a kern_bench file edited mid-process, a breaker
-    opening — bake per compiled shape, exactly as they already did
-    under bench-style plan reuse)."""
+    contract (a trace-time consult that reads mutable state outside
+    the digest — a breaker opening — bakes per compiled shape,
+    exactly as it already did under bench-style plan reuse)."""
     digest = trace_conf_digest()
     if digest is None:
         return None
@@ -329,11 +327,11 @@ class CompiledStageExec(TpuExec):
             self._jit_step = self._site(
                 self._ja_spec_body,
                 label="CompiledStageExec.probe_step",
-                static_argnums=(5, 6, 7, 8), donate_argnums=(3, 4))
+                static_argnums=(5, 6, 7), donate_argnums=(3, 4))
             self._jit_step_exact = self._site(
                 self._ja_exact_body,
                 label="CompiledStageExec.probe_step_exact",
-                static_argnums=(3, 4, 5, 6))
+                static_argnums=(3, 4, 5))
         _note(stages_fused=1, ops_fused=len(self._absorbed))
 
     # -- TpuExec surface ---------------------------------------------------
@@ -658,7 +656,7 @@ class CompiledStageExec(TpuExec):
         return total, needs
 
     def _probe_in_stage(self, table, build_batch, stream_batch,
-                        cand_cap, s_caps, b_caps, use_fused):
+                        cand_cap, s_caps, b_caps):
         """Traced: counts + probe + emit, plus the speculative-sizing
         overflow flag (the join's _probe_one contract, in-program)."""
         import jax.numpy as jnp
@@ -667,7 +665,7 @@ class CompiledStageExec(TpuExec):
         zeros = jnp.zeros((table.capacity,), jnp.bool_)
         out, _bm = self._join._probe_kernel(
             table, build_batch, stream_batch, (lo, counts, skey_cols),
-            zeros, cand_cap, s_caps, b_caps, use_fused)
+            zeros, cand_cap, s_caps, b_caps)
         flag = total > cand_cap
         s_needs, b_needs = needs
         for need, cap in zip(
@@ -678,23 +676,21 @@ class CompiledStageExec(TpuExec):
         return out, flag
 
     def _ja_spec_body(self, table, build_batch, stream_batch, state,
-                      flag, cand_cap, s_caps, b_caps, use_fused):
+                      flag, cand_cap, s_caps, b_caps):
         if table is None:
             table = self._join._build_kernel(build_batch)
         out, size_flag = self._probe_in_stage(
-            table, build_batch, stream_batch, cand_cap, s_caps, b_caps,
-            use_fused)
+            table, build_batch, stream_batch, cand_cap, s_caps, b_caps)
         state, flag, ev = self._agg._streaming_step(
             out, state, flag | size_flag)
         return table, state, flag, ev
 
     def _ja_exact_body(self, table, build_batch, stream_batch,
-                       cand_cap, s_caps, b_caps, use_fused):
+                       cand_cap, s_caps, b_caps):
         if table is None:
             table = self._join._build_kernel(build_batch)
         out, size_flag = self._probe_in_stage(
-            table, build_batch, stream_batch, cand_cap, s_caps, b_caps,
-            use_fused)
+            table, build_batch, stream_batch, cand_cap, s_caps, b_caps)
         part = self._agg._fused_update_exact(out)
         ev = None if self._agg.mode == "partial" \
             else self._agg._evaluate(part)
@@ -709,16 +705,14 @@ class CompiledStageExec(TpuExec):
         r4): after SPEC_REFRESH warm uses the entry expires and the
         next probe re-measures FRESH — no monotone max — so one
         pathological batch cannot inflate the plan shape's buckets for
-        the process lifetime. Returns ((cand_cap, s_caps, b_caps,
-        use_fused), warm)."""
+        the process lifetime. Returns ((cand_cap, s_caps, b_caps),
+        warm)."""
         import jax
         from ..columnar.column import bucket_capacity
-        from ..ops.pallas_tier import fused_tier_enabled
         from .joins import HashJoinExec, _byte_cap_tuple
         from .speculation import speculation_allowed
         key = (stream_batch.capacity, build_batch.capacity)
         cached = self._size_cache.get(key)
-        use_fused = fused_tier_enabled("join_probe", key)
         known = cached is not None
         if cached is not None and speculation_allowed():
             cached[3] += 1
@@ -726,8 +720,7 @@ class CompiledStageExec(TpuExec):
                 del self._size_cache[key]
                 cached = None
             else:
-                return (cached[0], cached[1], cached[2], use_fused), \
-                    True
+                return (cached[0], cached[1], cached[2]), True
         if known:
             _note(size_cache_refreshes=1)
         else:
@@ -750,7 +743,7 @@ class CompiledStageExec(TpuExec):
             b_caps = tuple(None if c is None else max(c, o)
                            for c, o in zip(b_caps, ob))
         self._size_cache[key] = [cand_cap, s_caps, b_caps, 0]
-        return (cand_cap, s_caps, b_caps, use_fused), False
+        return (cand_cap, s_caps, b_caps), False
 
     def _execute_join_agg(self):
         from ..columnar.batch import empty_batch
@@ -786,7 +779,7 @@ class CompiledStageExec(TpuExec):
             saw = True
             n_in += 1
             live[0] = n_in
-            (cand_cap, s_caps, b_caps, use_fused), warm = \
+            (cand_cap, s_caps, b_caps), warm = \
                 self._sizing(build_batch, stream_batch)
             sp = SpillableBatch.from_batch(stream_batch)
             try:
@@ -801,13 +794,12 @@ class CompiledStageExec(TpuExec):
                                     gather_shape=(
                                         "join_agg", b.capacity,
                                         build_batch.capacity, cand_cap,
-                                        s_caps, b_caps, use_fused),
+                                        s_caps, b_caps),
                                     fault_point="device.dispatch",
                                     fault_key=f"stage:join:{n_in}"):
                                 return self._jit_step(
                                     box[0], build_batch, b, box[1],
-                                    box[2], cand_cap, s_caps, b_caps,
-                                    use_fused)
+                                    box[2], cand_cap, s_caps, b_caps)
                         finally:
                             s.release()
                     for out in with_retry(
@@ -823,12 +815,12 @@ class CompiledStageExec(TpuExec):
                                     gather_shape=(
                                         "join_agg", b.capacity,
                                         build_batch.capacity, cand_cap,
-                                        s_caps, b_caps, use_fused),
+                                        s_caps, b_caps),
                                     fault_point="device.dispatch",
                                     fault_key=f"stage:join:{n_in}"):
                                 return self._jit_step_exact(
                                     table, build_batch, b, cand_cap,
-                                    s_caps, b_caps, use_fused)
+                                    s_caps, b_caps)
                         finally:
                             s.release()
                     for tbl, part, pev, size_flag in with_retry(

@@ -2,8 +2,8 @@
 GpuMetric/GpuTaskMetrics/NVTX stack joined into one subsystem (ISSUE 2):
 
   * `events` — process-wide JSONL event bus (query begin/end, operator
-    spans, semaphore waits, spills, OOM retries, Pallas tier decisions,
-    plan fallbacks, exchange volumes), gated by the
+    spans, semaphore waits, spills, OOM retries, plan fallbacks,
+    exchange volumes), gated by the
     spark.rapids.tpu.eventLog.{enabled,dir,level} confs and costing one
     pointer check per batch when disabled.
   * `span` — op_span(name, phase=, metric=): the ONE span primitive —

@@ -11,9 +11,9 @@ transfer pack, the Pallas kernel families) and records, per compiled
 program:
 
   * a stable program key — (owning exec/family label, arg-shape
-    bucket, backend platform) — the log2 bucket discipline of
-    ops/pallas_tier.shape_bucket, so one key covers every batch that
-    compiles to the same program shape;
+    bucket, backend platform) — log2 buckets per dimension (the
+    engine's capacities are powers of two), so one key covers every
+    batch that compiles to the same program shape;
   * dispatch count, first-trace vs cache-hit discriminated;
   * trace-ns (the Python tracing of the body, measured inside the
     traced function — it only runs when jax actually traces) and
@@ -95,8 +95,13 @@ def _platform() -> str:
 
 
 def _shape_bucket(shape) -> Tuple[int, ...]:
-    from ..ops.pallas_tier import shape_bucket
-    return shape_bucket(shape)
+    """log2-ceiling bucket per dimension (engine capacities are already
+    powers of two, so this is usually exact)."""
+    out = []
+    for s in (shape if isinstance(shape, (tuple, list)) else (shape,)):
+        s = max(int(s), 1)
+        out.append(s.bit_length() - (1 if s & (s - 1) == 0 else 0))
+    return tuple(out)
 
 
 def _args_bucket(args, kwargs) -> Tuple:

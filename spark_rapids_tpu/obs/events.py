@@ -12,7 +12,7 @@ Event kinds and their levels (spark.rapids.tpu.eventLog.level):
              recompile_storm, query_phases, adaptive_demote,
              query_stalled
   MODERATE   op_close, semaphore_acquire, spill, oom_retry,
-             pallas_tier, plan_fallback, plan_not_on_tpu, exchange,
+             plan_fallback, plan_not_on_tpu, exchange,
              pipeline_wait, pipeline_full, op_error, fault_inject,
              io_retry, task_retry, integrity_fail, pipeline_stuck,
              spill_error, spill_writer_dead, task_retry_settle_error,
@@ -53,7 +53,6 @@ EVENT_LEVELS: Dict[str, int] = {
     "semaphore_acquire": MODERATE,
     "spill": MODERATE,
     "oom_retry": MODERATE,
-    "pallas_tier": MODERATE,
     "plan_fallback": MODERATE,
     "plan_not_on_tpu": MODERATE,
     "exchange": MODERATE,
@@ -101,7 +100,7 @@ EVENT_LEVELS: Dict[str, int] = {
     # ingest seam (scan / shuffle / unspill) and the pack+transfer time
     "upload": MODERATE,
     # gather engine (ISSUE 8): one record per wired-exec execution with
-    # its materializing-gather totals (count/packed/pallas/bytes) —
+    # its materializing-gather totals (count/packed/bytes) —
     # reconciles with the numGathers metric and op_close batch counts
     "gather_stats": MODERATE,
     # runtime statistics plane (ISSUE 11): one record per exchange

@@ -149,9 +149,8 @@ def compact_columns(columns: Sequence[Column], keep, num_rows
     Fixed-width columns compact through ONE packed row gather (XLA's
     gather cost on v5e is per-row loop overhead, not bytes — see
     ops/rowpack), routed through the gather engine (ops/gather) so the
-    measured Pallas tier and the structural numGathers accounting cover
-    every compaction in the engine; varlen/nested columns keep the
-    per-column path."""
+    structural numGathers accounting covers every compaction in the
+    engine; varlen/nested columns keep the per-column path."""
     from .gather import gather_batch_columns
     perm, new_rows = compaction_order(keep, num_rows)
     cap = keep.shape[0]

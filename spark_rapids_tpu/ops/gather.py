@@ -1,17 +1,10 @@
-"""The gather engine (ISSUE 8): one routing + accounting chokepoint for
-every materializing row gather in the engine.
+"""The gather engine (ISSUE 8): one accounting chokepoint for every
+materializing row gather in the engine.
 
 Three jobs:
 
-1. **Routing** — `gather_rows` is the tier-aware packed row gather: it
-   serves the call from the Pallas DMA kernel (ops/pallas_gather.py)
-   when the measured tier selector says the kernel wins for this
-   (rows, capacity) shape bucket (`gather` family, ops/pallas_tier.py),
-   else from the XLA formulation (ops/rowpack.py). No record -> XLA, so
-   default CPU behavior is byte-identical to the pre-gather-engine tree.
-   The decision is made on the host at trace time (the established
-   pallas_tier contract); an open `pallas_gather` circuit breaker
-   (exec/lifecycle.FAMILY_DOMAINS) demotes NEW traces to XLA.
+1. **One entry point** — `gather_rows` is the packed row gather every
+   exec calls: the XLA formulation (ops/rowpack.py), recorded.
 
 2. **Structural accounting** — every routed gather records (count,
    packed, bytes-moved estimate) into a thread-local recorder while a
@@ -51,32 +44,28 @@ _tls = threading.local()
 
 class GatherStats:
     """Structural gather totals: number of materializing gathers, how
-    many rode a packed (multi-column) row gather, how many were served
-    by the Pallas DMA kernel, and the estimated bytes moved."""
+    many rode a packed (multi-column) row gather, and the estimated
+    bytes moved."""
 
-    __slots__ = ("count", "packed_count", "pallas_count", "bytes")
+    __slots__ = ("count", "packed_count", "bytes")
 
     def __init__(self, count: int = 0, packed_count: int = 0,
-                 pallas_count: int = 0, nbytes: int = 0):
+                 nbytes: int = 0):
         self.count = count
         self.packed_count = packed_count
-        self.pallas_count = pallas_count
         self.bytes = nbytes
 
     def add(self, other: "GatherStats") -> None:
         self.count += other.count
         self.packed_count += other.packed_count
-        self.pallas_count += other.pallas_count
         self.bytes += other.bytes
 
     def copy(self) -> "GatherStats":
-        return GatherStats(self.count, self.packed_count,
-                           self.pallas_count, self.bytes)
+        return GatherStats(self.count, self.packed_count, self.bytes)
 
     def delta(self, since: "GatherStats") -> "GatherStats":
         return GatherStats(self.count - since.count,
                            self.packed_count - since.packed_count,
-                           self.pallas_count - since.pallas_count,
                            self.bytes - since.bytes)
 
 
@@ -88,11 +77,10 @@ _proc_lock = threading.Lock()
 def counters() -> dict:
     with _proc_lock:
         return {"count": _proc.count, "packed_count": _proc.packed_count,
-                "pallas_count": _proc.pallas_count, "bytes": _proc.bytes}
+                "bytes": _proc.bytes}
 
 
-def record(n: int = 1, packed: bool = False, pallas: bool = False,
-           nbytes: int = 0) -> None:
+def record(n: int = 1, packed: bool = False, nbytes: int = 0) -> None:
     """Note a routed gather on the active recorder (one pointer check
     when no wired exec is observing)."""
     rec = getattr(_tls, "rec", None)
@@ -101,8 +89,6 @@ def record(n: int = 1, packed: bool = False, pallas: bool = False,
     rec.count += n
     if packed:
         rec.packed_count += n
-    if pallas:
-        rec.pallas_count += n
     rec.bytes += nbytes
 
 
@@ -175,7 +161,7 @@ class GatherTracker:
             return
         obs_events.emit("gather_stats", op=op, op_id=op_id,
                         count=delta.count, packed=delta.packed_count,
-                        pallas=delta.pallas_count, bytes=delta.bytes)
+                        bytes=delta.bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -183,27 +169,12 @@ class GatherTracker:
 # ---------------------------------------------------------------------------
 
 
-def _pallas_tier_on(rows: int, cap: int) -> bool:
-    if rows == 0 or cap == 0:
-        return False
-    from .pallas_tier import fused_tier_enabled
-    return fused_tier_enabled("gather", (rows, cap))
-
-
 def gather_rows(plan, imat, fmat, idx):
-    """Tier-aware packed row gather (drop-in for rowpack.gather_rows)."""
-    rows = int(idx.shape[0])
-    cap = int(imat.shape[0])
+    """Packed row gather (rowpack.gather_rows, recorded)."""
+    from .rowpack import gather_rows as _xla_gather_rows
     lanes = int(imat.shape[1]) + (2 * int(fmat.shape[1])
                                   if fmat is not None else 0)
-    use_pallas = bool(lanes) and _pallas_tier_on(rows, cap)
-    record(1, packed=True, pallas=use_pallas, nbytes=rows * lanes * 4)
-    if use_pallas:
-        from .pallas_gather import pallas_gather_rows
-        from .pallas_kernels import on_tpu
-        return pallas_gather_rows(plan, imat, fmat, idx,
-                                  interpret=not on_tpu())
-    from .rowpack import gather_rows as _xla_gather_rows
+    record(1, packed=True, nbytes=int(idx.shape[0]) * lanes * 4)
     return _xla_gather_rows(plan, imat, fmat, idx)
 
 

@@ -65,7 +65,7 @@ def _use_pallas() -> bool:
     when spark.rapids.tpu.pallas.enabled, else the fused-XLA path. An
     open `pallas_hash` circuit breaker (exec/lifecycle.FAMILY_DOMAINS
     entry for the `murmur3` family, ISSUE 8) demotes NEW traces to the
-    XLA formulation like the fused-tier families."""
+    XLA formulation."""
     from ..config import PALLAS_ENABLED, active_conf
     from ..parallel.mesh import active_mesh
     from .pallas_kernels import on_tpu
@@ -79,12 +79,12 @@ def _use_pallas() -> bool:
     mesh = active_mesh()
     if mesh is not None and mesh.size > 1:
         return False
-    # one implementation of breaker-consult + engagement noting
-    # (shared with the fused-tier families)
-    from .pallas_tier import _breaker_allows, _note_engaged
-    if not _breaker_allows("murmur3"):
+    from ..exec import lifecycle
+    if not lifecycle.breaker_allows(lifecycle.FAMILY_DOMAINS["murmur3"]):
         return False
-    _note_engaged("murmur3")
+    # a classified-transient failure of this attempt then counts
+    # against the domain, and a half-open probe can close on success
+    lifecycle.note_engagement("murmur3")
     return True
 
 

@@ -32,9 +32,8 @@ from ..columnar.column import (
 )
 from .basic import active_mask, compaction_order, gather_column
 from .hashing import murmur3_batch
-# row gathers in this module route through ops.gather (tier selection,
-# breaker demotion, numGathers accounting) — do NOT import the raw
-# rowpack.gather_rows here
+# row gathers in this module route through ops.gather (numGathers
+# accounting) — do NOT import the raw rowpack.gather_rows here
 from .rowpack import pack_rows, split_packable
 from .strings import string_equal
 
@@ -70,60 +69,6 @@ def _bucket_bits(capacity: int) -> int:
     return min(21, max(10, (capacity - 1).bit_length() + 1))
 
 
-def int_key_lanes(key_cols: Sequence[Column]):
-    """Key columns as u32 equality lanes + a combined validity lane, or
-    None when any key is not integer-like (strings/floats/decimals keep
-    the XLA verify: float IEEE `==` and varlen compares are not
-    bit-equality). 32-bit-or-narrower types widen to one i32 lane
-    (injective, so lane equality == value equality); 64-bit types split
-    into (lo, hi) u32 lanes. Shared by the XLA BuildTable and the fused
-    Pallas probe so both compare identical bit patterns."""
-    lanes = []
-    valid = None
-    for c in key_cols:
-        if type(c) is not Column:
-            return None
-        dt = c.data.dtype
-        if dt == jnp.bool_:
-            lanes.append(jax.lax.bitcast_convert_type(
-                c.data.astype(jnp.int32), jnp.uint32))
-        elif jnp.issubdtype(dt, jnp.integer):
-            if jnp.dtype(dt).itemsize <= 4:
-                lanes.append(jax.lax.bitcast_convert_type(
-                    c.data.astype(jnp.int32), jnp.uint32))
-            else:
-                pair = jax.lax.bitcast_convert_type(
-                    c.data.astype(jnp.int64), jnp.uint32)  # (n, 2) lo, hi
-                lanes.append(pair[:, 0])
-                lanes.append(pair[:, 1])
-        else:
-            return None
-        v = c.validity
-        valid = v if valid is None else (valid & v)
-    if valid is None:
-        return None
-    return tuple(lanes), valid.astype(jnp.int32)
-
-
-def candidate_fill_inputs(lo, counts, out_capacity: int):
-    """Shared candidate-expansion inputs for the i32 fast path: the
-    scattered owner-row-index array `seg` (range starts carry their row,
-    disjoint by construction) and the (lo, start) 2-lane matrix. Both the
-    XLA `expand_candidates` and the fused Pallas probe walk these, so the
-    two tiers produce bit-identical (stream_idx, build_pos) layouts."""
-    n_rows = counts.shape[0]
-    cum32 = jnp.cumsum(counts)          # inclusive, i32
-    start = cum32 - counts              # exclusive prefix
-    nonempty = counts > 0
-    pos = jnp.where(nonempty, jnp.minimum(start, out_capacity),
-                    out_capacity)
-    j = jnp.arange(n_rows, dtype=jnp.int32)
-    seg = jnp.zeros((out_capacity,), jnp.int32).at[pos].max(
-        j, mode="drop")
-    ls = jnp.stack([lo, start], axis=1)
-    return seg, ls
-
-
 class BuildTable:
     """Hash-bucketed build side: the TPU analog of the cuDF hash table
     the reference builds once and probes per stream batch. Rows sort by
@@ -138,8 +83,7 @@ class BuildTable:
 
     def __init__(self, bucket_table, perm, valid_count, num_rows,
                  key_cols: Sequence[Column], payload: Sequence[Column],
-                 capacity: int, pair_table=None, pack=None,
-                 key_lanes=None):
+                 capacity: int, pair_table=None, pack=None):
         self.bucket_table = bucket_table  # (2^B + 1,) int32 offsets
         self.perm = perm  # sorted position -> original build row
         self.valid_count = valid_count
@@ -162,20 +106,10 @@ class BuildTable:
         #  after compaction — the gather-elimination contract asserted
         #  by the structural numGathers tests)
         self.pack = pack
-        # (u32 lane arrays..., i32 combined-validity lane) in SORTED hash
-        # order, or None for non-integer keys: the fused Pallas probe
-        # keeps these VMEM-resident and verifies candidates in-register
-        # (ops/pallas_join.fused_probe_verify)
-        self.key_lanes = key_lanes
 
     @staticmethod
     def build(key_cols: Sequence[Column], payload: Sequence[Column],
-              num_rows, capacity: int,
-              with_key_lanes: bool = True) -> "BuildTable":
-        """with_key_lanes: prepare the fused Pallas probe's u32 key-lane
-        tables (1-2 extra permuted lanes per key). Callers on the default
-        XLA path pass the tier selector's family_may_engage so the
-        common case pays nothing for a kernel it will never run."""
+              num_rows, capacity: int) -> "BuildTable":
         from .strings import string_lengths
         valid = _keys_valid(key_cols, num_rows, capacity)
         # invalid/inactive rows: push to the end with the max hash AND keep
@@ -226,8 +160,8 @@ class BuildTable:
         # pack fixed-width keys and payload into SEPARATE sorted-order
         # matrices (round 8): the key pack serves the candidate-level
         # verify, the payload pack is gathered once at output level.
-        # The permutes route through the gather engine so the measured
-        # Pallas tier can serve the build reorder too.
+        # The permutes route through the gather engine like every
+        # materializing row gather.
         from .gather import gather_rows as routed_gather_rows
         key_pack_idx, _ = split_packable(key_cols)
         payload_pack_idx, payload_other_idx = split_packable(payload)
@@ -242,14 +176,9 @@ class BuildTable:
         pack = (plan_k, kmat_s, kfmat_s, plan_p, pmat_s, pfmat_s,
                 tuple(key_pack_idx), tuple(payload_pack_idx),
                 tuple(payload_other_idx))
-        key_lanes = None
-        kl = int_key_lanes(key_cols) if with_key_lanes else None
-        if kl is not None:
-            lanes, kvalid = kl
-            key_lanes = (tuple(ln[perm] for ln in lanes), kvalid[perm])
         return BuildTable(bucket_table, perm, valid_count,
                           num_rows, key_cols, payload, capacity,
-                          pair_table, pack, key_lanes)
+                          pair_table, pack)
 
 
 def _bt_flatten(bt: BuildTable):
@@ -257,21 +186,19 @@ def _bt_flatten(bt: BuildTable):
      kpi, ppi, poi) = bt.pack
     return ((bt.bucket_table, bt.perm, bt.valid_count, bt.num_rows,
              tuple(bt.key_cols), tuple(bt.payload),
-             bt.pair_table, kmat_s, kfmat_s, pmat_s, pfmat_s,
-             bt.key_lanes),
+             bt.pair_table, kmat_s, kfmat_s, pmat_s, pfmat_s),
             (bt.capacity, plan_k, plan_p, kpi, ppi, poi))
 
 
 def _bt_unflatten(aux, children):
     capacity, plan_k, plan_p, kpi, ppi, poi = aux
     (bucket_table, perm, valid_count, num_rows, key_cols, payload,
-     pair_table, kmat_s, kfmat_s, pmat_s, pfmat_s,
-     key_lanes) = children
+     pair_table, kmat_s, kfmat_s, pmat_s, pfmat_s) = children
     return BuildTable(bucket_table, perm, valid_count, num_rows,
                       list(key_cols), list(payload), capacity,
                       pair_table,
                       (plan_k, kmat_s, kfmat_s, plan_p, pmat_s, pfmat_s,
-                       kpi, ppi, poi), key_lanes)
+                       kpi, ppi, poi))
 
 
 jax.tree_util.register_pytree_node(BuildTable, _bt_flatten, _bt_unflatten)
@@ -329,7 +256,14 @@ def expand_candidates(lo, counts, out_capacity: int):
     total = jnp.sum(counts.astype(jnp.int64)) if counts.shape[0] \
         else jnp.int64(0)
     if counts.shape[0] and out_capacity < (1 << 31):
-        seg, ls = candidate_fill_inputs(lo, counts, out_capacity)
+        # range starts carry their owner row (disjoint by construction)
+        start = jnp.cumsum(counts) - counts     # exclusive prefix, i32
+        pos = jnp.where(counts > 0, jnp.minimum(start, out_capacity),
+                        out_capacity)
+        j = jnp.arange(counts.shape[0], dtype=jnp.int32)
+        seg = jnp.zeros((out_capacity,), jnp.int32).at[pos].max(
+            j, mode="drop")
+        ls = jnp.stack([lo, start], axis=1)
         row_f = jax.lax.cummax(seg)
         g = ls[row_f]                       # one 2-lane row gather
         i = jnp.arange(out_capacity, dtype=jnp.int32)

@@ -14,20 +14,13 @@ O(n_partitions x n_columns) serial numpy gathers (`partition_batch_host`
      table is the only value the host ever syncs on.
   2. `reorder_columns` — the partition-major reorder, routed through
      the gather engine (`ops/gather.gather_batch_columns`), so the
-     fixed-width lanes ride ONE packed row gather served by the
-     measured tier (Pallas DMA kernel where the `gather` family has a
-     recorded win, XLA floor otherwise) and the structural
+     fixed-width lanes ride ONE packed row gather and the structural
      numGathers/gatherTimeNs accounting covers the shuffle write path.
 
 The reordered batch then lands on the host as a single packed D2H copy
 (`columnar/transfer.fetch_split_host`) and each partition serializes
 straight from a row-range slice (`shuffle/serializer.serialize_slice`)
 — zero host-side row gathers per written batch.
-
-`tools/kern_bench.py`'s `partition_split` family benches this exact
-pipeline shape (counts + permutation + packed gather) XLA-vs-Pallas;
-the runtime tier consult rides the `gather` family records because the
-gather IS the tiered step.
 """
 
 from __future__ import annotations

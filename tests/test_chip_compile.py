@@ -1,6 +1,8 @@
 """Ask the chip's compiler, without the chip: the main path's programs are
 compiled for a DESCRIBED v5e at the shapes `chip_smoke.py` runs, so a kernel
-the TPU compiler refuses fails here and costs no chip time.
+the TPU compiler refuses fails here and costs no chip time. Four sections:
+the murmur3 Pallas kernels, every planner-built program of chip_smoke's two
+queries, the coalesce's concat, and the mesh exchange step for the 2x2 mesh.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process may hold libtpu, and every xdist worker imports this file),
@@ -212,23 +214,6 @@ def test_captured_shapes_are_chip_smokes(captured):
     assert caps(args[0]) == {chip_smoke.Q3_ORDERS}
     assert caps(args[1]) == {chip_smoke.Q3_LINES}
     assert not [k for k in captured if k.endswith("_exact")]
-
-
-# -- the fused tier: selected by nothing under default conf -------------------
-
-@pytest.mark.parametrize("lanes", [8, 100, 128])
-def test_dma_row_gather_compiles_at_packed_row_widths(one_chip, lanes):
-    """8 u32 lanes is what a typical packed row has; the kernel pads its
-    rows to the 128-lane tiling the compiler demands of a DMA slice. (Rows
-    wider than 128 lanes are still refused: a one-row slice of a (8,128)-
-    tiled matrix is not aligned along dimension 0.)"""
-    from spark_rapids_tpu.ops.pallas_gather import dma_row_gather
-    compiled = dma_row_gather._jit.lower(
-        jax.ShapeDtypeStruct((ROWS_2M, lanes), jnp.uint32,
-                             sharding=one_chip),
-        jax.ShapeDtypeStruct((ROWS_2M,), jnp.int32, sharding=one_chip),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 # -- four chips: the distributed step, for the described 2x2 mesh -------------

@@ -222,48 +222,51 @@ def test_statistics_event_kinds_are_registered():
         assert kind in events.EVENT_LEVELS, kind
 
 
-def test_pallas_family_registries_agree():
-    """Every Pallas kernel family (ops/pallas_tier.PALLAS_FAMILIES)
-    appears in (1) lifecycle.FAMILY_DOMAINS so the circuit breakers can
-    demote it, (2) tools/kern_bench.py's BENCHES so `auto` selection is
-    a measurement, and (3) the docs/perf.md tier table — and none of
-    the three registries carries a stale family (ISSUE 8: the three
-    drifted silently before measurement-gating existed)."""
-    import sys
-    from spark_rapids_tpu.exec import lifecycle
-    from spark_rapids_tpu.ops import pallas_tier
+def test_every_registered_conf_is_read():
+    """Every conf `config.py` registers is read by the engine: its
+    constant, its key, or the RapidsConf property that returns it
+    occurs in at least one other file under spark_rapids_tpu/. An
+    option nothing reads is a promise the engine does not keep
+    (`spark.rapids.sql.explain` and
+    `spark.rapids.sql.reader.batchSizeRows` were two, until PR 33)."""
+    pkg = ROOT / "spark_rapids_tpu"
+    config_py = pkg / "config.py"
+    source = config_py.read_text()
+    registered = re.findall(
+        r"^([A-Z][A-Z0-9_]*) = conf_[a-z_]+\(\s*\"([^\"]+)\"",
+        source, re.MULTILINE)
+    assert len(registered) == len(cfg._REGISTRY), (
+        "the scan lost a registration form", len(registered),
+        len(cfg._REGISTRY))
+    prop_of = {name: prop for prop, name in re.findall(
+        r"def (\w+)\(self\):\n\s+return self\.get\((\w+)\)", source)}
+    others = "\n".join(p.read_text() for p in sorted(pkg.rglob("*.py"))
+                       if p != config_py)
+    unread = [key for name, key in registered
+              if not re.search(rf"\b{name}\b", others)
+              and f'"{key}"' not in others
+              and not (name in prop_of
+                       and re.search(rf"\.{prop_of[name]}\b", others))]
+    assert not unread, f"registered but read nowhere: {unread}"
 
-    fams = set(pallas_tier.PALLAS_FAMILIES)
-    assert fams == set(lifecycle.FAMILY_DOMAINS), (
-        f"FAMILY_DOMAINS drifted: "
-        f"missing={sorted(fams - set(lifecycle.FAMILY_DOMAINS))} "
-        f"stale={sorted(set(lifecycle.FAMILY_DOMAINS) - fams)}")
-    # every family's breaker domain is a registered breaker
-    for fam, dom in lifecycle.FAMILY_DOMAINS.items():
-        assert dom in lifecycle.BREAKER_DOMAINS, (fam, dom)
 
-    sys.path.insert(0, str(ROOT / "tools"))
-    try:
-        import kern_bench
-    finally:
-        sys.path.pop(0)
-    assert fams == set(kern_bench.BENCHES), (
-        f"kern_bench families drifted: "
-        f"missing={sorted(fams - set(kern_bench.BENCHES))} "
-        f"stale={sorted(set(kern_bench.BENCHES) - fams)}")
-    for fam in fams:
-        assert fam in kern_bench.DEFAULT_SHAPES, fam
-        assert fam in kern_bench.QUICK_SHAPES, fam
-
-    docs = (ROOT / "docs" / "perf.md").read_text()
-    m = re.search(r"## Pallas kernel family tier table\n(.*?)(?:\n## |\Z)",
-                  docs, re.DOTALL)
-    assert m, "docs/perf.md lost its Pallas family tier table"
-    rows = set(re.findall(r"^\|\s*`([a-z_0-9]+)`\s*\|", m.group(1),
-                          re.MULTILINE))
-    assert rows == fams, (
-        f"docs/perf.md tier table drifted: "
-        f"missing={sorted(fams - rows)} stale={sorted(rows - fams)}")
+def test_docs_cite_existing_files():
+    """Every repo path that README.md, docs/*.md and the verify skill
+    name (spark_rapids_tpu/, tools/, tests/, benchmarks/ with a .py,
+    .md or .json suffix) exists: a document that cites a file which is
+    gone, or was never written (three documents cited one such record
+    file until PR 33), sends its reader after nothing."""
+    documents = [ROOT / "README.md", *sorted((ROOT / "docs").glob("*.md")),
+                 ROOT / ".claude" / "skills" / "verify" / "SKILL.md"]
+    cited = re.compile(
+        r"(?<![\w./-])((?:spark_rapids_tpu|tools|tests|benchmarks)"
+        r"/[\w./-]*\w\.(?:py|md|json))\b")
+    missing = sorted(
+        f"{doc.relative_to(ROOT)}: {path}"
+        for doc in documents if doc.exists()
+        for path in set(cited.findall(doc.read_text()))
+        if not (ROOT / path).exists())
+    assert not missing, "\n".join(missing)
 
 
 def test_fusion_whitelist_table_matches_registry():

@@ -3,13 +3,10 @@ the encoded lane — byte-identical collects with the conf on vs off, the
 >= 2x packed-upload byte shrink on a string-dictionary-heavy scan,
 code-space predicate / dictionary-hash-table engagement, late
 materialization ONLY at output-level seams, the PR 3 forced-spill
-recipe flowing encoded batches through the spill lane, seeded
-`device.dispatch` chaos over the materialize seam, and the
-`dict_gather` kern_bench family."""
+recipe flowing encoded batches through the spill lane, and seeded
+`device.dispatch` chaos over the materialize seam."""
 
 import os
-import sys
-from pathlib import Path
 
 import numpy as np
 import pyarrow as pa
@@ -24,9 +21,6 @@ from spark_rapids_tpu.api.session import TpuSession
 from spark_rapids_tpu.columnar import encoded, upload
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.encoded import DictionaryColumn
-
-sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "tools"))
-import kern_bench  # noqa: E402
 
 OFF = {"spark.rapids.tpu.scan.encoded.enabled": "false"}
 
@@ -295,16 +289,3 @@ def test_chaos_e2e_encoded_query_recovers(tmp_path):
         .collect()
     assert got == want and len(got) > 0
     assert faults.stats().get("device.dispatch", 0) >= 1
-
-
-# ---------------------------------------------------------------------------
-# the dict_gather kern_bench family
-# ---------------------------------------------------------------------------
-
-def test_kern_bench_dict_gather_family():
-    """Both lanes of the `dict_gather` family run (interpret mode) and
-    report positive medians — the harness half of the measured-tier
-    contract; the registries themselves are lint-pinned."""
-    xla_ms, pallas_ms = kern_bench.bench_dict_gather(
-        (256, 64), iters=2, reps=1, interpret=True)
-    assert xla_ms > 0 and pallas_ms > 0

@@ -4,13 +4,21 @@ keys, duplicates, hash-collision safety and residual conditions."""
 import numpy as np
 import pytest
 
+import jax.numpy as jnp
+
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
+from spark_rapids_tpu.columnar.column import Column, bucket_capacity
 from spark_rapids_tpu.exec.basic import InMemoryScanExec
 from spark_rapids_tpu.exec.joins import (
     HashJoinExec, NestedLoopJoinExec,
 )
 from spark_rapids_tpu.expr.core import col, lit
-from spark_rapids_tpu.types import INT, LONG, STRING, Schema, StructField
+from spark_rapids_tpu.ops.join import (
+    BuildTable, expand_candidates, probe_counts, verify_pairs,
+)
+from spark_rapids_tpu.types import (
+    DOUBLE, INT, LONG, STRING, Schema, StructField,
+)
 
 L_SCHEMA = Schema((StructField("lk", INT), StructField("lv", STRING)))
 R_SCHEMA = Schema((StructField("rk", INT), StructField("rv", STRING)))
@@ -187,3 +195,156 @@ def test_nested_loop_left_outer():
                                          for rk in R_DATA["rk"])}
     unmatched_rows = [r for r in got if r[2] is None and r[3] is None]
     assert {r[0] for r in unmatched_rows} == {1, 2, None}
+
+
+# --- the probe itself (ops/join.py): counts + expand + verify ---------------
+
+
+def _key_col(values, dtype, null_every=0):
+    c = Column.from_numpy(values, dtype,
+                          capacity=bucket_capacity(len(values)))
+    if null_every:
+        v = np.asarray(c.validity).copy()
+        v[::null_every] = False
+        c = Column(c.data, jnp.asarray(v), dtype)
+    return c
+
+
+def _long_keys(seed, nb, ns, dom, null_every):
+    rng = np.random.default_rng(seed)
+    # negative LONGs: every high bit of the 64-bit key set
+    bk = _key_col(rng.integers(-dom, dom, nb).astype(np.int64), LONG,
+                  null_every)
+    sk = _key_col(rng.integers(-dom, dom, ns).astype(np.int64), LONG,
+                  max(0, null_every - 2))
+    return [bk], [sk], nb, ns, None
+
+
+def _two_column_keys():
+    """(LONG, INT) keys with nulls in one column of each side."""
+    rng = np.random.default_rng(4)
+    nb, ns = 400, 900
+    bks = [_key_col(rng.integers(0, 50, nb).astype(np.int64), LONG, 5),
+           _key_col(rng.integers(0, 7, nb).astype(np.int32), INT)]
+    sks = [_key_col(rng.integers(0, 50, ns).astype(np.int64), LONG),
+           _key_col(rng.integers(0, 7, ns).astype(np.int32), INT, 9)]
+    return bks, sks, nb, ns, None
+
+
+def _disjoint_keys(cand_cap):
+    bk = _key_col(np.arange(100, dtype=np.int64), LONG)
+    sk = _key_col((np.arange(300) + 1000).astype(np.int64), LONG)
+    return [bk], [sk], 100, 300, cand_cap
+
+
+def _one_key_overflowing():
+    """64 x 64 equal keys, 4096 candidates, a bucket of 1024 (the
+    speculative cached-bucket overflow shape): the expansion keeps the
+    first 1024 in stream-row order, 16 stream rows' whole ranges."""
+    k = _key_col(np.zeros(64, np.int64), LONG)
+    return [k], [k], 64, 64, 1024
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(lambda: _long_keys(0, 500, 1500, 200, 7),
+                 id="long-duplicates-nulls"),
+    pytest.param(lambda: _long_keys(1, 64, 200, 1000, 0),
+                 id="long-sparse-no-nulls"),
+    pytest.param(lambda: _long_keys(2, 300, 300, 5, 3),
+                 id="long-heavy-duplication"),
+    pytest.param(lambda: _long_keys(3, 1, 100, 2, 0),
+                 id="long-single-row-build"),
+    pytest.param(_two_column_keys, id="two-column-int-keys"),
+    pytest.param(lambda: _disjoint_keys(128), id="no-match-cap-128"),
+    pytest.param(lambda: _disjoint_keys(256), id="no-match-cap-256"),
+    pytest.param(_one_key_overflowing, id="cap-under-candidate-count"),
+])
+def test_probe_verified_pairs_match_oracle(case):
+    """The verified (stream row, build row) pairs of probe_counts +
+    expand_candidates + verify_pairs are exactly the pairs of rows
+    whose keys are equal and non-null on both sides; with a candidate
+    bucket under the true count, exactly those among the first
+    `cand_cap` candidates in stream-row order."""
+    bks, sks, nb, ns, cand_cap = case()
+    build = BuildTable.build(bks, bks, jnp.int32(nb), bks[0].capacity)
+    lo, counts, _ = probe_counts(build, sks, jnp.int32(ns),
+                                 sks[0].capacity)
+    total = int(jnp.sum(counts))
+    if cand_cap is None:
+        cand_cap = bucket_capacity(max(total, 1))
+    s_idx, b_pos, total_dev = expand_candidates(lo, counts, cand_cap)
+    assert int(total_dev) == total
+    pair_valid = s_idx >= 0
+    ok, b_row = verify_pairs(build, sks,
+                             jnp.where(pair_valid, s_idx, -1),
+                             jnp.where(pair_valid, b_pos, -1), pair_valid)
+    ok = np.asarray(ok)
+    got = sorted(zip(np.asarray(s_idx)[ok].tolist(),
+                     np.asarray(b_row)[ok].tolist()))
+
+    def rows(cols, n):
+        data = [np.asarray(c.data)[:n] for c in cols]
+        valid = np.logical_and.reduce(
+            [np.asarray(c.validity)[:n] for c in cols])
+        return [tuple(int(d[i]) for d in data) if valid[i] else None
+                for i in range(n)]
+    bkeys, skeys = rows(bks, nb), rows(sks, ns)
+    want = [(s, b) for s in range(ns) for b in range(nb)
+            if skeys[s] is not None and skeys[s] == bkeys[b]]
+    if total > cand_cap:
+        # every candidate here is a true match (one key): the kept
+        # ones are the first cand_cap in (stream row, range) order
+        assert len(want) == total
+        kept = {s for s, _ in want[:cand_cap]}
+        assert len(got) == cand_cap and {s for s, _ in got} == kept
+        want = [p for p in want if p[0] in kept]
+    assert got == want
+
+
+# --- session level: planner-built join against a python oracle --------------
+
+
+def _session_join_tables():
+    rng = np.random.default_rng(11)
+    no, nl = 180, 500
+    orders = {"o_key": rng.integers(0, 150, no).tolist(),
+              "o_flag": rng.integers(0, 10, no).tolist(),
+              "o_name": [f"o{i % 17}" for i in range(no)]}
+    lines = {"l_key": [int(k) if i % 6 else None
+                       for i, k in enumerate(rng.integers(0, 150, nl))],
+             "l_val": (rng.random(nl) * 100).round(6).tolist()}
+    return orders, lines
+
+
+@pytest.mark.parametrize("how", ["inner", "left_outer", "left_semi",
+                                 "left_anti"])
+def test_session_join_matches_oracle(how):
+    """A join planned by the session (LONG keys with nulls on the stream
+    side, duplicate build keys, a string payload) returns the python
+    oracle's row multiset."""
+    from spark_rapids_tpu.api.session import TpuSession
+    orders, lines = _session_join_tables()
+    sess = TpuSession()
+    df_o = sess.from_pydict(orders, Schema((
+        StructField("o_key", LONG), StructField("o_flag", INT),
+        StructField("o_name", STRING))))
+    df_l = sess.from_pydict(lines, Schema((
+        StructField("l_key", LONG, True), StructField("l_val", DOUBLE))))
+    got = df_l.join(df_o, left_on="l_key", right_on="o_key",
+                    how=how).collect()
+    orows = list(zip(orders["o_key"], orders["o_flag"], orders["o_name"]))
+    want = []
+    for lrow in zip(lines["l_key"], lines["l_val"]):
+        matches = [o for o in orows
+                   if lrow[0] is not None and o[0] == lrow[0]]
+        if how in ("inner", "left_outer"):
+            want.extend(lrow + o for o in matches)
+            if how == "left_outer" and not matches:
+                want.append(lrow + (None, None, None))
+        elif bool(matches) == (how == "left_semi"):
+            want.append(lrow)
+
+    def order(rows):
+        return sorted(map(tuple, rows),
+                      key=lambda r: tuple((x is None, x) for x in r))
+    assert order(got) == order(want) and got

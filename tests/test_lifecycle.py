@@ -133,31 +133,39 @@ BREAKER = dict(FAST, **{
     "spark.rapids.tpu.breaker.threshold": "2",
     "spark.rapids.tpu.breaker.cooldownMs": "120",
     "spark.rapids.tpu.task.maxAttempts": "6",
-    "spark.rapids.tpu.pallas.fusedTier": "on",
 })
+
+
+@pytest.fixture
+def hash_tier(monkeypatch):
+    """The consult that a breaker demotes: ops/hashing._use_pallas (the
+    murmur3 kernels, domain `pallas_hash`), with the platform probe
+    answering "on a TPU" so that only the breaker decides here."""
+    from spark_rapids_tpu.ops import hashing, pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "on_tpu", lambda: True)
+    return hashing._use_pallas
 
 
 def test_breaker_disabled_by_default_records_nothing():
     C.set_active_conf(C.RapidsConf(dict(FAST)))
     for _ in range(5):
-        lifecycle.record_domain_failure("pallas_fused")
+        lifecycle.record_domain_failure("pallas_hash")
     assert lifecycle.open_breakers() == []
-    assert lifecycle.breaker_allows("pallas_fused")
+    assert lifecycle.breaker_allows("pallas_hash")
 
 
-def test_breaker_demotes_fused_tier_and_rearms_after_cooldown(spy):
+def test_breaker_demotes_fused_tier_and_rearms_after_cooldown(
+        spy, hash_tier):
     """Acceptance criterion: N injected device failures demote the
-    fused-Pallas domain to XLA (fused_tier_enabled answers False with
-    reason 'circuit breaker open'); after the cooldown the half-open
-    probe re-engages and a successful attempt closes the breaker."""
-    from spark_rapids_tpu.ops.pallas_tier import (family_may_engage,
-                                                  fused_tier_enabled)
+    Pallas hash domain to XLA (the consult answers False); after the
+    cooldown the half-open probe re-engages and a successful attempt
+    closes the breaker."""
     conf = C.RapidsConf(dict(BREAKER))
     C.set_active_conf(conf)
     engagements = []
 
     def flaky(attempt):
-        engagements.append(fused_tier_enabled("scan_agg", (1024,)))
+        engagements.append(hash_tier())
         if attempt <= 2:
             raise faults.InjectedDeviceError("device.dispatch")
         return "ok"
@@ -167,50 +175,45 @@ def test_breaker_demotes_fused_tier_and_rearms_after_cooldown(spy):
     # runs demoted on the XLA safe path
     assert engagements == [True, True, False]
     opens = _kinds(spy, "breaker_open")
-    assert {e["domain"] for e in opens} == {"pallas_fused",
+    assert {e["domain"] for e in opens} == {"pallas_hash",
                                             "device_dispatch"}
     assert any(e["safe_path"] for e in opens)
     assert set(lifecycle.open_breakers()) == {"device_dispatch",
-                                              "pallas_fused"}
-    assert not family_may_engage("scan_agg")
+                                              "pallas_hash"}
     h = lifecycle.health()
-    assert h["breakers"]["pallas_fused"]["state"] == "open"
-    assert h["breakers"]["pallas_fused"]["trips"] == 1
+    assert h["breakers"]["pallas_hash"]["state"] == "open"
+    assert h["breakers"]["pallas_hash"]["trips"] == 1
 
     # demoted inside the cooldown window
-    assert not fused_tier_enabled("scan_agg", (1024,))
+    assert not hash_tier()
 
     # cooldown -> half-open probe -> success closes and re-arms
     time.sleep(0.15)
-    assert with_task_retry(
-        lambda a: fused_tier_enabled("scan_agg", (1024,)),
-        conf=conf) is True
+    assert with_task_retry(lambda a: hash_tier(), conf=conf) is True
     assert lifecycle.open_breakers() == []
     assert [e["domain"] for e in _kinds(spy, "breaker_half_open")
-            if e["domain"] == "pallas_fused"] == ["pallas_fused"]
+            if e["domain"] == "pallas_hash"] == ["pallas_hash"]
     assert [e["domain"] for e in _kinds(spy, "breaker_close")].count(
-        "pallas_fused") == 1
-    assert fused_tier_enabled("scan_agg", (1024,))
+        "pallas_hash") == 1
+    assert hash_tier()
 
 
-def test_breaker_reopens_on_failed_probe(spy):
+def test_breaker_reopens_on_failed_probe(spy, hash_tier):
     conf = C.RapidsConf(dict(BREAKER))
     C.set_active_conf(conf)
-    from spark_rapids_tpu.ops.pallas_tier import fused_tier_enabled
 
     def flaky(attempt):
-        engaged = fused_tier_enabled("scan_agg", (512,))
-        if engaged:  # fails every time the fused tier engages
+        if hash_tier():  # fails every time the Pallas kernels engage
             raise faults.InjectedDeviceError("device.dispatch")
         return "xla"
 
     assert with_task_retry(flaky, conf=conf) == "xla"
-    assert "pallas_fused" in lifecycle.open_breakers()
+    assert "pallas_hash" in lifecycle.open_breakers()
     time.sleep(0.15)
     # half-open probe engages, fails again -> re-open (trips == 2)
     assert with_task_retry(flaky, conf=conf) == "xla"
-    assert lifecycle.health()["breakers"]["pallas_fused"]["trips"] == 2
-    assert "pallas_fused" in lifecycle.open_breakers()
+    assert lifecycle.health()["breakers"]["pallas_hash"]["trips"] == 2
+    assert "pallas_hash" in lifecycle.open_breakers()
 
 
 def test_breaker_half_open_single_probe_and_kill_switch(spy):
@@ -222,40 +225,39 @@ def test_breaker_half_open_single_probe_and_kill_switch(spy):
         "spark.rapids.tpu.breaker.threshold": "1",
         "spark.rapids.tpu.breaker.cooldownMs": "60"}))
     C.set_active_conf(conf)
-    lifecycle.record_domain_failure("pallas_join")
-    assert not lifecycle.breaker_allows("pallas_join")  # open
+    lifecycle.record_domain_failure("pallas_hash")
+    assert not lifecycle.breaker_allows("pallas_hash")  # open
     time.sleep(0.08)
-    assert lifecycle.breaker_allows("pallas_join")       # the probe
-    assert not lifecycle.breaker_allows("pallas_join"), \
+    assert lifecycle.breaker_allows("pallas_hash")       # the probe
+    assert not lifecycle.breaker_allows("pallas_hash"), \
         "a second consult engaged while the probe was in flight"
-    lifecycle.record_domain_success("pallas_join")       # probe passed
-    assert lifecycle.breaker_allows("pallas_join")
+    lifecycle.record_domain_success("pallas_hash")       # probe passed
+    assert lifecycle.breaker_allows("pallas_hash")
     assert lifecycle.open_breakers() == []
     # kill-switch: an open breaker must not outlive the conf
-    lifecycle.record_domain_failure("pallas_join")
-    assert not lifecycle.breaker_allows("pallas_join")
+    lifecycle.record_domain_failure("pallas_hash")
+    assert not lifecycle.breaker_allows("pallas_hash")
     C.set_active_conf(C.RapidsConf(dict(FAST, **{
         "spark.rapids.tpu.breaker.enabled": "false"})))
-    assert lifecycle.breaker_allows("pallas_join")
+    assert lifecycle.breaker_allows("pallas_hash")
 
 
-def test_breaker_counts_the_exhausted_final_attempt(spy):
+def test_breaker_counts_the_exhausted_final_attempt(spy, hash_tier):
     """Review r2: the FINAL failing attempt (the strongest persistence
     signal) must count toward the breaker before with_task_retry
     re-raises — with maxAttempts=1 it is the only signal there is."""
-    from spark_rapids_tpu.ops.pallas_tier import fused_tier_enabled
     conf = C.RapidsConf(dict(BREAKER, **{
         "spark.rapids.tpu.task.maxAttempts": "1",
         "spark.rapids.tpu.breaker.threshold": "1"}))
     C.set_active_conf(conf)
 
     def doomed(attempt):
-        assert fused_tier_enabled("scan_agg", (256,))
+        assert hash_tier()
         raise faults.InjectedDeviceError("device.dispatch")
 
     with pytest.raises(faults.InjectedDeviceError):
         with_task_retry(doomed, conf=conf)
-    assert "pallas_fused" in lifecycle.open_breakers()
+    assert "pallas_hash" in lifecycle.open_breakers()
     assert _kinds(spy, "breaker_open")
 
 
@@ -698,9 +700,9 @@ def test_profile_report_lifecycle_rollup():
         {"kind": "query_cancelled", "phase": "sem-wait"},
         {"kind": "query_cancelled", "phase": "compute"},
         {"kind": "query_cancelled", "phase": "compute"},
-        {"kind": "breaker_open", "domain": "pallas_fused"},
-        {"kind": "breaker_half_open", "domain": "pallas_fused"},
-        {"kind": "breaker_close", "domain": "pallas_fused"},
+        {"kind": "breaker_open", "domain": "pallas_hash"},
+        {"kind": "breaker_half_open", "domain": "pallas_hash"},
+        {"kind": "breaker_close", "domain": "pallas_hash"},
         {"kind": "partition_recompute", "partition": 1},
         {"kind": "task_retry", "attempt": 1},
     ]

@@ -137,7 +137,7 @@ def test_speculation_scope_trips_and_rerun_matches():
     assert got == want
 
 
-@pytest.mark.slow  # ~5s; fusion equality nightly, pallas_fused equality kept tier-1 (round-7 budget move)
+@pytest.mark.slow  # ~5s; fusion equality nightly (round-7 budget move)
 def test_fused_filter_project_agg_matches_unfused():
     from spark_rapids_tpu.config import RapidsConf, set_active_conf
     rng = np.random.default_rng(5)

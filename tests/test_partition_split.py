@@ -3,12 +3,10 @@ byte-equality against the gather formulation across every column
 family, the one-pass device split (counts + stable permutation + packed
 D2H), zero host-side gathers on the device lanes (structural), engine
 on/off equality under the PR 3 forced-spill recipe, seeded
-`shuffle.decode` injection placement invariance across lanes, the
-`partition_split` kern_bench family, and the vectorized range-key
-materialization."""
+`shuffle.decode` injection placement invariance across lanes, and the
+vectorized range-key materialization."""
 
 import decimal
-import json
 import os
 import sys
 from pathlib import Path
@@ -30,9 +28,6 @@ from spark_rapids_tpu.shuffle.manager import (HostShuffleReader,
 from spark_rapids_tpu.types import (DOUBLE, INT, LONG, STRING, ArrayType,
                                     DecimalType, MapType, Schema,
                                     StructField, StructType)
-
-sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "tools"))
-import kern_bench  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -470,23 +465,8 @@ def test_bench_shuffle_attribution_delta():
 
 
 # ---------------------------------------------------------------------------
-# kern_bench family + range-key vectorization
+# range-key vectorization
 # ---------------------------------------------------------------------------
-
-def test_kern_bench_partition_split_quick(tmp_path):
-    """Acceptance: the partition_split family runs on CPU via --quick
-    and produces a well-formed versioned record."""
-    from spark_rapids_tpu.ops.pallas_tier import KERN_BENCH_SCHEMA
-    out = tmp_path / "kb.json"
-    kern_bench.main(["--quick", "--families", "partition_split",
-                     "--out", str(out)])
-    doc = json.loads(out.read_text())
-    assert doc["schema"] == KERN_BENCH_SCHEMA
-    (rec,) = doc["records"]
-    assert rec["family"] == "partition_split"
-    assert rec["winner"] in ("xla", "pallas")
-    assert rec["shape"] == [1 << 11, 4]
-
 
 def test_host_key_array_matches_object_path():
     """The vectorized numeric/string range-key materialization returns

@@ -5,12 +5,10 @@ identity, the forced-dd f64 staging formulation, the staging pool
 1-transfer-per-scan-batch pinning, engine-level on/off equality (incl.
 the PR 3 forced-spill unspill lane and the host shuffle read seam),
 seeded `device.dispatch` chaos keying with order-independent placement,
-the fused split+pack single-dispatch program, the `h2d_upload`
-kern_bench family, the `upload` event/metrics surface, and the bench /
-profile_report roll-ups."""
+the fused split+pack single-dispatch program, the `upload`
+event/metrics surface, and the bench / profile_report roll-ups."""
 
 import decimal
-import json
 import os
 import sys
 from pathlib import Path
@@ -27,9 +25,6 @@ from spark_rapids_tpu.columnar.column import Column, host_build
 from spark_rapids_tpu.types import (BOOLEAN, BYTE, DOUBLE, FLOAT, INT, LONG,
                                     SHORT, STRING, ArrayType, DecimalType,
                                     MapType, Schema, StructField, StructType)
-
-sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "tools"))
-import kern_bench  # noqa: E402
 
 OFF = {"spark.rapids.tpu.transfer.packedUpload.enabled": "false"}
 
@@ -711,18 +706,3 @@ def test_bench_upload_attribution_block():
     delta = bench.upload_attribution()
     assert delta["uploads"] == 1 and delta["packed"] == 1 \
         and delta["transfers"] == 1
-
-
-def test_kern_bench_h2d_upload_quick(tmp_path):
-    """The h2d_upload family runs on CPU via --quick and produces a
-    well-formed versioned record (CI smoke, ISSUE 10 satellite)."""
-    from spark_rapids_tpu.ops.pallas_tier import KERN_BENCH_SCHEMA
-    out = tmp_path / "kb.json"
-    kern_bench.main(["--quick", "--families", "h2d_upload",
-                     "--out", str(out)])
-    doc = json.loads(out.read_text())
-    assert doc["schema"] == KERN_BENCH_SCHEMA
-    (rec,) = doc["records"]
-    assert rec["family"] == "h2d_upload"
-    assert rec["winner"] in ("xla", "pallas")
-    assert rec["shape"] == [1 << 11, 4]

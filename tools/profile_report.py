@@ -210,7 +210,6 @@ def build_summary(events: List[Dict[str, Any]], top: int = 10,
     storms = [e for e in events if e.get("kind") == "recompile_storm"]
     dstats = [e for e in events if e.get("kind") == "dispatch_stats"]
     writes = [e for e in events if e.get("kind") == "shuffle_write"]
-    tiers = [e for e in events if e.get("kind") == "pallas_tier"]
     gstats = [e for e in events if e.get("kind") == "gather_stats"]
     ups = [e for e in events if e.get("kind") == "upload"]
     xstats = [e for e in events if e.get("kind") == "exchange_stats"]
@@ -352,13 +351,9 @@ def build_summary(events: List[Dict[str, Any]], top: int = 10,
                                   for e in fused), default=0),
             "by_label": sorted({e.get("label") or "?" for e in fused}),
         },
-        "pallas_tier": {"decisions": len(tiers),
-                        "engaged": sum(1 for e in tiers
-                                       if e.get("engaged"))},
         "gathers": {"count": sum(e.get("count") or 0 for e in gstats),
                     "records": len(gstats),
                     "packed": sum(e.get("packed") or 0 for e in gstats),
-                    "pallas": sum(e.get("pallas") or 0 for e in gstats),
                     "bytes": sum(e.get("bytes") or 0 for e in gstats)},
         "uploads": {
             "batches": len(ups),
@@ -642,10 +637,6 @@ def build_report(events: List[Dict[str, Any]], top: int = 10,
             f"dispatches over {fs['batches']} batches — "
             f"~{fs['dispatches_saved']} saved vs per-op; donated "
             f"state {_fmt_bytes(fs['donated_bytes'])})")
-    pt = s["pallas_tier"]
-    if pt["decisions"]:
-        extras.append(f"pallas tier decisions: {pt['decisions']} "
-                      f"({pt['engaged']} engaged)")
     # gather-engine roll-up (ISSUE 8): materializing row gathers per
     # wired operator — the count drop IS the optimization, so a bench
     # round reads it next to the pipeline/workload lines
@@ -653,7 +644,6 @@ def build_report(events: List[Dict[str, Any]], top: int = 10,
     if g["records"]:
         extras.append(
             f"gathers: {g['count']} ({g['packed']} packed rows, "
-            f"{g['pallas']} via the Pallas DMA kernel, "
             f"~{_fmt_bytes(g['bytes'])} moved)")
     # upload-engine roll-up (ISSUE 10): host->device ingest — the
     # transfer-count drop (one per batch vs one per buffer) is the

@@ -53,7 +53,7 @@ INSTANT_KINDS = (
     "query_cancelled", "query_shed", "breaker_open",
     "breaker_half_open", "breaker_close", "partition_recompute",
     "quota_spill", "query_queued", "query_admitted", "peer_dead",
-    "pallas_tier", "shuffle_write", "upload", "exchange_stats",
+    "shuffle_write", "upload", "exchange_stats",
     "gather_stats", "dispatch_stats",
 )
 
