@@ -80,25 +80,35 @@ class ColumnarBatch:
     @staticmethod
     def from_arrow(table, fault_key=None) -> "ColumnarBatch":
         """pyarrow Table/RecordBatch -> device batch (one capacity
-        bucket). The scan ingest seam (ISSUE 10): columns are built
-        host-resident and the whole batch crosses the host->device
-        boundary through the packed upload engine — ONE transfer per
-        batch when `spark.rapids.tpu.transfer.packedUpload.enabled`
-        (default), one per buffer otherwise. `fault_key` is the batch's
-        chaos work-item key (the scan chunk offset)."""
-        from ..types import from_arrow as type_from_arrow
+        bucket). The scan ingest seam (ISSUE 10): the whole batch
+        crosses the host->device boundary through the packed upload
+        engine — ONE transfer per batch when
+        `spark.rapids.tpu.transfer.packedUpload.enabled` (default), one
+        per buffer otherwise. On the packed lane a fixed-width column
+        whose Arrow buffer already holds the engine's representation is
+        NOT built here: it rides as an `upload.ArrowFixed` and the
+        packer writes it from the Arrow buffers straight into the
+        staging buffer; every other column (strings, dictionaries,
+        DECIMAL, BOOLEAN, nested, any type that needs a cast), and every
+        column with the conf off, is built host-resident as before.
+        `fault_key` is the batch's chaos work-item key (the scan chunk
+        offset)."""
+        from ..config import UPLOAD_PACKED, active_conf
         from ..obs import op_span
         from .column import host_build
-        from .upload import to_device_batch
+        from .upload import ArrowFixed, to_device_batch
         n = table.num_rows
         cap = bucket_capacity(n)
         fields, cols = [], []
         with op_span("upload.pack", phase="upload"), host_build():
+            packed = active_conf().get(UPLOAD_PACKED)
             for name in table.column_names:
                 arr = table.column(name)
-                col = column_from_arrow(arr)
-                if col.capacity < cap:
-                    col = col.with_capacity(cap)
+                col = ArrowFixed.of(arr, cap) if packed else None
+                if col is None:
+                    col = column_from_arrow(arr)
+                    if col.capacity < cap:
+                        col = col.with_capacity(cap)
                 cols.append(col)
                 fields.append(StructField(name, col.dtype))
         # ISSUE 18: account encoded vs decoded scan lanes (encoded_scan

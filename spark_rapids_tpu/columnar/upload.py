@@ -17,15 +17,23 @@ This module provides the mirror:
      f64 staged as double-double float32 pairs on TPU) into ONE
      contiguous uint8 staging buffer drawn from a reusable,
      capacity-bucketed staging pool (the pinned-host-memory analog:
-     conf-capped idle bytes, grow-on-miss, LRU-trimmed) so steady-state
-     uploads do zero host allocation;
+     conf-capped idle bytes, grow-on-miss, LRU-trimmed). At the scan
+     seam a fixed-width Arrow column whose buffer already holds the
+     engine's physical representation (`ArrowFixed`) is written from
+     its Arrow buffers STRAIGHT into its wire block — no host `Column`
+     in between, no full-width temporary — so a steady-state scan of
+     such columns allocates nothing on the host; every other column
+     family is built as a host `Column` first and copied in, into the
+     same buffer (`counters()`: `direct_cols` / `built_cols`);
   2. ONE `jax.device_put` per batch — the single transfer, routed
      through the `device.dispatch` chaos fault point with the batch's
      work-item key;
   3. ONE jitted device unpack program per capacity-shape bucket (the
-     static layout spec keys the trace, like `_pack_jit`) that slices /
-     bitcasts the buffer back into column arrays — byte-identical to
-     the per-buffer lane for every column family.
+     static layout spec keys the trace, like `_pack_jit`; the spec of
+     a direct-packed column is the tuple its host `Column` would give,
+     so both share one program) that slices / bitcasts the buffer back
+     into column arrays — byte-identical to the per-buffer lane for
+     every column family.
 
 Wired at the three ingest seams: `SourceScanExec` batch upload
 (`ColumnarBatch.from_arrow`), the shuffle-read deserializer's device
@@ -76,7 +84,10 @@ __all__ = [
 
 _COUNTER_LOCK = threading.Lock()
 _COUNTERS = {"uploads": 0, "packed": 0, "per_buffer": 0, "transfers": 0,
-             "bytes": 0, "pack_ns": 0, "pool_hits": 0, "pool_misses": 0}
+             "bytes": 0, "pack_ns": 0, "pool_hits": 0, "pool_misses": 0,
+             # packed-lane columns written straight from Arrow buffers /
+             # copied in from a host Column
+             "direct_cols": 0, "built_cols": 0}
 
 
 def _note(**deltas) -> None:
@@ -329,11 +340,109 @@ def _put_aliased(dev, buf: np.ndarray) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# direct-packed Arrow columns (the scan seam)
+# ---------------------------------------------------------------------------
+
+def _arrow_direct(at) -> bool:
+    """True when an Arrow type's value buffer IS the engine's physical
+    lane (same width, same meaning, no cast): the signed integers, the
+    two floats, DATE as int32 days, TIMESTAMP as int64 microseconds.
+    Everything else (strings, dictionaries, DECIMAL, bit-packed
+    BOOLEAN, nested, NullType, any other unit or width) is built as a
+    host Column."""
+    import pyarrow as pa
+    t = pa.types
+    if t.is_timestamp(at):
+        return at.unit == "us"
+    return t.is_int8(at) or t.is_int16(at) or t.is_int32(at) \
+        or t.is_int64(at) or t.is_float32(at) or t.is_float64(at) \
+        or t.is_date32(at)
+
+
+class ArrowFixed:
+    """A fixed-width Arrow column (Array or ChunkedArray) standing in
+    for its host `Column` in the list a packed upload takes: the packer
+    writes it from the Arrow buffers straight into its wire block.
+    `build()` gives the host Column for a lane that needs one."""
+
+    __slots__ = ("arr", "dtype", "capacity")
+
+    def __init__(self, arr, dtype, capacity: int):
+        self.arr = arr
+        self.dtype = dtype
+        self.capacity = capacity
+
+    @staticmethod
+    def of(arr, capacity: int) -> Optional["ArrowFixed"]:
+        """The stand-in for `arr`, or None where its type needs a host
+        Column (chosen by the Arrow type alone)."""
+        if not _arrow_direct(arr.type):
+            return None
+        from ..types import from_arrow as type_from_arrow
+        return ArrowFixed(arr, type_from_arrow(arr.type), capacity)
+
+    def build(self) -> Column:
+        from .column import column_from_arrow, host_build
+        with host_build():
+            col = column_from_arrow(self.arr)
+            return col.with_capacity(max(self.capacity, col.capacity))
+
+
+def _pack_arrow_fixed(col: ArrowFixed, buf: np.ndarray, pos: int,
+                      dd: bool) -> int:
+    """Write one ArrowFixed as its wire block (data then validity) at
+    `buf[pos:]`: the same bytes `_pack_host_column` gives for
+    `col.build()`, written once. Chunks go one after another (offset
+    and length honoured), data at null slots and the tail [n, cap) are
+    zeroed in place."""
+    np_dtype = col.dtype.jnp_dtype
+    size, cap = np_dtype.itemsize, col.capacity
+    end = pos + cap * size
+    data, valid = buf[pos:end], buf[end:end + cap]
+    # a double-double lane is (hi, lo) float32 pairs: _host_bytes' formula
+    pair = data.view(np.float32).reshape(cap, 2) \
+        if dd and np_dtype == np.float64 else None
+    arr = col.arr
+    row = 0
+    for chunk in arr.chunks if hasattr(arr, "chunks") else (arr,):
+        m = len(chunk)
+        if not m:
+            continue
+        bitmap, values = chunk.buffers()
+        off = chunk.offset
+        block = data[row * size:(row + m) * size]
+        if pair is None:
+            block[:] = np.frombuffer(values, np.uint8, m * size, off * size)
+        else:
+            src = np.frombuffer(values, np.float64, m, off * 8)
+            hi, lo = pair[row:row + m, 0], pair[row:row + m, 1]
+            with np.errstate(over="ignore", invalid="ignore"):
+                np.copyto(hi, src, casting="unsafe")
+                np.subtract(src, hi, out=lo, casting="unsafe")
+        if chunk.null_count == 0:
+            valid[row:row + m] = 1
+        else:
+            lead = off % 8
+            bits = np.unpackbits(
+                np.frombuffer(bitmap, np.uint8, (lead + m + 7) // 8,
+                              off // 8),
+                bitorder="little")[lead:lead + m]
+            valid[row:row + m] = bits
+            block.reshape(m, size)[bits == 0] = 0  # as fill_null(0)
+        row += m
+    data[row * size:] = 0
+    valid[row:] = 0
+    return end + cap
+
+
+# ---------------------------------------------------------------------------
 # layout spec — one hashable description per column, sizing the host
 # pack and keying the jitted device unpack (trace per capacity bucket)
 # ---------------------------------------------------------------------------
 
 def _col_spec(col: Column):
+    if isinstance(col, ArrowFixed):
+        return ("fix", col.dtype, str(col.dtype.jnp_dtype), col.capacity)
     if isinstance(col, DictionaryColumn):
         return ("dict", col.dtype, col.capacity, col.dict_capacity,
                 col.dict_byte_capacity)
@@ -380,6 +489,8 @@ def _packable_leaf(a) -> bool:
 def _packable_column(col) -> bool:
     """True when the packer knows this column's class and every buffer
     is host-resident — anything else keeps the per-buffer lane."""
+    if isinstance(col, ArrowFixed):
+        return True
     if isinstance(col, DictionaryColumn):
         return _packable_leaf(col.codes) and _packable_leaf(col.validity) \
             and _packable_leaf(col.dict_offsets) \
@@ -431,6 +542,8 @@ def _put_block(buf: np.ndarray, pos: int, block: np.ndarray) -> int:
 
 def _pack_host_column(col: Column, buf: np.ndarray, pos: int,
                       dd: bool) -> int:
+    if isinstance(col, ArrowFixed):
+        return _pack_arrow_fixed(col, buf, pos, dd)
     if isinstance(col, DictionaryColumn):
         pos = _put_block(buf, pos, _host_bytes(col.codes, dd))
         pos = _put_block(buf, pos, _host_bytes(col.dict_offsets, dd))
@@ -474,11 +587,15 @@ def pack_host_batch(cols: Sequence[Column], n: int,
     total = 4 + sum(_spec_nbytes(s) for s in specs)
     pool = pool or staging_pool()
     buf = pool.acquire(total)
-    buf[:4] = np.array([n], dtype="<i4").view(np.uint8)
-    pos = 4
-    for col in cols:
-        pos = _pack_host_column(col, buf, pos, dd)
-    assert pos == total, (pos, total)
+    try:
+        buf[:4] = np.array([n], dtype="<i4").view(np.uint8)
+        pos = 4
+        for col in cols:
+            pos = _pack_host_column(col, buf, pos, dd)
+        assert pos == total, (pos, total)
+    except BaseException:
+        pool.discard(buf)
+        raise
     return buf, total
 
 
@@ -632,8 +749,10 @@ def metric_sink(num_metric, time_metric):
 
 
 def _record(lane: str, seam: str, nbytes: int, rows: int, n_cols: int,
-            transfers: int, pack_ns: int) -> None:
+            transfers: int, pack_ns: int, direct_cols: int = 0,
+            built_cols: int = 0) -> None:
     _note(uploads=1, transfers=transfers, bytes=nbytes, pack_ns=pack_ns,
+          direct_cols=direct_cols, built_cols=built_cols,
           **({"packed": 1} if lane == "packed" else {"per_buffer": 1}))
     sink = getattr(_TLS, "sink", None)
     if sink is not None:
@@ -642,7 +761,7 @@ def _record(lane: str, seam: str, nbytes: int, rows: int, n_cols: int,
     from ..obs import events as obs_events
     obs_events.emit("upload", lane=lane, seam=seam, bytes=nbytes,
                     rows=rows, cols=n_cols, transfers=transfers,
-                    pack_ns=pack_ns)
+                    pack_ns=pack_ns, direct_cols=direct_cols)
 
 
 # ---------------------------------------------------------------------------
@@ -714,8 +833,10 @@ def packed_upload_batch(cols: Sequence[Column], n: int, schema,
         raise
     _finish_staging(pool, buf, dev)
     del dev
+    direct = sum(isinstance(c, ArrowFixed) for c in cols)
     _record("packed", seam, total, n, len(cols), 1,
-            time.perf_counter_ns() - t0)
+            time.perf_counter_ns() - t0, direct_cols=direct,
+            built_cols=len(cols) - direct)
     return ColumnarBatch(list(out_cols), num_rows, schema, host_rows=n)
 
 
@@ -753,13 +874,15 @@ def _per_buffer_batch(cols: Sequence[Column], n: int, schema,
 
 def to_device_batch(cols: Sequence[Column], n: int, schema,
                     fault_key: Optional[str] = None, seam: str = "other"):
-    """Promote host-built columns to a device ColumnarBatch on the lane
-    the conf selects: packed (one transfer) when enabled and every
-    column is packable, per-buffer otherwise."""
+    """Promote host-built columns (and the scan seam's ArrowFixed
+    stand-ins) to a device ColumnarBatch on the lane the conf selects:
+    packed (one transfer) when enabled and every column is packable,
+    per-buffer otherwise."""
     from ..config import UPLOAD_PACKED, active_conf
     if active_conf().get(UPLOAD_PACKED) \
             and all(_packable_column(c) for c in cols):
         return packed_upload_batch(cols, n, schema, fault_key, seam)
+    cols = [c.build() if isinstance(c, ArrowFixed) else c for c in cols]
     return _per_buffer_batch(cols, n, schema, seam, fault_key)
 
 

@@ -678,6 +678,7 @@ def test_upload_event_and_exec_metrics(monkeypatch, tmp_path):
 
 
 def test_profile_report_uploads_rollup():
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "tools"))
     from profile_report import build_report
     events = [
         {"kind": "upload", "lane": "packed", "seam": "scan",
