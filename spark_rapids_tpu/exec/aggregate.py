@@ -18,9 +18,11 @@ partial/final split so distributed aggregation reuses the same exec.
 
 from __future__ import annotations
 
+import threading
 from functools import partial
-from typing import Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..columnar.batch import ColumnarBatch
@@ -36,23 +38,101 @@ from ..ops.basic import active_mask, sanitize
 from ..ops.sort import string_words_for
 from ..types import DataType, LongType, Schema, StructField
 from ..obs.dispatch import instrument
+from ..obs.span import op_span
 from .base import (AGG_TIME, CONCAT_TIME, DEBUG, DISPATCH_METRICS,
                    NUM_INPUT_BATCHES, NUM_INPUT_ROWS, TpuExec)
 from .basic import bind_projection, eval_projection
 from .coalesce import concat_batches
 
 
+# ---------------------------------------------------------------------------
+# process counters of the exact drive (the chaos-delta pattern, as
+# `stage_compiler.counters()`)
+# ---------------------------------------------------------------------------
+
+_COUNTER_LOCK = threading.Lock()
+_COUNTERS = {
+    # drives of `_execute_exact`: one a query for a group-by the stage
+    # compiler did not fuse
+    "executions": 0,
+    # batches (source batches, and merges of partials) that took the hash
+    # path: `update_hash` / `merge_hash` at 2 rounds and one host read of
+    # `leftover`
+    "hash_updates": 0,
+    # of those, the ones whose 2 rounds left keys over and that went on to
+    # 6 rounds: a second program and a second host sync
+    "hash_round_retries": 0,
+    # of those, the ones 6 rounds did not resolve either and that fell to
+    # the exact sort path
+    "exact_fallbacks": 0,
+}
+
+
+def _note(**deltas) -> None:
+    with _COUNTER_LOCK:
+        for k, v in deltas.items():
+            _COUNTERS[k] += v
+
+
+def counters() -> Dict[str, int]:
+    """Process-cumulative counters of the hash group-by's tiers."""
+    with _COUNTER_LOCK:
+        return dict(_COUNTERS)
+
+
+def reset_aggregate_counters() -> None:
+    with _COUNTER_LOCK:
+        for k in _COUNTERS:
+            _COUNTERS[k] = 0
+
+
 @partial(instrument, label="aggregate.shrink_batch",
-         static_argnums=(1,))
-def _shrink_batch(batch: ColumnarBatch, cap: int) -> ColumnarBatch:
+         static_argnums=(1, 2))
+def _shrink_batch(batch: ColumnarBatch, cap: int,
+                  byte_caps: Optional[Tuple[Optional[int], ...]] = None
+                  ) -> ColumnarBatch:
     """Move the active prefix into a smaller capacity bucket: aggregated
     partials carry few groups in huge input-sized buckets; merging at input
-    size would sort mostly-padding (the dominant waste in a groupby)."""
-    from ..ops.basic import slice_rows
-    cols = [slice_rows(c, jnp.int32(0), batch.num_rows, cap)
-            for c in batch.columns]
+    size would sort mostly-padding (the dominant waste in a groupby).
+    `byte_caps` (one a column, None where it is not a string) shrinks the
+    string columns' byte buckets too: a gather of a string column costs by
+    its byte bucket, whatever its rows."""
+    from ..ops.basic import gather_column
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    live = idx < batch.num_rows
+    cols = [gather_column(c, idx, live,
+                          byte_caps[i] if byte_caps is not None else None)
+            for i, c in enumerate(batch.columns)]
     return ColumnarBatch(cols, batch.num_rows, batch.schema)
 
+
+@partial(instrument, label="aggregate.partial_size")
+def _partial_size(batch: ColumnarBatch):
+    """[rows, bytes of each string column's active prefix]: what a tight
+    bucket for a partial needs, in one array for one host read."""
+    return jnp.stack([batch.num_rows.astype(jnp.int32)] + [
+        c.offsets[batch.num_rows] for c in batch.columns
+        if isinstance(c, StringColumn)])
+
+
+def _tight_partial(out: ColumnarBatch, sizes) -> ColumnarBatch:
+    """`out` in the bucket its groups need (`sizes`: `_partial_size` on the
+    host). The hash tiers' output keeps its INPUT's capacity and byte
+    buckets (four groups of Q1 in 8,388,608 rows): everything after it, the
+    merge, the evaluation and the result sort, would work, and compile, at
+    that size."""
+    from ..columnar.column import bucket_capacity
+    rows = int(sizes[0])
+    cap = bucket_capacity(max(rows, 1))
+    nbytes = iter(int(b) for b in sizes[1:])
+    byte_caps = tuple(
+        bucket_capacity(max(next(nbytes), 1))
+        if isinstance(c, StringColumn) else None for c in out.columns)
+    held = tuple(c.byte_capacity if isinstance(c, StringColumn) else None
+                 for c in out.columns)
+    cols = out.columns if (cap, byte_caps) == (out.capacity, held) else \
+        _shrink_batch(out, cap, byte_caps).columns
+    return ColumnarBatch(cols, rows, out.schema)
 
 
 def _result_column(data, valid, dtype) -> Column:
@@ -624,10 +704,11 @@ class AggregateExec(TpuExec):
         in_rows = self.metrics[NUM_INPUT_ROWS]
         in_batches = self.metrics[NUM_INPUT_BATCHES]
         aggregated: List[SpillableBatch] = []
+        _note(executions=1)
 
         with agg_time.ns_timer():
             first_pass = self._merge_jitted if self.mode == "final" \
-                else self._update_and_aggregate
+                else self._update_spanned
             for batch in self._source.execute():
                 in_batches.add(1)
                 if batch._host_rows is not None:
@@ -710,6 +791,10 @@ class AggregateExec(TpuExec):
     def _sync_free(self) -> bool:
         return self._masked_ok
 
+    def _update_spanned(self, batch: ColumnarBatch) -> ColumnarBatch:
+        with op_span("agg.update", phase="group-agg"):
+            return self._update_and_aggregate(batch)
+
     def _update_and_aggregate(self, batch: ColumnarBatch) -> ColumnarBatch:
         if self._masked_ok:
             # one program: fused steps + masked buckets + lax.cond exact
@@ -717,22 +802,37 @@ class AggregateExec(TpuExec):
             return self._jit_step_exact(batch)
         pre = self._jit_pre(batch)
         if self._hash_path_ok:
-            for rounds in (2, 6):
-                out, leftover = self._jit_update_hash[rounds](pre)
-                if not bool(leftover):
-                    return out
+            out = self._hash_tiers(self._jit_update_hash, pre)
+            if out is not None:
+                return out
             # unresolved hash collisions: exact sort fallback (reference
             # duality: hash primary, sort fallback)
         return self._jit_update(pre, self._key_words(pre))
+
+    def _hash_tiers(self, sites, batch: ColumnarBatch
+                    ) -> Optional[ColumnarBatch]:
+        """The hash path's tiers, counted: 2 rounds, then 6 where keys
+        were left over (each reads `leftover` on the host, and with it the
+        partial's size, so that it leaves in a tight bucket); None where 6
+        left some too and the caller falls to the sort path."""
+        _note(hash_updates=1)
+        for rounds in (2, 6):
+            out, leftover = sites[rounds](batch)
+            # one host read: the flag, and what a tight bucket needs
+            leftover, sizes = jax.device_get((leftover, _partial_size(out)))
+            if not leftover:
+                return _tight_partial(out, sizes)
+            _note(**{"hash_round_retries" if rounds == 2
+                     else "exact_fallbacks": 1})
+        return None
 
     def _merge_jitted(self, batch: ColumnarBatch) -> ColumnarBatch:
         if self._masked_ok:
             return self._jit_merge_auto(batch)
         if self._hash_path_ok:
-            for rounds in (2, 6):
-                out, leftover = self._jit_merge_hash[rounds](batch)
-                if not bool(leftover):
-                    return out
+            out = self._hash_tiers(self._jit_merge_hash, batch)
+            if out is not None:
+                return out
         return self._jit_merge(batch, self._key_words(batch))
 
     def _spill_wrap(self, fn):
@@ -748,6 +848,11 @@ class AggregateExec(TpuExec):
         """Concat + re-aggregate; under OOM the retry framework splits the
         set of partial batches and re-merges the halves (always correct:
         merge ops are associative & commutative)."""
+        with op_span("agg.merge", phase="group-agg"):
+            return self._merge_all_spanned(aggregated)
+
+    def _merge_all_spanned(self, aggregated: List[SpillableBatch]
+                           ) -> ColumnarBatch:
         extra_owned: List[SpillableBatch] = []
 
         def split_set(items: List[SpillableBatch]):

@@ -24,8 +24,10 @@ from ..expr.core import BoundReference, Expression, resolve
 from ..memory.retry import split_in_half_by_rows, with_retry, with_retry_no_split
 from ..memory.spillable import SpillableBatch
 from ..ops.basic import active_mask, slice_rows
+from ..obs.span import op_span
 from ..ops.sort import (
-    SortOrder, order_key_lanes, sort_batch_columns, string_words_for,
+    SortOrder, order_key_lanes, sort_batch_columns, string_key_bytes,
+    string_words_for,
 )
 from ..types import Schema
 from .base import (DEBUG, DISPATCH_METRICS, GATHER_METRICS, GATHER_TIME,
@@ -73,7 +75,7 @@ class SortExec(TpuExec):
         super().__init__(child)
         self.orders = resolve_sort_orders(orders, child.output_schema)
         self.limit = limit
-        # one compiled sort program per (capacity bucket, string words);
+        # one compiled sort program per (capacity bucket, key bytes);
         # the site is plan-fingerprint cached (ISSUE 14) so a rebuilt
         # identical plan reuses it across collects
         self._jit_sort = self._site(self._sort_kernel,
@@ -103,15 +105,21 @@ class SortExec(TpuExec):
         return string_words_for(batch.columns,
                                 [o.ordinal for o in self.orders])
 
-    def _sort_kernel(self, batch: ColumnarBatch, words: int) -> ColumnarBatch:
+    def _sort_kernel(self, batch: ColumnarBatch,
+                     key_bytes: int) -> ColumnarBatch:
         cols, _ = sort_batch_columns(batch.columns, self.orders,
-                                     batch.num_rows, batch.capacity, words)
+                                     batch.num_rows, batch.capacity,
+                                     key_bytes)
         return ColumnarBatch(cols, batch.num_rows, batch.schema)
 
     def _sort_one(self, batch: ColumnarBatch) -> ColumnarBatch:
-        words = self._string_words(batch)
-        with self._gather_track.observe((batch.capacity, words)):
-            out = self._jit_sort(batch, words)
+        with op_span("sort.result", phase="sort"):
+            # the key lanes follow the keys' MEASURED width (one host
+            # sync): down to one byte a key as well as up
+            key_bytes = string_key_bytes(batch.columns,
+                                         [o.ordinal for o in self.orders])
+            with self._gather_track.observe((batch.capacity, key_bytes)):
+                out = self._jit_sort(batch, key_bytes)
         out = ColumnarBatch(out.columns, batch.num_rows, batch.schema,
                             batch._host_rows)
         if self.limit is not None:

@@ -145,7 +145,7 @@ class WindowExec(TpuExec):
         orders = [SortOrder(s) for s in self._part_slots] + [
             SortOrder(s, asc, nf) for s, (asc, nf)
             in zip(self._order_slots, self._order_dirs)]
-        perm = sort_permutation(batch.columns, orders, n, cap, words)
+        perm = sort_permutation(batch.columns, orders, n, cap, 8 * words)
         # round 8: the partition-sort permutation moves the whole batch
         # through the gather engine — ONE packed row gather for the
         # fixed-width columns instead of one gather per column

@@ -76,6 +76,11 @@ PHASES = (
                            # (columnar/transfer.py, TpuExec.collect)
     "join-build",          # a fused join's build side: child drain,
                            # concat, decode enqueue (exec/stage_compiler)
+    "group-agg",           # a group-by the stage compiler did not fuse:
+                           # each source batch's update with its
+                           # leftover syncs, and the merge (exec/aggregate)
+    "sort",                # a result sort with its key-width sync
+                           # (exec/sort.py)
     "other",               # derived remainder — never negative
 )
 

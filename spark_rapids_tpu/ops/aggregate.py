@@ -295,7 +295,7 @@ def groupby_aggregate(key_columns: Sequence[Column],
         # the old per-column gather-by-permutation cost ~26 ms per column
         from .sort import sort_batch_columns
         sorted_all, _ = sort_batch_columns(all_cols, orders, num_rows,
-                                           capacity, string_words)
+                                           capacity, 8 * string_words)
     sorted_keys = sorted_all[: len(key_columns)]
     sorted_in = sorted_all[len(key_columns):]
     seg, num_groups = group_segment_ids(sorted_keys, num_rows, capacity,

@@ -12,6 +12,15 @@ doubles as the permutation used to gather the payload columns.
 
 Inactive rows (index >= num_rows) always sort last via a leading
 activity lane, so sorted batches keep the packed-prefix invariant.
+
+The SORT's lanes (`packed_key_lanes`) are those fields at the width they
+need, packed most significant first into as few u32 lanes as they fill: the
+chip's compiler takes ~15 s a key lane up to four and does not finish twenty
+(PERF.md). A string key's width is measured (`string_key_bytes`) and goes
+down to one byte as well as up. `order_key_lanes` keeps the unpacked stack
+(one lane a field, u64 string prefixes of at least DEFAULT_STRING_WORDS) for
+the readers that compare lanes across batches or to their neighbours: segment
+ids, the out-of-core merge's bound, window partitions.
 """
 
 from __future__ import annotations
@@ -23,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar.column import Column, StringColumn
+from ..obs.dispatch import instrument
 from ..types import BooleanType, DataType
 from .basic import active_mask, gather_column
 
@@ -121,21 +131,39 @@ def string_prefix_lanes(col: StringColumn, num_words: int) -> List[jnp.ndarray]:
     return lanes
 
 
+def _max_string_length(offsets):
+    return jnp.max(jnp.stack([jnp.max(o[1:] - o[:-1]) for o in offsets]))
+
+
+# through the dispatch ledger like every other program: run eagerly the
+# subtract and the two reductions were device time under no label
+_max_string_length_jit = instrument(_max_string_length,
+                                    label="sort.key_width")
+
+
+def string_key_bytes(columns: Sequence[Column],
+                     ordinals: Sequence[int]) -> int:
+    """Byte width making string ordering EXACT for these batches: measures
+    the longest string of the key columns on device (one program, one host
+    sync, outside jit) and rounds UP to a power of two (1, 2, 4, 8, ...)
+    so the static width buckets like capacities do. Goes DOWN to what the
+    keys need: CHAR(1) keys sort on one byte each, not on 32. 1 where no
+    key is a string (no sync)."""
+    offsets = tuple(columns[i].offsets for i in ordinals
+                    if isinstance(columns[i], StringColumn))
+    if not offsets:
+        return 1
+    need = max(1, int(_max_string_length_jit(offsets)))
+    return 1 << (need - 1).bit_length()
+
+
 def string_words_for(columns: Sequence[Column], ordinals: Sequence[int],
                      num_rows=None) -> int:
-    """Lane count making string ordering EXACT for these batches: measures
-    the max string length on device (one host sync, outside jit) and rounds
-    to a power-of-two word count so lane shapes bucket like capacities do."""
-    words = DEFAULT_STRING_WORDS
-    for i in ordinals:
-        col = columns[i]
-        if isinstance(col, StringColumn):
-            lengths = col.offsets[1:] - col.offsets[:-1]
-            max_len = int(jnp.max(lengths))
-            need = max(1, -(-max_len // 8))
-            while words < need:
-                words *= 2
-    return words
+    """`string_key_bytes` as a count of 8-byte words, never under
+    DEFAULT_STRING_WORDS: the width of `order_key_lanes`' u64 prefix lanes
+    (segment ids, the out-of-core merge's bound, window partitions, the
+    hash group-by's string min/max)."""
+    return max(DEFAULT_STRING_WORDS, string_key_bytes(columns, ordinals) // 8)
 
 
 def order_key_lanes(columns: Sequence[Column], orders: Sequence[SortOrder],
@@ -182,13 +210,97 @@ def _split_u64_lanes(lanes):
     return out
 
 
+def _string_key_fields(col: StringColumn, key_bytes: int):
+    """The first `key_bytes` bytes of each string, big-endian and
+    zero-padded (a shorter string sorts before its extensions), as
+    (u32 lane, bits) fields: one sub-word field under four bytes, else
+    whole u32 words."""
+    cap = col.capacity
+    starts = col.offsets[:cap]
+    lengths = col.offsets[1:] - starts
+    byte_cap = col.byte_capacity
+    step = min(key_bytes, 4)
+    fields = []
+    for w in range(0, key_bytes, step):
+        word = jnp.zeros((cap,), jnp.uint32)
+        for j in range(w, w + step):
+            safe = jnp.clip(starts + j, 0, byte_cap - 1)
+            byte = jnp.where(j < lengths, col.data[safe], 0)
+            word = (word << jnp.uint32(8)) | byte.astype(jnp.uint32)
+        fields.append((word, 8 * step))
+    return fields
+
+
+def _numeric_key_fields(col: Column):
+    """One fixed-width column as (u32 lane, bits) fields in value order: a
+    BOOLEAN is one bit, a narrow integer its own width, a 64-bit lane its
+    high and low words."""
+    lanes = numeric_order_lanes(col)
+    if len(lanes) == 1 and col.data.dtype == jnp.bool_:
+        return [(lanes[0], 1)]
+    return [(lane.astype(jnp.uint32), 8 * lane.dtype.itemsize)
+            for lane in _split_u64_lanes(lanes)]
+
+
+def _pack_fields(fields) -> List[jnp.ndarray]:
+    """Concatenate (u32 lane, bits) fields, most significant first, into
+    as few u32 lanes as their bits fill. Plain ascending lexicographic
+    order of the lanes is the lexicographic order of the fields: a field
+    may straddle two lanes, and only the last lane has spare (low, zero)
+    bits."""
+    lanes: List[jnp.ndarray] = []
+    cur, free = None, 32
+    for v, bits in fields:
+        if bits > free:
+            # the high part fills the open lane, the low part opens the next
+            low = bits - free
+            lanes.append(cur | (v >> jnp.uint32(low)))
+            v = v & jnp.uint32((1 << low) - 1)
+            cur, free, bits = None, 32, low
+        v = v << jnp.uint32(free - bits) if free > bits else v
+        cur = v if cur is None else cur | v
+        free -= bits
+        if free == 0:
+            lanes.append(cur)
+            cur, free = None, 32
+    if cur is not None:
+        lanes.append(cur)
+    return lanes
+
+
+def packed_key_lanes(columns: Sequence[Column], orders: Sequence[SortOrder],
+                     num_rows, capacity: int, key_bytes: int
+                     ) -> List[jnp.ndarray]:
+    """The sort's key lanes: `order_key_lanes`' fields (activity, then per
+    key its null rank and its value) at the width they need, packed into as
+    few u32 lanes as they fill. A string key takes `key_bytes` bytes
+    (`string_key_bytes`: exact where it covers the longest string). Two
+    CHAR(1) keys are 1 + 2 x (1 + 8) = 19 bits: ONE lane, where the
+    unpacked stack was 19 (the chip's compiler takes ~15 s for one u32 key
+    lane and 110 s for four)."""
+    act = active_mask(num_rows, capacity)
+    fields = [((~act).astype(jnp.uint32), 1)]
+    for o in orders:
+        col = columns[o.ordinal]
+        valid = col.validity & act
+        null_rank = valid if o.nulls_first else ~valid
+        fields.append((null_rank.astype(jnp.uint32), 1))
+        vfields = _string_key_fields(col, key_bytes) \
+            if isinstance(col, StringColumn) else _numeric_key_fields(col)
+        for v, bits in vfields:
+            v = jnp.where(valid, v, jnp.uint32(0))
+            if not o.ascending:
+                v = v ^ jnp.uint32((1 << bits) - 1)
+            fields.append((v, bits))
+    return _pack_fields(fields)
+
+
 def sort_permutation(columns: Sequence[Column], orders: Sequence[SortOrder],
                      num_rows, capacity: int,
-                     string_words: int = DEFAULT_STRING_WORDS):
+                     key_bytes: int = 8 * DEFAULT_STRING_WORDS):
     """Stable sort permutation: int32 (capacity,) such that gathering by it
     yields rows in the requested order, inactive rows last."""
-    lanes = _split_u64_lanes(
-        order_key_lanes(columns, orders, num_rows, capacity, string_words))
+    lanes = packed_key_lanes(columns, orders, num_rows, capacity, key_bytes)
     iota = jnp.arange(capacity, dtype=jnp.int32)
     out = jax.lax.sort(tuple(lanes) + (iota,), num_keys=len(lanes))
     return out[-1]
@@ -196,7 +308,7 @@ def sort_permutation(columns: Sequence[Column], orders: Sequence[SortOrder],
 
 def sort_batch_columns(columns: Sequence[Column], orders: Sequence[SortOrder],
                        num_rows, capacity: int,
-                       string_words: int = DEFAULT_STRING_WORDS,
+                       key_bytes: int = 8 * DEFAULT_STRING_WORDS,
                        ) -> Tuple[List[Column], jnp.ndarray]:
     """Sort all columns of a batch; returns (sorted columns, permutation).
 
@@ -207,8 +319,7 @@ def sort_batch_columns(columns: Sequence[Column], orders: Sequence[SortOrder],
     a KEY so the sort is stable and varlen columns still gather by it.
     """
     from .rowpack import pack_rows, split_packable, unpack_rows
-    lanes = _split_u64_lanes(
-        order_key_lanes(columns, orders, num_rows, capacity, string_words))
+    lanes = packed_key_lanes(columns, orders, num_rows, capacity, key_bytes)
     iota = jnp.arange(capacity, dtype=jnp.int32)
     p_idx, o_idx = split_packable(columns)
     out: List = [None] * len(columns)

@@ -12,7 +12,11 @@ decode of a string column goes through, needs no search (ISSUE 32): the
 source of output byte p is p + (src_start - out_start) of p's row, a step
 function of p that changes only at row starts, so it marks each row start
 with the step's change (N-wide) and takes ONE prefix sum over the byte
-bucket. The other kernels keep the search until a benchmark cell runs them.
+bucket. `concat_string`, the coalesce's primitive for a scanned string
+column, takes the same form (ISSUE 35): its rows' lengths, validity and
+starts move as two contiguous blocks, and one gather reads the two inputs'
+bytes laid end to end. The other kernels keep the search until a benchmark
+cell runs them.
 """
 
 from __future__ import annotations
@@ -56,6 +60,29 @@ def _rebuild_offsets(lengths):
     ])
 
 
+def _bytes_by_row_marks(table, src_starts, new_offsets, byte_cap: int):
+    """(byte_cap,) uint8: output row r's bytes are `table[src_starts[r]:]`
+    laid from `new_offsets[r]` on; zeros past the last row.
+
+    The source of output byte p is p + delta[row owning p], with delta =
+    src_start - out_start constant over a row: mark each row's start with
+    delta's change from the row before and prefix-sum the marks: one N-wide
+    scatter and one B-wide scan, where finding the row by searchsorted is
+    ~log N B-wide gathers (18x the time at Q14's shapes, 45x at 8M rows on
+    v5e). The marks are ADDED: empty rows share a start with the next row
+    and telescope to the last one's delta; starts at or past the bucket
+    drop."""
+    pos = jnp.arange(byte_cap, dtype=jnp.int32)
+    delta = src_starts - new_offsets[:-1]
+    marks = jnp.zeros(byte_cap, jnp.int32).at[new_offsets[:-1]].add(
+        jnp.diff(delta, prepend=jnp.int32(0)), mode="drop",
+        indices_are_sorted=True)
+    src_pos = pos + jnp.cumsum(marks, dtype=jnp.int32)
+    in_use = pos < new_offsets[-1]
+    src_pos = jnp.where(in_use, jnp.clip(src_pos, 0, table.shape[0] - 1), 0)
+    return jnp.where(in_use, table[src_pos], jnp.uint8(0))
+
+
 def gather_string(col: StringColumn, indices, out_valid,
                   out_byte_capacity: int | None = None) -> StringColumn:
     """Gather rows of a string column by pre-clamped int32 `indices`.
@@ -70,57 +97,38 @@ def gather_string(col: StringColumn, indices, out_valid,
     new_offsets = _rebuild_offsets(lengths)
     src_starts = col.offsets[indices]
 
-    pos = jnp.arange(byte_cap, dtype=jnp.int32)
-    # source of output byte p: p + delta[row owning p]. delta is constant
-    # over a row, so mark each row's start with delta's change from the row
-    # before and prefix-sum the marks: one N-wide scatter and one B-wide
-    # scan, where finding the row by searchsorted is ~log N B-wide gathers
-    # (18x the time at Q14's shapes, 45x at 8M rows on v5e). The marks are
-    # ADDED: empty rows share a start with the next row and telescope to
-    # the last one's delta; starts at or past the bucket drop.
-    delta = src_starts - new_offsets[:-1]
-    marks = jnp.zeros(byte_cap, jnp.int32).at[new_offsets[:-1]].add(
-        jnp.diff(delta, prepend=jnp.int32(0)), mode="drop",
-        indices_are_sorted=True)
-    src_pos = pos + jnp.cumsum(marks, dtype=jnp.int32)
-    in_use = pos < new_offsets[-1]
-    src_pos = jnp.where(in_use, jnp.clip(src_pos, 0, col.byte_capacity - 1), 0)
-    data = jnp.where(in_use, col.data[src_pos], jnp.uint8(0))
+    data = _bytes_by_row_marks(col.data, src_starts, new_offsets, byte_cap)
     return StringColumn(data, new_offsets, out_valid, col.dtype)
 
 
 def concat_string(a: StringColumn, b: StringColumn, a_rows, b_rows,
                   out_capacity: int,
                   out_byte_capacity: int | None = None) -> StringColumn:
-    """Concatenate active rows of two string columns."""
+    """Concatenate active rows of two string columns.
+
+    The per-row lanes (lengths, validity, source starts) are a's first
+    a_rows then b's, two contiguous blocks at a traced offset as for a
+    fixed-width lane (`basic._concat_fixed`): no per-row index gather. The
+    bytes come from ONE table, a's buffer then b's: the source of output
+    byte p is p + (start in the table - out_start) of p's row, found from
+    row-start marks and one prefix sum (`_bytes_by_row_marks`). The per-byte
+    `searchsorted` this replaces was ~23 B-wide gathers at the top of a
+    32-batch coalesce: 25.1 s of a TPC-H Q1 query's 47.9 s on v5e
+    (PERF.md, PR 35)."""
+    from .basic import _concat_fixed
     byte_cap = out_byte_capacity or (a.byte_capacity + b.byte_capacity)
-    idx = jnp.arange(out_capacity, dtype=jnp.int32)
-    from_b = idx >= a_rows
-    total = a_rows + b_rows
-    out_valid_slot = idx < total
-
-    a_len = string_lengths(a)
-    b_len = string_lengths(b)
-    a_idx = jnp.where(idx < a.capacity, idx, 0)
-    b_idx = jnp.clip(idx - a_rows, 0, b.capacity - 1)
-    lengths = jnp.where(from_b, b_len[b_idx], a_len[a_idx])
-    lengths = jnp.where(out_valid_slot, lengths, 0)
-    validity = jnp.where(from_b, b.validity[b_idx], a.validity[a_idx]) & out_valid_slot
+    live = jnp.arange(out_capacity, dtype=jnp.int32) < a_rows + b_rows
+    lengths = jnp.where(live, _concat_fixed(
+        string_lengths(a), string_lengths(b), a_rows, out_capacity), 0)
+    validity = _concat_fixed(a.validity, b.validity, a_rows,
+                             out_capacity) & live
     new_offsets = _rebuild_offsets(lengths)
-    src_starts = jnp.where(from_b, b.offsets[b_idx], a.offsets[a_idx])
+    src_starts = _concat_fixed(a.offsets[:-1],
+                               b.offsets[:-1] + a.byte_capacity,
+                               a_rows, out_capacity)
 
-    pos = jnp.arange(byte_cap, dtype=jnp.int32)
-    row = jnp.searchsorted(new_offsets, pos, side="right").astype(jnp.int32) - 1
-    row = jnp.clip(row, 0, out_capacity - 1)
-    intra = pos - new_offsets[row]
-    src_pos = src_starts[row] + intra
-    row_from_b = from_b[row]
-    in_use = pos < new_offsets[-1]
-    a_bytes = a.data[jnp.where(in_use & ~row_from_b,
-                               jnp.clip(src_pos, 0, a.byte_capacity - 1), 0)]
-    b_bytes = b.data[jnp.where(in_use & row_from_b,
-                               jnp.clip(src_pos, 0, b.byte_capacity - 1), 0)]
-    data = jnp.where(in_use, jnp.where(row_from_b, b_bytes, a_bytes), jnp.uint8(0))
+    data = _bytes_by_row_marks(jnp.concatenate([a.data, b.data]), src_starts,
+                               new_offsets, byte_cap)
     return StringColumn(data, new_offsets, validity, a.dtype)
 
 
