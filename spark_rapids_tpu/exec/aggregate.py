@@ -56,8 +56,8 @@ _COUNTERS = {
     # compiler did not fuse
     "executions": 0,
     # batches (source batches, and merges of partials) that took the hash
-    # path: `update_hash` / `merge_hash` at 2 rounds and one host read of
-    # `leftover`
+    # path: `update_hash` / `merge_hash` at its first tier (the lane tier
+    # where it applies, else 2 rounds) and one host read of `leftover`
     "hash_updates": 0,
     # of those, the ones whose 2 rounds left keys over and that went on to
     # 6 rounds: a second program and a second host sync
@@ -65,7 +65,36 @@ _COUNTERS = {
     # of those, the ones 6 rounds did not resolve either and that fell to
     # the exact sort path
     "exact_fallbacks": 0,
+    # of `hash_updates`, the ones the lane tier resolved: string keys as
+    # packed fixed-width lanes through the masked-bucket kernel, before
+    # any hash round
+    "lane_updates": 0,
+    # the ones whose schema allows the lane tier and whose measured keys
+    # were too wide for it (the hash rounds ran as without it)
+    "lane_declines": 0,
+    # the ones the lane tier ran on and left keys over (more distinct keys
+    # than its slots): they went on to the hash rounds
+    "lane_leftovers": 0,
 }
+
+#: what a tier that left keys over counts, by its key in the tiers' sites
+_LEFTOVER_COUNTER = {"lanes": "lane_leftovers", 2: "hash_round_retries",
+                     6: "exact_fallbacks"}
+
+#: the lane tier takes string keys up to this many bytes, and as many key
+#: lane columns as `masked_group_assignment`'s packed stats word holds
+LANE_KEY_BYTES = 16
+LANE_KEY_COLUMNS = 16
+
+
+def _masked_type(dt: DataType) -> bool:
+    """A column type the masked-bucket kernels reduce (and order as ONE
+    static lane): fixed-width, not decimal128."""
+    from ..types import (ArrayType, BinaryType, DecimalType, StringType,
+                         StructType)
+    return not (isinstance(dt, (StringType, BinaryType, StructType,
+                                ArrayType))
+                or (isinstance(dt, DecimalType) and dt.is_decimal128))
 
 
 def _note(**deltas) -> None:
@@ -241,8 +270,11 @@ class AggregateExec(TpuExec):
         self._jit_merge = self._site(self._merge_batch,
                                      label="AggregateExec.merge",
                                      static_argnums=(1,))
-        # hash-path tiers: cheap 2-round first, 6-round escalation for
-        # mid-cardinality, exact sort as the last resort
+        # hash-path tiers: where the schema allows it (`_lane_ok`) the
+        # lane tier first (short string keys as packed lanes through the
+        # masked-bucket kernel: no scatter, no hash round), then the cheap
+        # 2-round hash, the 6-round escalation for mid-cardinality, and
+        # the exact sort as the last resort
         self._jit_update_hash = {
             r: self._site(partial(self._update_batch, hash_path=True,
                                   hash_rounds=r),
@@ -253,6 +285,13 @@ class AggregateExec(TpuExec):
                                   hash_rounds=r),
                           label="AggregateExec.merge_hash", key_salt=r)
             for r in (2, 6)}
+        if self._lane_ok:
+            self._jit_update_hash["lanes"] = self._site(
+                self._lane_update, label="AggregateExec.update_hash",
+                key_salt="lanes", static_argnums=(1,))
+            self._jit_merge_hash["lanes"] = self._site(
+                self._lane_merge, label="AggregateExec.merge_hash",
+                key_salt="lanes", static_argnums=(1,))
         # sync-free exact merge: masked buckets + in-program sort fallback
         self._jit_merge_auto = self._site(
             partial(self._merge_batch, auto_path=True),
@@ -506,6 +545,25 @@ class AggregateExec(TpuExec):
         both = ColumnarBatch(cols, a.num_rows + b.num_rows,
                              self._buffer_schema)
         return self._merge_batch(both, auto_path=True)
+
+    def _lane_groupby(self, keys, agg_inputs, batch: ColumnarBatch,
+                      key_bytes: int):
+        """The lane tier's program: (partial in the SMALL bucket, leftover).
+        Where `leftover` is set the partial is not an answer."""
+        from ..ops.maskedagg import masked_groupby_lanes
+        out_keys, results, num_groups, leftover = masked_groupby_lanes(
+            keys, agg_inputs, batch.num_rows, batch.capacity, key_bytes,
+            self._slots, self._rounds)
+        return self._build_small_batch(out_keys, results, num_groups), \
+            leftover
+
+    def _lane_update(self, batch: ColumnarBatch, key_bytes: int):
+        return self._lane_groupby(*self._update_inputs(batch), batch,
+                                  key_bytes)
+
+    def _lane_merge(self, batch: ColumnarBatch, key_bytes: int):
+        return self._lane_groupby(*self._merge_inputs(batch), batch,
+                                  key_bytes)
 
     def _run_groupby(self, keys, agg_inputs, batch, out_schema, words: int,
                      hash_path: bool = False, hash_rounds: int = 2,
@@ -777,15 +835,39 @@ class AggregateExec(TpuExec):
     def _masked_ok(self) -> bool:
         """True when the masked-bucket kernels apply: every key and buffer
         column is fixed-width (strings have no static order lanes for the
-        in-program exact fallback and no masked min/max encoding)."""
-        from ..types import (ArrayType, BinaryType, DecimalType, StringType,
-                             StructType)
-        return not any(
-            isinstance(f.data_type,
-                       (StringType, BinaryType, StructType, ArrayType))
-            or (isinstance(f.data_type, DecimalType)
-                and f.data_type.is_decimal128)
-            for f in self._buffer_schema.fields)
+        in-program exact fallback and no masked min/max encoding; string
+        KEYS beside such buffers take the hash tiers' lane tier,
+        `_lane_ok`)."""
+        return all(_masked_type(f.data_type)
+                   for f in self._buffer_schema.fields)
+
+    @property
+    def _lane_ok(self) -> bool:
+        """True when the hash tiers start with the lane tier: the masked
+        kernel sums every BUFFER, and what keeps the group-by off it
+        (`_masked_ok`) is only STRING keys, which ride as packed lanes
+        where the batch's keys measure short enough (`_lane_key_bytes`)."""
+        from ..types import StringType
+        fields = self._buffer_schema.fields
+        return (not self._masked_ok
+                and all(_masked_type(f.data_type)
+                        for f in fields[self._key_count:])
+                and all(_masked_type(f.data_type)
+                        or isinstance(f.data_type, StringType)
+                        for f in fields[: self._key_count]))
+
+    def _lane_key_bytes(self, batch: ColumnarBatch) -> Optional[int]:
+        """The static key width the lane tier runs this batch at (one host
+        read of the keys' longest string, a power of two), or None where
+        the keys are too wide for it."""
+        from ..ops.maskedagg import key_lane_count
+        from ..ops.sort import string_key_bytes
+        key_bytes = string_key_bytes(batch.columns, range(self._key_count))
+        lanes = sum(key_lane_count(f.data_type, key_bytes)
+                    for f in self._buffer_schema.fields[: self._key_count])
+        if key_bytes > LANE_KEY_BYTES or lanes > LANE_KEY_COLUMNS:
+            return None
+        return key_bytes
 
     @property
     def _sync_free(self) -> bool:
@@ -811,19 +893,30 @@ class AggregateExec(TpuExec):
 
     def _hash_tiers(self, sites, batch: ColumnarBatch
                     ) -> Optional[ColumnarBatch]:
-        """The hash path's tiers, counted: 2 rounds, then 6 where keys
-        were left over (each reads `leftover` on the host, and with it the
-        partial's size, so that it leaves in a tight bucket); None where 6
-        left some too and the caller falls to the sort path."""
+        """The hash path's tiers, counted: the lane tier where the schema
+        and the measured keys allow it, then 2 hash rounds, then 6, each
+        where the one before left keys over (each reads `leftover` on the
+        host, and with it the partial's size, so that it leaves in a tight
+        bucket); None where 6 left some too and the caller falls to the
+        sort path."""
         _note(hash_updates=1)
-        for rounds in (2, 6):
-            out, leftover = sites[rounds](batch)
+        tiers = [(r, sites[r]) for r in (2, 6)]
+        if "lanes" in sites:
+            key_bytes = self._lane_key_bytes(batch)
+            if key_bytes is None:
+                _note(lane_declines=1)
+            else:
+                tiers.insert(0, ("lanes",
+                                 lambda b: sites["lanes"](b, key_bytes)))
+        for tier, program in tiers:
+            out, leftover = program(batch)
             # one host read: the flag, and what a tight bucket needs
             leftover, sizes = jax.device_get((leftover, _partial_size(out)))
             if not leftover:
+                if tier == "lanes":
+                    _note(lane_updates=1)
                 return _tight_partial(out, sizes)
-            _note(**{"hash_round_retries" if rounds == 2
-                     else "exact_fallbacks": 1})
+            _note(**{_LEFTOVER_COUNTER[tier]: 1})
         return None
 
     def _merge_jitted(self, batch: ColumnarBatch) -> ColumnarBatch:

@@ -18,8 +18,16 @@ in practice for cardinality << capacity, and for cardinality ~ capacity
 the sort path is the right algorithm anyway.
 
 Cost: O(R·n) scatters/gathers/compares, no O(n log n) sort, no
-data-dependent shapes. This is the hot kernel for TPC-style low-to-mid
-cardinality aggregations.
+data-dependent shapes: the kernel for mid-cardinality aggregations and for
+keys the masked-bucket kernel cannot take. On a TPU every one of those
+scatters and row gathers serializes, and a string key is compared byte by
+byte behind a per-byte search (`string_equal`): 20 s for 8M rows and four
+groups on v5e (PERF.md, PR 35). So the exec runs these rounds SECOND where
+it can: string keys that measure at most 16 bytes go first through the
+masked-bucket kernel as packed lanes (ops/maskedagg.masked_groupby_lanes,
+exec/aggregate._hash_tiers), and come here where that leaves keys over
+(more distinct keys than its slots), where the keys are wider, or where a
+buffer is a string.
 """
 
 from __future__ import annotations

@@ -26,8 +26,19 @@ the upstream filter/project into ONE XLA program with ZERO host syncs.
 
 `leftover` handling is the caller's choice: speculate (emit the small
 partial + device flag; plan-level retry re-runs exact if it ever trips —
-exec/speculation.py) or wrap in lax.cond with the exact sort-based kernel
-(masked_groupby_exact).
+exec/speculation.py), wrap in lax.cond with the exact sort-based kernel
+(masked_groupby_exact), or read the flag on the host and run another kernel
+(the hash group-by's lane tier, exec/aggregate._hash_tiers).
+
+Keys and buffers are fixed-width columns. A STRING key no longer than 16
+bytes is one too: its length and its zero-padded bytes, packed into 32-bit
+lanes (`string_key_lanes`; the width is measured by the caller, a static
+argument), equal exactly when the strings are. `masked_groupby_lanes` hands
+such keys to the same assignment and spells the result's few key strings
+back from the slots' lanes: no scatter, no row gather and no compare by
+bytes over the source rows (the hash update those replace spent 20 s on
+8M rows for four groups on v5e; PERF.md, PR 36). String BUFFERS (min / max
+/ first of a string) stay with the hash and sort tiers.
 """
 
 from __future__ import annotations
@@ -218,10 +229,12 @@ def masked_group_assignment(key_cols: Sequence[Column], num_rows,
 
 
 def _slot_sweep(agg_inputs, seg, positions, capacity: int, n_slots: int,
-                G: int, R: int, occ):
+                G: int, R: int, occ, skip=None):
     """All aggregates over all slots, skipping the slots past the first G
     on device when no group resolved after round 1 (the common
-    low-cardinality case pays for G slots, not R*G)."""
+    low-cardinality case pays for G slots, not R*G). `skip` (a device
+    bool, or None) skips the sweep altogether and returns zeros: for a
+    caller that throws the result away where keys were left over."""
 
     def sweep(S: int):
         si = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -243,10 +256,17 @@ def _slot_sweep(agg_inputs, seg, positions, capacity: int, n_slots: int,
             outs.append((svals, svalid))
         return tuple(outs)
 
-    if R > 1 and agg_inputs:
-        return jax.lax.cond(jnp.any(occ[G:]), lambda _: sweep(n_slots),
-                            lambda _: sweep(G), None)
-    return sweep(n_slots)
+    def swept(_=None):
+        if R > 1 and agg_inputs:
+            return jax.lax.cond(jnp.any(occ[G:]), lambda _: sweep(n_slots),
+                                lambda _: sweep(G), None)
+        return sweep(n_slots)
+
+    if skip is None or not agg_inputs:
+        return swept()
+    zeros = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype), jax.eval_shape(swept))
+    return jax.lax.cond(skip, lambda _: zeros, swept, None)
 
 
 def _decimal_limbs(col: Column):
@@ -419,14 +439,18 @@ def _slot_reduce(op: str, m, col: Optional[Column], positions,
 def masked_groupby(key_columns: Sequence[Column],
                    agg_inputs: Sequence[Tuple[str, Optional[Column]]],
                    num_rows, capacity: int, row_mask=None,
-                   group_slots: int = 32, rounds: int = 2):
+                   group_slots: int = 32, rounds: int = 2,
+                   sweep_unless_leftover: bool = False):
     """Group-by into a SMALL output bucket (capacity bucket_capacity(R*G)).
 
     Returns (out_keys, tagged results, num_groups, leftover). When
     `leftover` is True the output is INCOMPLETE (rows of dirty buckets are
     dropped) — the caller must either lax.cond to an exact kernel or run
-    under a speculation scope that re-executes the plan exactly.
-    No strings (keys or buffers) — callers gate on schema.
+    under a speculation scope that re-executes the plan exactly; with
+    `sweep_unless_leftover` the aggregates are then not computed at all
+    (zeros), for a caller that reads the flag and discards the output.
+    Fixed-width keys and buffers only — callers gate on schema; short
+    string KEYS come in as lanes through `masked_groupby_lanes`.
     """
     G, R = group_slots, rounds
     n_slots = R * G
@@ -462,7 +486,8 @@ def masked_groupby(key_columns: Sequence[Column],
                 "string buffers take the sort/hash tiers")
 
     sweeps = _slot_sweep(agg_inputs, seg, positions, capacity, n_slots,
-                         G, R, occ)
+                         G, R, occ,
+                         skip=leftover if sweep_unless_leftover else None)
 
     results = []
     for svals, svalid in sweeps:
@@ -475,6 +500,92 @@ def masked_groupby(key_columns: Sequence[Column],
         data, v = _place(vals, valid)
         data = jnp.where(v, data, jnp.zeros((), data.dtype))
         out_keys.append(Column(data, v, c.dtype))
+    return out_keys, results, num_groups, leftover
+
+
+def _string_lane_widths(key_bytes: int) -> List[int]:
+    """Bit widths of a short string key's fields: its length in bytes
+    (0..key_bytes), then its zero-padded bytes as `_string_key_fields`
+    cuts them (one sub-word field under four bytes, else whole words)."""
+    step = min(key_bytes, 4)
+    return [key_bytes.bit_length()] + [8 * step] * (key_bytes // step)
+
+
+def key_lane_count(dtype: DataType, key_bytes: int) -> int:
+    """How many key columns `masked_groupby_lanes` hands the assignment
+    for one key of this type: a string's packed lanes, else the column."""
+    from ..types import StringType
+    if not isinstance(dtype, StringType):
+        return 1
+    return -(-sum(_string_lane_widths(key_bytes)) // 32)
+
+
+def string_key_lanes(col: StringColumn, key_bytes: int) -> List[Column]:
+    """A string key of at most `key_bytes` bytes as fixed-width key columns.
+
+    Such a string IS its length and its zero-padded bytes: two are equal
+    exactly when those are (so "a" and "a\0" differ, by length), packed
+    most significant first into as few 32-bit lanes as they fill (CHAR(1):
+    9 bits, one lane; 16 bytes: five). The lanes keep the column's
+    validity, so NULL stays apart from ""; a NULL row's lanes are zero."""
+    from ..types import INT
+    from .sort import _pack_fields, _string_key_fields
+    from .strings import string_lengths
+    fields = [(string_lengths(col).astype(jnp.uint32),
+               _string_lane_widths(key_bytes)[0])] \
+        + _string_key_fields(col, key_bytes)
+    return [Column(jax.lax.bitcast_convert_type(
+                jnp.where(col.validity, lane, jnp.uint32(0)), jnp.int32),
+                   col.validity, INT)
+            for lane in _pack_fields(fields)]
+
+
+def string_from_key_lanes(lanes: Sequence[Column], key_bytes: int,
+                          dtype: DataType) -> StringColumn:
+    """Inverse of `string_key_lanes`, for the few rows of a result: the
+    string column those lanes spell (no row of the source is read)."""
+    from .sort import _unpack_fields
+    from .strings import string_from_padded
+    widths = _string_lane_widths(key_bytes)
+    length, *words = _unpack_fields(
+        [jax.lax.bitcast_convert_type(c.data, jnp.uint32) for c in lanes],
+        widths)
+    # a word's bytes, most significant first
+    padded = jnp.stack(
+        [(w >> jnp.uint32(shift)).astype(jnp.uint8)
+         for w, bits in zip(words, widths[1:])
+         for shift in range(bits - 8, -8, -8)], axis=1)
+    return string_from_padded(length.astype(jnp.int32), padded,
+                              lanes[0].validity, dtype)
+
+
+def masked_groupby_lanes(key_columns: Sequence[Column],
+                         agg_inputs: Sequence[Tuple[str, Optional[Column]]],
+                         num_rows, capacity: int, key_bytes: int,
+                         group_slots: int = 32, rounds: int = 2):
+    """`masked_groupby` for keys of which some are strings no longer than
+    `key_bytes` bytes (measured by the caller: `sort.string_key_bytes`):
+    each rides as `string_key_lanes`, and the string key columns of the
+    SMALL result are spelt back from the slots' lanes. No scatter, no row
+    gather and no compare by bytes over the source rows beyond the
+    `key_bytes` byte gathers that read a key. The aggregates are swept
+    only where no key was left over: the caller reads `leftover` and runs
+    another kernel where it is set."""
+    spans, lanes = [], []
+    for c in key_columns:
+        own = string_key_lanes(c, key_bytes) \
+            if isinstance(c, StringColumn) else [c]
+        spans.append(len(own))
+        lanes.extend(own)
+    out_lanes, results, num_groups, leftover = masked_groupby(
+        lanes, agg_inputs, num_rows, capacity, None, group_slots, rounds,
+        sweep_unless_leftover=True)
+    out_keys, at = [], 0
+    for c, n in zip(key_columns, spans):
+        own = out_lanes[at:at + n]
+        at += n
+        out_keys.append(string_from_key_lanes(own, key_bytes, c.dtype)
+                        if isinstance(c, StringColumn) else own[0])
     return out_keys, results, num_groups, leftover
 
 

@@ -268,6 +268,30 @@ def _pack_fields(fields) -> List[jnp.ndarray]:
     return lanes
 
 
+def _unpack_fields(lanes: Sequence[jnp.ndarray],
+                   widths: Sequence[int]) -> List[jnp.ndarray]:
+    """Inverse of `_pack_fields`: the u32 fields of those bit widths, in
+    their order, out of the packed lanes."""
+    def low_bits(v, bits: int):
+        return v & jnp.uint32((1 << bits) - 1)
+
+    out: List[jnp.ndarray] = []
+    at, free = 0, 32
+    for bits in widths:
+        if bits > free:
+            low = bits - free
+            v = (low_bits(lanes[at], free) << jnp.uint32(low)) \
+                | (lanes[at + 1] >> jnp.uint32(32 - low))
+            at, free = at + 1, 32 - low
+        else:
+            free -= bits
+            v = low_bits(lanes[at] >> jnp.uint32(free), bits)
+        if free == 0:
+            at, free = at + 1, 32
+        out.append(v)
+    return out
+
+
 def packed_key_lanes(columns: Sequence[Column], orders: Sequence[SortOrder],
                      num_rows, capacity: int, key_bytes: int
                      ) -> List[jnp.ndarray]:

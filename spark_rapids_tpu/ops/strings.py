@@ -721,11 +721,9 @@ def string_from_padded(lengths, padded, validity,
     lengths = jnp.where(validity, lengths, 0)
     offsets = _rebuild_offsets(lengths)
     byte_cap = bucket_capacity(max(cap * width, 1))
-    pos = jnp.arange(byte_cap, dtype=jnp.int32)
-    row = jnp.searchsorted(offsets, pos, side="right").astype(jnp.int32) - 1
-    row = jnp.clip(row, 0, cap - 1)
-    intra = pos - offsets[row]
-    in_use = pos < offsets[-1]
-    safe_intra = jnp.clip(intra, 0, width - 1)
-    data = jnp.where(in_use, padded[row, safe_intra], jnp.uint8(0))
+    # row r's bytes start at r * width of the padded table laid flat: the
+    # marks-and-prefix-sum form, no per-byte search for the row
+    data = _bytes_by_row_marks(
+        padded.reshape(-1), jnp.arange(cap, dtype=jnp.int32) * width,
+        offsets, byte_cap)
     return StringColumn(data, offsets, validity, dtype or StringType())
