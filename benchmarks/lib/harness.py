@@ -195,6 +195,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         "per_query_s": [q.end - q.start for q in win.completed],
         "errors": [q.error for q in win.queries if q.error][:3],
         "window_compiles": window_delta["counters"]["traces"],
+        "families": {"window": window_delta["families"],
+                     "setup": setup_delta["families"]},
         "setup": {"data_s": data_s, "warm_collects": warm_collects,
                   "compile_s": setup_delta["counters"]["compile_ns"] / 1e9},
         "reference_s": reference_s,

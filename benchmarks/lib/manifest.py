@@ -5,6 +5,7 @@ PR adds files and entries and edits nothing that is there."""
 import importlib.util
 import json
 import os
+import shutil
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
@@ -91,6 +92,15 @@ class Manifest:
         return mod.read
 
 
+def manifest_at(root: str) -> Manifest:
+    """The real benchmark seen from a root of its own, so that a run's
+    `.bench_work/<cell>` is its own too: for a test that rehearses a cell
+    while another worker rehearses the same cell in the checkout."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    os.symlink(BENCH, os.path.join(root, "benchmarks"))
+    return Manifest(root=str(root), bench=os.path.join(root, "benchmarks"))
+
+
 def apply_rehearsal(cfg: dict) -> dict:
     """The configuration at its `rehearse` size: a CPU rehearsal of the
     command, never a result."""
@@ -98,3 +108,63 @@ def apply_rehearsal(cfg: dict) -> dict:
     for key, small in cfg.get("rehearse", {}).items():
         out[key] = {**cfg[key], **small}
     return out
+
+
+#: the lists of `BENCHMARK.json` whose entries later PRs may only add to
+LISTS = ("configs", "workloads", "end_to_end", "per_layer")
+#: prose: a cell's or a configuration's `why` may be rewritten
+PROSE = {"configs": ("why",), "workloads": ("why",)}
+
+
+def append_only(doc: dict, accepted: dict) -> list:
+    """What `doc` did to the accepted benchmark other than append, in words;
+    empty where nothing. The driver's rule for a PR that is not a
+    `benchmark` PR: every accepted entry is still there, in its order, at
+    the head of its list, and unchanged but for cells appended to its
+    `workloads` (a `why` is prose; a `bound` may only tighten)."""
+    wrong = []
+    for group in LISTS:
+        was = [e["name"] for e in accepted[group]]
+        now = [e["name"] for e in doc[group]]
+        today = {e["name"]: e for e in doc[group]}
+        wrong += [f"{group}: accepted entry {n!r} is gone"
+                  for n in was if n not in today]
+        # what is left of the accepted entries, in yesterday's order and in
+        # today's; they should be the head of today's list
+        left = [n for n in was if n in today]
+        order = [n for n in now if n in was]
+        moved = [(a, b) for a, b in zip(order, left) if a != b]
+        if moved:
+            wrong.append(f"{group}: {moved[0][0]!r} stands where the "
+                         f"accepted order has {moved[0][1]!r}")
+        elif now[:len(left)] != left:
+            new = [n for n in now[:len(left)] if n not in was]
+            wrong.append(f"{group}: {new} stand before the accepted entry "
+                         f"{left[-1]!r}; new entries go to the end")
+        for old in accepted[group]:
+            if old["name"] in today:
+                wrong += [f"{group}: {old['name']}: {w}" for w in
+                          _entry_changes(old, today[old["name"]],
+                                         PROSE.get(group, ()))]
+    return wrong
+
+
+def _entry_changes(old: dict, new: dict, prose: tuple) -> list:
+    wrong = []
+    for key in sorted(set(old) | set(new)):
+        if key in prose:
+            continue
+        if key not in new or key not in old:
+            wrong.append(f"key {key!r} " +
+                         ("removed" if key in old else "added"))
+        elif key == "workloads":
+            a, b = old[key], new[key]
+            if b[:len(a)] != a:
+                wrong.append(f"`workloads` {b} does not start with the "
+                             f"accepted {a}; a cell is appended")
+        elif key == "bound":
+            if new[key] > old[key]:
+                wrong.append(f"`bound` raised from {old[key]} to {new[key]}")
+        elif new[key] != old[key]:
+            wrong.append(f"{key!r} changed from {old[key]!r} to {new[key]!r}")
+    return wrong

@@ -1,5 +1,6 @@
 """What the benchmark reads from the program: the dispatch ledger, the phase
-ledger and the lifecycle counters, as snapshots and their differences."""
+ledger and the counter families of single modules, as snapshots and their
+differences."""
 
 #: ledger labels of the scan -> upload -> coalesce path; every other label
 #: is a stage program (step, sizing, probe, filter, sort)
@@ -23,10 +24,25 @@ def ledger_by_label() -> dict:
     return out
 
 
+def families() -> dict:
+    """{family: the process's counters of one module}. A program older than
+    a module's `counters()` reads as an empty family."""
+    from spark_rapids_tpu.columnar import upload
+    from spark_rapids_tpu.exec import aggregate
+    return {"aggregate": getattr(aggregate, "counters", dict)(),
+            "upload": getattr(upload, "counters", dict)()}
+
+
+def family(obs, name: str) -> dict:
+    """The window's delta of one counter family, for a reader; empty where
+    the observation or the program carries none."""
+    return obs.window.get("families", {}).get(name, {})
+
+
 def snapshot() -> dict:
     from spark_rapids_tpu.obs import dispatch, phase
     return {"counters": dispatch.counters(), "labels": ledger_by_label(),
-            "phases": phase.counters()}
+            "phases": phase.counters(), "families": families()}
 
 
 def delta(a: dict, b: dict) -> dict:
@@ -43,6 +59,9 @@ def delta(a: dict, b: dict) -> dict:
         "labels": labels,
         "phases": {k: v - a["phases"].get(k, 0)
                    for k, v in b["phases"].items()},
+        "families": {name: {k: v - a["families"].get(name, {}).get(k, 0)
+                            for k, v in fam.items()}
+                     for name, fam in b["families"].items()},
     }
 
 
@@ -54,8 +73,8 @@ class Observation:
                  peaks, memory_peak_bytes):
         self.queries = queries        # queries completed in the traced window
         self.window_s = window_s      # its length on the host's clock
-        self.window = window          # ledger/phase delta over it
-        self.setup = setup            # ledger/phase delta over set-up
+        self.window = window          # ledger/phase/families delta over it
+        self.setup = setup            # the same over set-up
         self.trace = trace            # lib.trace.Reduced, or None
         self.work = work              # the configuration's work model
         self.peaks = peaks            # this device kind's peaks

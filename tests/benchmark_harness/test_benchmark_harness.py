@@ -32,45 +32,56 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 # -- the manifest --------------------------------------------------------------
 
-def test_manifest_names_units_and_files_are_within_the_contract():
-    assert set(DOC) == {"command", "paths", "run_seconds", "configs",
+def manifest_within_the_contract(doc, root=ROOT):
+    """The contract's limits on names, units and files, on `BENCHMARK.json`
+    as `doc` has it and the files under `root`: the real manifest, and a
+    copy with the next PR's entries appended (`test_manifest_append_only.py`)."""
+    assert set(doc) == {"command", "paths", "run_seconds", "configs",
                         "workloads", "end_to_end", "per_layer"}
+    cells = [w["name"] for w in doc["workloads"]]
     names = []
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
-        for e in DOC[group]:
+        for e in doc[group]:
             assert NAME.match(e["name"]), e["name"]
             names.append((group in ("end_to_end", "per_layer"), e["name"]))
     assert len(names) == len(set(names)), "a name is used twice"
-    for m in DOC["end_to_end"] + DOC["per_layer"]:
+    for m in doc["end_to_end"] + doc["per_layer"]:
         assert UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    for m in DOC["end_to_end"]:
+    for m in doc["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25
         assert m["source"] in ("host_clock", "device_trace")
-    e2e = {m["name"] for m in DOC["end_to_end"]}
+    e2e = {m["name"] for m in doc["end_to_end"]}
     assert "setup_s" in e2e
-    for m in DOC["per_layer"]:
-        assert m["moves"] in e2e and set(m["workloads"]) <= set(CELLS)
+    for m in doc["per_layer"]:
+        assert m["moves"] in e2e and set(m["workloads"]) <= set(cells)
+        assert len(m["workloads"]) == len(set(m["workloads"]))
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    for c in DOC["configs"]:
-        assert any(c["file"].startswith(p + "/") for p in DOC["paths"])
-        cfg = json.loads((ROOT / c["file"]).read_text())
+    for c in doc["configs"]:
+        assert any(c["file"].startswith(p + "/") for p in doc["paths"])
+        cfg = json.loads((Path(root) / c["file"]).read_text())
         assert cfg["source"] == c["source"] and cfg["name"] == c["name"]
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
         assert all(NAME.match(k) for k in c["reduced"])
         assert len(c["source"]) <= 200 and len(c["why"]) <= 200
-    for w in DOC["workloads"]:
+        assert any(w["config"] == c["name"] for w in doc["workloads"])
+    for w in doc["workloads"]:
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
         assert NAME.match(w["traffic"]) and NAME.match(w["config"])
-    assert 1 <= DOC["run_seconds"] <= 51
-    for p in DOC["paths"]:
-        for f in (ROOT / p).rglob("*"):
+        assert w["config"] in {c["name"] for c in doc["configs"]}
+    assert 1 <= doc["run_seconds"] <= 51
+    for p in doc["paths"]:
+        for f in (Path(root) / p).rglob("*"):
             if f.is_file() and "__pycache__" not in f.parts:
                 assert re.match(r"^[A-Za-z0-9_.\-/]+$",
-                                str(f.relative_to(ROOT))), f
+                                str(f.relative_to(root))), f
+
+
+def test_manifest_names_units_and_files_are_within_the_contract():
+    manifest_within_the_contract(DOC)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -519,7 +530,13 @@ def test_the_float32_control_in_the_programs_place_is_not_correct(
 
 # -- later PRs add files and entries, and edit nothing -------------------------
 
-def test_a_cell_config_traffic_and_metric_added_as_new_files_only(tmp_path):
+def appended_copy(tmp_path):
+    """What the next `model_config` PR does, on a copy: a configuration, a
+    traffic mix, a cell and a per-layer metric as NEW files, their entries
+    appended to `BENCHMARK.json`, and the cell appended to the list of every
+    metric all accepted cells report and of `groupby_ms`, `sort_ms` and
+    `join_busy_share` (the next cell has a join, a group-by and a sort).
+    Returns the copy's manifest and the files it started from."""
     bench = tmp_path / "benchmarks"
     shutil.copytree(ROOT / "benchmarks", bench,
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -541,6 +558,11 @@ def test_a_cell_config_traffic_and_metric_added_as_new_files_only(tmp_path):
         "    return obs.dispatches(lambda label: True) / obs.queries\n")
 
     doc = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cells = {w["name"] for w in doc["workloads"]}
+    for m in doc["per_layer"]:
+        if set(m["workloads"]) == cells or \
+                m["name"] in ("groupby_ms", "sort_ms", "join_busy_share"):
+            m["workloads"].append("q6_other")
     doc["configs"].append({"name": "tpch-q6-other", "source": cfg["source"],
                            "file": "benchmarks/configs/tpch-q6-other/config.json",
                            "reduced": list(cfg["reduced"]), "why": "test"})
@@ -550,21 +572,30 @@ def test_a_cell_config_traffic_and_metric_added_as_new_files_only(tmp_path):
                              "better": "lower", "source": "program_counter",
                              "layer": "fused stages", "moves": "query_s",
                              "workloads": ["q6_other"]})
-    for m in doc["per_layer"]:
-        if m["name"] in ("ingest_dispatches", "stage_dispatches"):
-            m["workloads"].append("q6_other")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
+    return Manifest(root=str(tmp_path), bench=str(bench)), before
 
-    res = harness.run_cell(
-        "q6_other", 11, 0.5, True, require_tpu=False, rehearse=True,
-        manifest=Manifest(root=str(tmp_path), bench=str(bench)))
+
+def test_a_cell_config_traffic_and_metric_added_as_new_files_only(tmp_path):
+    own, before = appended_copy(tmp_path)
+    res = harness.run_cell("q6_other", 11, 0.5, True, require_tpu=False,
+                           rehearse=True, manifest=own)
     assert res["correct"] is True
     assert res["attempted"] == 3                  # the new cell's own trace_queries
     got = res["metrics"]
-    assert set(got) == {"all_dispatches", "ingest_dispatches",
-                        "stage_dispatches"}
+    # every list the cell was appended to whose reader finds something on the
+    # CPU (no device plane, no allocator peak) and in Q6 (no join; a phase of
+    # a group-by or a sort only where one ran in this process before)
+    listed = {m["name"]: m for m in own.metrics_of("q6_other", "per_layer")}
+    assert {"all_dispatches", "ingest_dispatches", "direct_pack_share",
+            "groupby_ms", "sort_ms", "join_busy_share"} <= set(listed)
+    silent = {n for n, m in listed.items()
+              if m["source"] == "device_trace"} | {"hbm_peak_gib"}
+    assert set(listed) - silent - {"groupby_ms", "sort_ms"} <= set(got) \
+        <= set(listed) - silent
     assert got["all_dispatches"]["value"] == pytest.approx(
         got["ingest_dispatches"]["value"] + got["stage_dispatches"]["value"])
+    assert got["direct_pack_share"] == {"value": 100.0, "unit": "%"}
     assert {p: p.read_bytes() for p in before} == before  # nothing edited
 
 

@@ -48,14 +48,20 @@ def read():
     return Manifest().reader("decode_busy_share")
 
 
-def test_the_manifest_reports_it_in_the_q14_cell_alone():
-    m = Manifest()
-    entry = {e["name"]: e for e in m.doc["per_layer"]}["decode_busy_share"]
+def manifest_reports_it_from_the_q14_cell_on(doc):
+    """The entry as PR 32 wrote it, wherever it stands; the cell it was added
+    for comes first in its list, and later cells with a dictionary decode
+    follow (`q1_groupby_string_keys` since PR 37)."""
+    entry = dict({e["name"]: e for e in doc["per_layer"]}["decode_busy_share"])
+    cells = entry.pop("workloads")
     assert entry == {"name": "decode_busy_share", "unit": "%",
                      "better": "lower", "source": "device_trace",
-                     "layer": "kernels", "moves": "query_s",
-                     "workloads": [CELL]}
-    assert m.doc["per_layer"][-1] is entry        # appended, nothing moved
+                     "layer": "kernels", "moves": "query_s"}
+    assert cells[0] == CELL and "q6_scan_filter_sum" not in cells
+
+
+def test_the_manifest_reports_it_from_the_q14_cell_on():
+    manifest_reports_it_from_the_q14_cell_on(Manifest().doc)
 
 
 @pytest.mark.parametrize("module_s, want", [

@@ -13,7 +13,7 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from benchmarks.lib import harness, trace  # noqa: E402
-from benchmarks.lib.manifest import Manifest  # noqa: E402
+from benchmarks.lib.manifest import Manifest, manifest_at  # noqa: E402
 
 FIXTURE = Path(__file__).resolve().parent / "fixture_q1_tiny_v5e.xplane.pb.gz"
 CELL = "q6_scan_filter_sum"
@@ -132,9 +132,10 @@ def test_label_readers_are_silent_where_nothing_can_be_read(
 
 # -- through the harness -------------------------------------------------------
 
-def test_the_traced_rehearsal_reports_the_four_span_metrics():
+def test_the_traced_rehearsal_reports_the_four_span_metrics(tmp_path):
+    # a root of its own: another worker rehearses this cell in the checkout
     res = harness.run_cell(CELL, 2147483659, 0.5, True, require_tpu=False,
-                           rehearse=True)
+                           rehearse=True, manifest=manifest_at(tmp_path))
     assert res["correct"] is True
     for name in SPAN_METRICS:
         assert res["metrics"][name]["unit"] == "ms/query"

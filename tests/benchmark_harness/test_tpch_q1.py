@@ -1,7 +1,7 @@
 """CPU tests of what the configuration `tpch-q1` and its cell
 `q1_groupby_string_keys` add (ISSUE 35): the generator of the lineitem
 columns Q1 reads, the session path against the plain reference at the
-rehearsal size, the five per-layer readers, and the check that refuses a
+rehearsal size, the per-layer readers, and the check that refuses a
 program whose result sort the chip's compiler cannot finish. The
 cell-parametrised tests of `test_benchmark_harness.py` pick the cell up from
 `BENCHMARK.json` by themselves (sound run, traced run, four faults, float32
@@ -20,7 +20,8 @@ if str(ROOT) not in sys.path:
 from benchmarks.lib import datagen, dbgen, dbgen_q1, peaks, trace  # noqa: E402
 from benchmarks.lib.groupby_programs import (  # noqa: E402
     GROUPBY_LABELS, UPDATE_LABELS)
-from benchmarks.lib.manifest import Manifest, apply_rehearsal  # noqa: E402
+from benchmarks.lib.manifest import (Manifest, apply_rehearsal,  # noqa: E402
+                                     manifest_at)
 
 CELL = "q1_groupby_string_keys"
 UPDATE, PRE, EVAL = "jit__traced", "jit__pre_project", "jit__evaluate"
@@ -46,46 +47,51 @@ def config():
 
 # -- the manifest --------------------------------------------------------------
 
-# `per_layer` as the accepted benchmark had it (PR 34's commit), in its order.
-ACCEPTED = ["ingest_dispatches", "ingest_stall_ms", "concat_busy_share",
-            "stage_dispatches", "compile_s", "window_compiles",
-            "agg_stage_roofline", "query_hbm_share", "device_idle_share",
-            "hbm_peak_gib", "plan_span_ms", "scan_decode_ms", "upload_ms",
-            "device_wait_ms", "ingest_busy_share", "labelled_busy_share",
-            "join_busy_share", "join_probe_roofline", "join_build_ms",
-            "join_sizing_dispatches", "decode_busy_share"]
-ADDED = ["groupby_busy_share", "groupby_update_roofline", "groupby_ms",
-         "sort_ms", "groupby_fallbacks"]
-NOT_THE_CELLS = {"agg_stage_roofline", "join_busy_share",
+# The per-layer metrics by what this cell has to do with them. Nothing here
+# says where a list ends: a later cell is appended after this one
+# (`test_manifest_append_only.py` holds what was accepted in its place).
+REPORTS = ["ingest_dispatches", "ingest_stall_ms", "concat_busy_share",
+           "stage_dispatches", "compile_s", "window_compiles",
+           "query_hbm_share", "device_idle_share", "hbm_peak_gib",
+           "plan_span_ms", "scan_decode_ms", "upload_ms", "device_wait_ms",
+           "ingest_busy_share", "labelled_busy_share", "decode_busy_share",
+           "direct_pack_share"]
+ITS_OWN = ["groupby_busy_share", "groupby_update_roofline", "groupby_ms",
+           "sort_ms", "groupby_fallbacks", "groupby_lane_share"]
+NOT_THE_CELLS = ["agg_stage_roofline", "join_busy_share",
                  "join_probe_roofline", "join_build_ms",
-                 "join_sizing_dispatches", "decode_busy_share"}
+                 "join_sizing_dispatches"]
 
 
-def test_the_manifest_only_gained_entries_at_the_end():
-    """The driver takes new entries at the END of a list alone: nothing that
-    was there moved, `decode_busy_share` among it (its own test pinned it
-    last; `tests/conftest.py` says why that one line cannot hold)."""
-    doc = Manifest().doc
-    names = [e["name"] for e in doc["per_layer"]]
-    assert names == ACCEPTED + ADDED
-    assert [c["name"] for c in doc["configs"]][-1] == "tpch-q1"
-    assert [w["name"] for w in doc["workloads"]][-1] == CELL
-    assert doc["per_layer"][len(ACCEPTED) - 1] == {
-        "name": "decode_busy_share", "unit": "%", "better": "lower",
-        "source": "device_trace", "layer": "kernels", "moves": "query_s",
-        "workloads": ["q14_join_like_ratio"]}
+def manifest_names_the_cell_and_its_configuration(doc):
+    cell = {w["name"]: w for w in doc["workloads"]}[CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("tpch-q1", "closed1", 1)
+    config = {c["name"]: c for c in doc["configs"]}["tpch-q1"]
+    assert config["file"] == "benchmarks/configs/tpch-q1/config.json"
+    assert "clause 2.4.1 Q1" in config["source"] and not config["reduced"]
 
 
-@pytest.mark.parametrize("metric", ACCEPTED + ADDED)
-def test_the_cell_is_appended_to_the_lists_of_the_metrics_it_reports(metric):
-    entry = {e["name"]: e for e in Manifest().doc["per_layer"]}[metric]
+def manifest_lists_the_cell(doc, metric):
+    """A metric the cell reports lists it; one the cell brought lists it
+    FIRST; one it has nothing to read for does not list it."""
+    entry = {e["name"]: e for e in doc["per_layer"]}[metric]
     if metric in NOT_THE_CELLS:
         assert CELL not in entry["workloads"]
-    elif metric in ADDED:
-        assert entry["workloads"] == [CELL] and entry["moves"] == "query_s"
+    elif metric in ITS_OWN:
+        assert entry["workloads"][0] == CELL and entry["moves"] == "query_s"
     else:
-        assert entry["workloads"] == ["q6_scan_filter_sum",
-                                      "q14_join_like_ratio", CELL]
+        assert CELL in entry["workloads"]
+        assert entry["workloads"][0] != CELL        # an older cell's metric
+
+
+def test_the_manifest_names_the_cell_and_its_configuration():
+    manifest_names_the_cell_and_its_configuration(Manifest().doc)
+
+
+@pytest.mark.parametrize("metric", REPORTS + ITS_OWN + NOT_THE_CELLS)
+def test_the_cell_is_appended_to_the_lists_of_the_metrics_it_reports(metric):
+    manifest_lists_the_cell(Manifest().doc, metric)
 
 
 # -- the generator -------------------------------------------------------------
@@ -232,6 +238,25 @@ def test_the_float32_control_fails_sum_rel_err_alone(config):
     assert cfg["limits"]["rows_wrong"] == 0
 
 
+def test_the_scatter_adds_float32_pair_bias_would_fail_the_limit(config):
+    """Until PR 36 the program read 1.22e-10..1.25e-10 on every seed: ONE
+    column, `avg_disc`, whose float32-pair scatter-add rounded the same way
+    add after add. The limit sits a decade under that bias, so the bias fails
+    if it comes back, and at least four times over what the program reads on
+    the chip since (2.5e-14 at most: `PERF.md` section 2)."""
+    cfg, ref, _ = config
+    limit = cfg["limits"]["sum_rel_err"]
+    assert 4 * 2.5e-14 <= limit <= 1.22e-10 / 10
+    tables = ref.generate(3, cfg)
+    answer = ref.reference(tables, cfg)
+    biased = [tuple(v * (1 + 1.22e-10) if i == 8 else v      # avg_disc
+                    for i, v in enumerate(row)) for row in ref.as_rows(answer)]
+    got = ref.compare(biased, answer)
+    assert got["rows_wrong"] == 0
+    assert 1.2e-10 < got["sum_rel_err"] < 1.25e-10 and \
+        got["sum_rel_err"] > 10 * limit
+
+
 def test_compare_counts_rows_keys_counts_nulls_and_values_not_finite(config):
     _, ref, _ = config
     answer = [("A", "F", 1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 10),
@@ -301,7 +326,7 @@ def test_the_result_sort_check_passes_here_and_refuses_the_parents_lanes(
         query.build(None, {}, {})                        # before any scan
 
 
-# -- the five readers ----------------------------------------------------------
+# -- the readers ---------------------------------------------------------------
 
 class Obs:
     """A hand-made observation: what `lib/observe.Observation` carries."""
@@ -382,27 +407,23 @@ def test_the_span_readers_read_their_phase_and_are_silent_without_it(
     assert read(Obs(0, {phase: 13_000_000})) is None
 
 
-def test_groupby_fallbacks_reads_the_programs_counters(monkeypatch):
-    from spark_rapids_tpu.exec import aggregate
+def test_groupby_fallbacks_reads_the_windows_counters():
     read = Manifest().reader("groupby_fallbacks")
 
-    def counters(**c):
-        monkeypatch.setattr(aggregate, "counters", lambda: dict(c),
-                            raising=False)
+    def obs(**c):
+        o = Obs()
+        o.window["families"] = {"aggregate": c}
+        return o
 
-    counters(executions=6, hash_updates=6, hash_round_retries=0,
-             exact_fallbacks=0)
-    assert read(Obs()) == 0.0                         # a count: 0 is a reading
-    counters(executions=6, hash_updates=6, hash_round_retries=6,
-             exact_fallbacks=3)
-    assert read(Obs()) == 1.5
-    counters(executions=0, hash_updates=0, hash_round_retries=0,
-             exact_fallbacks=0)
-    assert read(Obs()) is None                        # no group-by drove
-    counters(hash_updates=1)                          # other counters
-    assert read(Obs()) is None
-    monkeypatch.delattr(aggregate, "counters")        # the parent's program
-    assert read(Obs()) is None
+    assert read(obs(executions=6, hash_updates=6, hash_round_retries=0,
+                    exact_fallbacks=0)) == 0.0        # a count: 0 is a reading
+    assert read(obs(executions=6, hash_updates=6, hash_round_retries=6,
+                    exact_fallbacks=3)) == 1.5
+    assert read(obs(executions=0, hash_updates=0, hash_round_retries=0,
+                    exact_fallbacks=0)) is None       # no group-by drove
+    assert read(obs(hash_updates=1)) is None          # other counters
+    assert read(obs()) is None                        # the parent's program
+    assert read(Obs()) is None                        # no families carried
 
 
 def test_the_traced_rehearsal_reports_the_groupbys_spans_and_counters(
@@ -412,10 +433,7 @@ def test_the_traced_rehearsal_reports_the_groupbys_spans_and_counters(
     from spark_rapids_tpu.obs import dispatch
     # a root of its own: the cell-parametrised tests rehearse this cell in
     # the checkout's `.bench_work/<cell>` from another worker
-    (tmp_path / "BENCHMARK.json").write_bytes(
-        (ROOT / "BENCHMARK.json").read_bytes())
-    (tmp_path / "benchmarks").symlink_to(ROOT / "benchmarks")
-    own = Manifest(root=str(tmp_path), bench=str(tmp_path / "benchmarks"))
+    own = manifest_at(tmp_path)
     before = aggregate.counters()
     res = harness.run_cell(CELL, 2147483659, 0.5, True, require_tpu=False,
                            rehearse=True, manifest=own)
@@ -425,6 +443,12 @@ def test_the_traced_rehearsal_reports_the_groupbys_spans_and_counters(
     assert got["groupby_ms"]["value"] > 0 and got["sort_ms"]["value"] > 0
     # four groups resolve in the hash update's first two rounds
     assert got["groupby_fallbacks"] == {"value": 0.0, "unit": "count/query"}
+    # every update rode the lane tier; five of the seven columns of a batch
+    # (four DOUBLE, the DATE) are packed straight from their Arrow buffers
+    assert got["groupby_lane_share"] == {"value": 100.0, "unit": "%"}
+    assert got["direct_pack_share"]["value"] == pytest.approx(100 * 5 / 7)
+    window = res["families"]["window"]["aggregate"]
+    assert window["executions"] == window["lane_updates"] == res["attempted"]
     after = aggregate.counters()
     queries = after["executions"] - before["executions"]
     assert queries >= res["attempted"] + 1            # the warm-up's too

@@ -17,7 +17,8 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from benchmarks.lib import datagen, dbgen, dbgen_part, peaks, trace  # noqa: E402
-from benchmarks.lib.manifest import Manifest, apply_rehearsal  # noqa: E402
+from benchmarks.lib.manifest import (Manifest, apply_rehearsal,  # noqa: E402
+                                     manifest_at)
 
 CELL = "q14_join_like_ratio"
 PROBE, DECODE, CONCAT = "jit__ja_spec_body", "jit__decode", "jit__concat_pair"
@@ -265,10 +266,11 @@ def test_join_sizing_dispatches_counts_the_cold_paths_program():
     assert read(Obs(0, labels=labels)) is None
 
 
-def test_the_traced_rehearsal_reports_the_joins_span_and_counter():
+def test_the_traced_rehearsal_reports_the_joins_span_and_counter(tmp_path):
     from benchmarks.lib import harness
+    # a root of its own: another worker rehearses this cell in the checkout
     res = harness.run_cell(CELL, 2147483659, 0.5, True, require_tpu=False,
-                           rehearse=True)
+                           rehearse=True, manifest=manifest_at(tmp_path))
     assert res["correct"] is True and res["failed"] == 0
     got = res["metrics"]
     assert got["join_build_ms"]["unit"] == "ms/query"
