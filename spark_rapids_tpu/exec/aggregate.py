@@ -52,8 +52,8 @@ from .coalesce import concat_batches
 
 _COUNTER_LOCK = threading.Lock()
 _COUNTERS = {
-    # drives of `_execute_exact`: one a query for a group-by the stage
-    # compiler did not fuse
+    # drives of a group-by's exact tier or of a fused stage's group-by: one
+    # a query (two where the plan ran a second time)
     "executions": 0,
     # batches (source batches, and merges of partials) that took the hash
     # path: `update_hash` / `merge_hash` at its first tier (the lane tier
@@ -72,6 +72,18 @@ _COUNTERS = {
     # the ones whose schema allows the lane tier and whose measured keys
     # were too wide for it (the hash rounds ran as without it)
     "lane_declines": 0,
+    # source batches that took the exact tier (masked buckets with the sort
+    # path behind them, one program) in the FIRST pass of their plan: the
+    # plan shape is known to overflow the buckets
+    # (`speculation.known_to_trip`), so it did not speculate
+    "many_group_updates": 0,
+    # queries whose speculation flag tripped (`TpuExec.collect`: masked
+    # buckets overflowed, or a join's cached sizes were stale)
+    "spec_trips": 0,
+    # of those, the ones that ran their whole plan a second time, every
+    # operator on its exact tier: all of them; a plan shape known to trip
+    # does not speculate again, so neither moves in its later queries
+    "plan_reruns": 0,
     # the ones the lane tier ran on and left keys over (more distinct keys
     # than its slots): they went on to the hash rounds
     "lane_leftovers": 0,
@@ -101,6 +113,15 @@ def _note(**deltas) -> None:
     with _COUNTER_LOCK:
         for k, v in deltas.items():
             _COUNTERS[k] += v
+
+
+def note_many_groups(fingerprint: Optional[str]) -> None:
+    """Count one source batch's exact update of a plan shape that is known
+    to overflow the masked buckets (the fused stages count theirs here
+    too)."""
+    from .speculation import known_to_trip
+    if known_to_trip(fingerprint):
+        _note(many_group_updates=1)
 
 
 def counters() -> Dict[str, int]:
@@ -659,7 +680,7 @@ class AggregateExec(TpuExec):
     def internal_execute(self) -> Iterator[ColumnarBatch]:
         from .speculation import speculation_allowed
         if (self._masked_ok and self._spec_enabled
-                and speculation_allowed()):
+                and speculation_allowed(self.plan_fingerprint())):
             yield from self._execute_speculative()
             return
         yield from self._execute_exact()
@@ -709,13 +730,34 @@ class AggregateExec(TpuExec):
                 empty_batch(src_schema), state, flag)
         scope = current_scope()
         if scope is not None:
-            scope.record(flag)
+            scope.record(flag, owner=self.plan_fingerprint())
         if self.mode == "partial":
             yield state
         else:
             # the last step already evaluated its state in-program
             yield evaluated if evaluated is not None \
                 else self._jit_evaluate(state)
+
+    def _tight_input(self, batch: ColumnarBatch,
+                     fingerprint: Optional[str]) -> ColumnarBatch:
+        """A sparse source batch of a plan shape known to overflow the
+        masked buckets, moved into a tight bucket before its exact update.
+        The update will take the sort path, whose cost follows the batch's
+        capacity, not its rows, and a join hands on its candidate bucket
+        (Q3: 30,500 rows in 1,048,576 slots, 0.143 s a step; PERF.md
+        section 6, PR 38). ONE host read (`_partial_size`: the rows, and
+        the bytes of the string columns it carries), only of a bucket from
+        `SHRINK_THRESHOLD_CAP` up, and a move only where the rows fill
+        less than a quarter of it."""
+        from .speculation import known_to_trip
+        if batch.capacity < self.SHRINK_THRESHOLD_CAP \
+                or not known_to_trip(fingerprint):
+            return batch
+        from ..columnar.column import bucket_capacity
+        sizes = jax.device_get(_partial_size(batch))
+        if 4 * bucket_capacity(max(int(sizes[0]), 1)) > batch.capacity:
+            return batch
+        return _tight_partial(batch, sizes)
 
     def _absorb_partial(self, aggregated: List[SpillableBatch],
                         out: ColumnarBatch) -> None:
@@ -881,7 +923,9 @@ class AggregateExec(TpuExec):
         if self._masked_ok:
             # one program: fused steps + masked buckets + lax.cond exact
             # sort fallback; the host never reads any flag (no round trip)
-            return self._jit_step_exact(batch)
+            note_many_groups(self.plan_fingerprint())
+            return self._jit_step_exact(
+                self._tight_input(batch, self.plan_fingerprint()))
         pre = self._jit_pre(batch)
         if self._hash_path_ok:
             out = self._hash_tiers(self._jit_update_hash, pre)

@@ -555,7 +555,10 @@ class TpuExec:
         """Materialize results. Opens a speculation scope: aggregates may
         run their fast masked-bucket tier and flag overflow on device; the
         flag costs one extra host read here, and a trip re-runs the plan
-        with every operator on its exact tier."""
+        with every operator on its exact tier (span `plan.rerun`, counted
+        in `exec/aggregate.counters()`). An aggregate whose flag tripped is
+        remembered by plan fingerprint and does not speculate again
+        (`speculation.known_to_trip`): the re-run is paid once a shape."""
         from ..obs import op_span
         from .speculation import force_exact, speculation_scope
 
@@ -577,7 +580,10 @@ class TpuExec:
             with op_span("result.fetch", phase="device-wait"):
                 tripped = scope.tripped()
             if tripped:
-                with force_exact():
+                from . import aggregate
+                aggregate._note(spec_trips=1, plan_reruns=1)
+                with op_span("plan.rerun", phase="plan-rerun"), \
+                        force_exact():
                     out = run()
         return out
 

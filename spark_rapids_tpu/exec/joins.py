@@ -347,7 +347,11 @@ class HashJoinExec(TpuExec):
     def _build(self) -> Tuple[BuildTable, ColumnarBatch]:
         build_child = self.children[1] if self.build_side == "right" \
             else self.children[0]
-        with self.metrics[BUILD_TIME].ns_timer():
+        from ..obs import op_span
+        # the span the fused join stage opens around its build child's
+        # drain: `join_build_ms` reads a per-operator join's too
+        with self.metrics[BUILD_TIME].ns_timer(), \
+                op_span("join.build", phase="join-build"):
             batches = list(build_child.execute())
             if len(batches) > 1:
                 # distinct per-batch dictionaries cannot concatenate
@@ -928,6 +932,14 @@ class AdaptiveJoinExec(TpuExec):
     def output_schema(self) -> Schema:
         return self._template.output_schema
 
+    def _fingerprint_extras(self):
+        # what a trace of the operators ABOVE can depend on: the join's
+        # semantics (the plain-shape join's extras), not the strategy this
+        # execution picks; the join it builds at run time keys its own
+        # programs by its own fingerprint (`_SpillableScanExec`)
+        extras = self._template._fingerprint_extras()
+        return None if extras is None else ("adaptive",) + extras
+
     def _materialize(self, side: TpuExec):
         """Drain a side into SPILLABLE batches + its padded byte size
         (reference GpuShuffledSymmetricHashJoinExec holds both sides
@@ -960,7 +972,7 @@ class AdaptiveJoinExec(TpuExec):
                 self._conf, op=type(self).__name__, op_id=self._op_id):
             cap_basis = adaptive.demote_cap(self._conf)
         r_sps, size_r = self._materialize(right)
-        r_scan = _SpillableScanExec(r_sps, right.output_schema)
+        r_scan = _SpillableScanExec(r_sps, right.output_schema, right)
         swappable = self.join_type == "inner" and not self.condition
         demoted = False
         if thr_b >= 0 and size_r <= thr_b:
@@ -981,9 +993,22 @@ class AdaptiveJoinExec(TpuExec):
                     condition=self.condition)
                 yield from join.execute()
                 return
-        # symmetric: hold BOTH sides spillable, measure, decide
-        l_sps, size_l = self._materialize(left)
-        l_scan = _SpillableScanExec(l_sps, left.output_schema)
+        # symmetric: hold BOTH sides spillable, measure, decide. The left
+        # side is held as it is UNDER the filters at its top (a filter
+        # keeps its input's capacity, so the padded bytes measured are the
+        # same), and the filters go back over the replay: the join built
+        # below absorbs them as a key mask where its shape allows, as the
+        # small-build path above does, in place of a compaction of every
+        # row before the join (PERF.md section 6, PR 38)
+        from .basic import FilterExec
+        l_base, conditions = left, []
+        while isinstance(l_base, FilterExec):
+            conditions.append(l_base.condition)
+            l_base = l_base.child
+        l_sps, size_l = self._materialize(l_base)
+        l_scan = _SpillableScanExec(l_sps, l_base.output_schema, l_base)
+        for condition in reversed(conditions):
+            l_scan = FilterExec(condition, l_scan)
         self._measured = (size_l, size_r)
         # the side that would actually be BUILT must fit: only inner
         # joins without a condition may swap build sides
@@ -1035,12 +1060,22 @@ class AdaptiveJoinExec(TpuExec):
 
 class _SpillableScanExec(TpuExec):
     """Leaf replaying spillable batches (unspilling on demand); each
-    batch releases its pin after the downstream consumes it."""
+    batch releases its pin after the downstream consumes it. It stands in
+    a plan for `stands_for`, the exec whose output it replays, and takes
+    its fingerprint from it: the join built over it at run time then keys
+    its programs like a planned one, and a later query compiles nothing."""
 
-    def __init__(self, sps, schema: Schema):
+    def __init__(self, sps, schema: Schema,
+                 stands_for: Optional[TpuExec] = None):
         super().__init__()
         self._sps = sps
         self._schema = schema
+        self._stands_for = stands_for
+
+    def _fingerprint_extras(self):
+        fp = self._stands_for.plan_fingerprint() \
+            if self._stands_for is not None else None
+        return None if fp is None else ("replay", fp)
 
     @property
     def output_schema(self) -> Schema:

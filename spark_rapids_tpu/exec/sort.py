@@ -19,14 +19,16 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar.batch import ColumnarBatch
-from ..columnar.column import Column, StringColumn, bucket_capacity
+from ..columnar.column import (MIN_BUCKET, Column, StringColumn,
+                               bucket_capacity)
 from ..expr.core import BoundReference, Expression, resolve
 from ..memory.retry import split_in_half_by_rows, with_retry, with_retry_no_split
 from ..memory.spillable import SpillableBatch
 from ..ops.basic import active_mask, slice_rows
 from ..obs.span import op_span
 from ..ops.sort import (
-    SortOrder, order_key_lanes, sort_batch_columns, string_key_bytes,
+    SortOrder, first_rows, lexsort_permutation, order_key_lanes,
+    packed_key_lanes, sort_batch_columns, string_key_bytes,
     string_words_for,
 )
 from ..types import Schema
@@ -107,10 +109,32 @@ class SortExec(TpuExec):
 
     def _sort_kernel(self, batch: ColumnarBatch,
                      key_bytes: int) -> ColumnarBatch:
+        """The batch sorted; under a limit, its first `limit` rows in the
+        limit's own capacity bucket (the slice is part of the program).
+        Where that bucket is smaller than the batch's only those rows are
+        materialized: found by selection where the limit fits the smallest
+        bucket (no sort at all), else read off the sort's permutation."""
+        n = batch.num_rows if self.limit is None \
+            else jnp.minimum(batch.num_rows, jnp.int32(self.limit))
+        out_cap = batch.capacity if self.limit is None \
+            else bucket_capacity(self.limit)
+        if out_cap < batch.capacity:
+            from ..ops.gather import gather_batch_columns
+            lanes = packed_key_lanes(batch.columns, self.orders,
+                                     batch.num_rows, batch.capacity,
+                                     key_bytes)
+            rows = first_rows(lanes, batch.capacity, self.limit, out_cap) \
+                if out_cap == MIN_BUCKET \
+                else lexsort_permutation(lanes, batch.capacity)[:out_cap]
+            cols = gather_batch_columns(batch.columns, rows, num_rows=n)
+            return ColumnarBatch(cols, n, batch.schema)
         cols, _ = sort_batch_columns(batch.columns, self.orders,
                                      batch.num_rows, batch.capacity,
                                      key_bytes)
-        return ColumnarBatch(cols, batch.num_rows, batch.schema)
+        if self.limit is not None:
+            from ..ops.basic import sanitize
+            cols = [sanitize(c, n) for c in cols]
+        return ColumnarBatch(cols, n, batch.schema)
 
     def _sort_one(self, batch: ColumnarBatch) -> ColumnarBatch:
         with op_span("sort.result", phase="sort"):
@@ -120,20 +144,9 @@ class SortExec(TpuExec):
                                          [o.ordinal for o in self.orders])
             with self._gather_track.observe((batch.capacity, key_bytes)):
                 out = self._jit_sort(batch, key_bytes)
-        out = ColumnarBatch(out.columns, batch.num_rows, batch.schema,
-                            batch._host_rows)
-        if self.limit is not None:
-            # device-side min(rows, limit): the old num_rows_host check
-            # cost a host sync per batch (round 4)
-            n = jnp.minimum(batch.num_rows, jnp.int32(self.limit))
-            if batch.capacity > bucket_capacity(self.limit):
-                cols = [slice_rows(c, jnp.int32(0), n,
-                                   bucket_capacity(self.limit))
-                        for c in out.columns]
-            else:
-                from ..ops.basic import sanitize
-                cols = [sanitize(c, n) for c in out.columns]
-            out = ColumnarBatch(cols, n, batch.schema)
+        if self.limit is None:
+            out = ColumnarBatch(out.columns, batch.num_rows, batch.schema,
+                                batch._host_rows)
         return out
 
     def internal_execute(self) -> Iterator[ColumnarBatch]:

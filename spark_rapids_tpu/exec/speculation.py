@@ -12,35 +12,78 @@ This is the engine's analog of the reference's optimistic
 hash-aggregate-then-sort-fallback duality (GpuAggregateExec.scala:909),
 lifted from per-batch to per-plan granularity because TPU host round
 trips, not device memory, are the scarce resource.
+
+A re-run is paid once a plan shape: an aggregate records its flag under its
+plan fingerprint, a trip is remembered process-wide (`known_to_trip`), and
+the next execution of an aggregate of that fingerprint takes its exact tier
+at once, in the first and only pass (a group-by of thousands of groups
+overflows the masked buckets in every query, for ever).
 """
 
 from __future__ import annotations
 
 import threading
 from contextlib import contextmanager
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
+
+#: plan fingerprints of aggregates whose masked buckets overflowed, most
+#: recent last; LRU-capped so distinct plans cannot grow it unboundedly
+_TRIPPED: Dict[str, None] = {}
+_TRIPPED_MAX = 128
+_TRIPPED_LOCK = threading.Lock()
+
+
+def known_to_trip(owner: Optional[str]) -> bool:
+    """Did an aggregate of this plan fingerprint overflow its masked
+    buckets before, in this process?"""
+    if owner is None:
+        return False
+    with _TRIPPED_LOCK:
+        return owner in _TRIPPED
+
+
+def _note_tripped(owner: str) -> None:
+    with _TRIPPED_LOCK:
+        _TRIPPED.pop(owner, None)
+        _TRIPPED[owner] = None
+        while len(_TRIPPED) > _TRIPPED_MAX:
+            _TRIPPED.pop(next(iter(_TRIPPED)))
+
+
+def reset_trip_memory() -> None:
+    """Test isolation."""
+    with _TRIPPED_LOCK:
+        _TRIPPED.clear()
 
 
 class SpeculationScope:
     def __init__(self):
-        self.flags: List = []  # device bool scalars
+        self.flags: List = []  # (device bool scalar, owner) pairs
 
-    def record(self, flag) -> None:
-        self.flags.append(flag)
+    def record(self, flag, owner: Optional[str] = None) -> None:
+        """`owner`: the plan fingerprint of the aggregate whose masked
+        buckets the flag guards; None for a flag that says nothing about
+        the plan's shape (a join's stale size cache)."""
+        self.flags.append((flag, owner))
 
     def drain(self) -> List:
         out, self.flags = self.flags, []
         return out
 
     def tripped(self) -> bool:
-        """ONE host sync over all recorded flags."""
+        """ONE host sync over all recorded flags; the owners of the ones
+        that tripped are remembered (`known_to_trip`)."""
         if not self.flags:
             return False
         import jax.numpy as jnp
         flags = self.drain()
-        return bool(np.asarray(jnp.any(jnp.stack(flags))))
+        hit = np.asarray(jnp.stack([f for f, _ in flags]))
+        for (_, owner), h in zip(flags, hit):
+            if h and owner is not None:
+                _note_tripped(owner)
+        return bool(hit.any())
 
 
 class _State(threading.local):
@@ -70,8 +113,12 @@ def adopt_context(scope, forced_exact: bool) -> None:
     _state.forced_exact = forced_exact
 
 
-def speculation_allowed() -> bool:
-    return _state.scope is not None and not _state.forced_exact
+def speculation_allowed(owner: Optional[str] = None) -> bool:
+    """May an operator speculate here? Not outside a scope, not in a forced
+    re-run, and not an aggregate (`owner`: its plan fingerprint) that is
+    known to overflow: it would only buy a second pass."""
+    return _state.scope is not None and not _state.forced_exact \
+        and not known_to_trip(owner)
 
 
 @contextmanager

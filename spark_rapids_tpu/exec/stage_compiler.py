@@ -135,10 +135,14 @@ def counters() -> Dict[str, int]:
 
 
 def reset_stage_counters() -> None:
+    """Counters, and what the process remembers of plan shapes by
+    fingerprint: the joins' sizes and the aggregates that tripped."""
+    from .speculation import reset_trip_memory
     with _COUNTER_LOCK:
         for k in _COUNTERS:
             _COUNTERS[k] = 0
         _SIZE_CACHES.clear()
+    reset_trip_memory()
 
 
 #: fingerprint -> {(stream_cap, build_cap): (cand_cap, s_caps,
@@ -533,18 +537,34 @@ class CompiledStageExec(TpuExec):
                 jnp.asarray(False))
 
     def _spec_allowed(self) -> bool:
+        """Speculate with the masked buckets? Not where this stage's plan
+        shape tripped before: it takes the exact tier in its first pass."""
         from .speculation import speculation_allowed
         agg = self._agg
         return agg._masked_ok and agg._spec_enabled \
-            and speculation_allowed()
+            and speculation_allowed(self.plan_fingerprint())
+
+    def _step_spanned(self, run, sp, exact=False):
+        """One source batch's step of the stage's group-by: the span the
+        per-operator group-by opens (`agg.update`, phase `group-agg`), so
+        that `groupby_ms` reads for a fused group-by too."""
+        from ..memory.retry import split_in_half_by_rows, with_retry
+        from ..obs import op_span
+        if exact:
+            from .aggregate import note_many_groups
+            note_many_groups(self.plan_fingerprint())
+        with op_span("agg.update", phase="group-agg"):
+            return list(with_retry(sp, run,
+                                   split_policy=split_in_half_by_rows))
 
     def _execute_agg(self):
-        from ..memory.retry import split_in_half_by_rows, with_retry
         from ..memory.spillable import SpillableBatch
         from .speculation import current_scope
+        from .aggregate import _note as note_agg
         agg = self._agg
         live = self._live_stats
         spec = self._spec_allowed()
+        note_agg(executions=1)
         saw = False
         n_in = 0
         if spec:
@@ -568,9 +588,7 @@ class CompiledStageExec(TpuExec):
                                 return self._jit_step(b, box[0], box[1])
                         finally:
                             s.release()
-                    for out in with_retry(
-                            sp, run,
-                            split_policy=split_in_half_by_rows):
+                    for out in self._step_spanned(run, sp):
                         box[0], box[1], box[2] = out
                 finally:
                     sp.close()
@@ -580,7 +598,7 @@ class CompiledStageExec(TpuExec):
                 return
             scope = current_scope()
             if scope is not None:
-                scope.record(flag)
+                scope.record(flag, owner=self.plan_fingerprint())
             if agg.mode == "partial":
                 yield state
             else:
@@ -594,6 +612,7 @@ class CompiledStageExec(TpuExec):
                 saw = True
                 n_in += 1
                 live[0] = n_in
+                batch = agg._tight_input(batch, self.plan_fingerprint())
                 sp = SpillableBatch.from_batch(batch)
                 try:
                     def run(s):
@@ -606,9 +625,7 @@ class CompiledStageExec(TpuExec):
                                 return self._jit_step_exact(b)
                         finally:
                             s.release()
-                    for part, ev in with_retry(
-                            sp, run,
-                            split_policy=split_in_half_by_rows):
+                    for part, ev in self._step_spanned(run, sp, True):
                         # the agg's own shrink + MERGE_FAN_IN window:
                         # live partials stay bounded under a forced-
                         # spill budget, exactly like the per-op drive
@@ -681,9 +698,8 @@ class CompiledStageExec(TpuExec):
             table = self._join._build_kernel(build_batch)
         out, size_flag = self._probe_in_stage(
             table, build_batch, stream_batch, cand_cap, s_caps, b_caps)
-        state, flag, ev = self._agg._streaming_step(
-            out, state, flag | size_flag)
-        return table, state, flag, ev
+        state, flag, ev = self._agg._streaming_step(out, state, flag)
+        return table, state, flag, ev, size_flag
 
     def _ja_exact_body(self, table, build_batch, stream_batch,
                        cand_cap, s_caps, b_caps):
@@ -747,8 +763,8 @@ class CompiledStageExec(TpuExec):
 
     def _execute_join_agg(self):
         from ..columnar.batch import empty_batch
-        from ..memory.retry import split_in_half_by_rows, with_retry
         from ..memory.spillable import SpillableBatch
+        from .aggregate import _note as note_agg
         from .coalesce import concat_batches
         from .speculation import current_scope
         join, agg = self._join, self._agg
@@ -764,8 +780,10 @@ class CompiledStageExec(TpuExec):
             else:
                 build_batch = empty_batch(build_child.output_schema)
         spec = self._spec_allowed()
+        note_agg(executions=1)
         table = None
         state = flag = ev = None
+        size_flags: List = []
         parts: List = []
         n_parts = 0
         last_ev = None
@@ -802,10 +820,9 @@ class CompiledStageExec(TpuExec):
                                     box[2], cand_cap, s_caps, b_caps)
                         finally:
                             s.release()
-                    for out in with_retry(
-                            sp, run,
-                            split_policy=split_in_half_by_rows):
-                        box[0], box[1], box[2], box[3] = out
+                    for out in self._step_spanned(run, sp):
+                        box[0], box[1], box[2], box[3], size_flag = out
+                        size_flags.append(size_flag)
                     table, state, flag, ev = box
                 else:
                     def run(s):
@@ -823,9 +840,8 @@ class CompiledStageExec(TpuExec):
                                     s_caps, b_caps)
                         finally:
                             s.release()
-                    for tbl, part, pev, size_flag in with_retry(
-                            sp, run,
-                            split_policy=split_in_half_by_rows):
+                    for tbl, part, pev, size_flag in self._step_spanned(
+                            run, sp, True):
                         table = tbl
                         # bounded accumulation: the agg's shrink +
                         # MERGE_FAN_IN window (forced-spill parity)
@@ -843,7 +859,12 @@ class CompiledStageExec(TpuExec):
             return
         if spec:
             if scope is not None:
-                scope.record(flag)
+                # the buckets' overflow under this stage's fingerprint (a
+                # trip is remembered: `_spec_allowed`); the join's stale
+                # sizes apart from it, they say nothing about the shape
+                scope.record(flag, owner=self.plan_fingerprint())
+                for size_flag in size_flags:
+                    scope.record(size_flag)
             if agg.mode == "partial":
                 yield state
             else:

@@ -81,6 +81,9 @@ PHASES = (
                            # leftover syncs, and the merge (exec/aggregate)
     "sort",                # a result sort with its key-width sync
                            # (exec/sort.py)
+    "plan-rerun",          # a whole plan run a second time after its
+                           # speculation flag tripped: what of the second
+                           # pass no inner phase claims (exec/base.py)
     "other",               # derived remainder — never negative
 )
 

@@ -371,23 +371,21 @@ def groupby_aggregate(key_columns: Sequence[Column],
     else:
         scanned = ()
 
-    # boundary compaction: one stable sort carrying the first-row
-    # positions and the packed key lanes (prefix lanes no longer ride it)
+    # boundary compaction: a group's first row goes to the slot of its
+    # (dense, sorted) segment id with one scatter of the positions, and the
+    # packed key lanes are read there with one row gather. (A stable sort
+    # carried them until PR 38: each of its operands cost the chip's
+    # compiler tens of seconds.)
     from .rowpack import pack_rows, split_packable, unpack_rows
+    first_pos = jnp.full((capacity,), capacity, jnp.int32).at[
+        jnp.where(first_flag, seg, capacity)].set(positions, mode="drop")
+    first_pos = jnp.where(group_act, first_pos, capacity)
     kp_idx, ko_idx = split_packable(sorted_keys)
     if kp_idx:
         kplan, kimat, kfmat = pack_rows([sorted_keys[i] for i in kp_idx])
-        key_lanes = [kimat[:, j] for j in range(kimat.shape[1])]
-        key_flanes = [kfmat[:, j] for j in range(kfmat.shape[1])] \
-            if kfmat is not None else []
-    else:
-        key_lanes, key_flanes = [], []
-    operands = ((~first_flag).astype(jnp.uint32), positions,
-                *key_lanes, *key_flanes)
-    comp = jax.lax.sort(operands, num_keys=1, is_stable=True)
-    first_pos = jnp.where(group_act, comp[1], capacity)
-    comp_keys_i = comp[2: 2 + len(key_lanes)]
-    comp_keys_f = comp[2 + len(key_lanes):]
+        first_safe = jnp.clip(first_pos, 0, capacity - 1)
+        s_imat = kimat[first_safe]
+        s_fmat = kfmat[first_safe] if kfmat is not None else None
 
     last_group = positions == (num_groups - 1)
 
@@ -495,8 +493,6 @@ def groupby_aggregate(key_columns: Sequence[Column],
     # the compaction's carried key lanes (packable) or gathered (varlen)
     out_keys: List[Optional[Column]] = [None] * len(key_columns)
     if kp_idx:
-        s_imat = jnp.stack(comp_keys_i, axis=1)
-        s_fmat = jnp.stack(comp_keys_f, axis=1) if key_flanes else None
         for j, c in zip(kp_idx, unpack_rows(kplan, s_imat, s_fmat)):
             from ..columnar.column import Column as _C
             out_keys[j] = _C(jnp.where(group_act, c.data,

@@ -319,15 +319,62 @@ def packed_key_lanes(columns: Sequence[Column], orders: Sequence[SortOrder],
     return _pack_fields(fields)
 
 
+def lexsort_permutation(lanes: Sequence[jnp.ndarray],
+                        capacity: int) -> jnp.ndarray:
+    """The stable permutation that orders rows by u32 key `lanes`, most
+    significant first. Several lanes are sorted one at a time, least
+    significant first, each pass a stable sort of (that lane read through
+    the permutation so far, the permutation): ONE two-operand sort in a
+    loop, whatever the number of lanes. The chip's compiler takes about
+    half a minute for such a sort at a 32,768-row bucket and 456 s for
+    five key lanes with their payload there (PERF.md section 6, PR 38):
+    the cost is in the operands, not in the rows."""
+    iota = jnp.arange(capacity, dtype=jnp.int32)
+    if len(lanes) == 1:
+        return jax.lax.sort((lanes[0], iota), num_keys=1, is_stable=True)[1]
+    stacked = jnp.stack(lanes)
+
+    def one_pass(j, perm):
+        lane = stacked[len(lanes) - 1 - j]
+        return jax.lax.sort((lane[perm], perm), num_keys=1,
+                            is_stable=True)[1]
+
+    return jax.lax.fori_loop(0, len(lanes), one_pass, iota)
+
+
+def first_rows(lanes: Sequence[jnp.ndarray], capacity: int, limit: int,
+               out_capacity: int) -> jnp.ndarray:
+    """The rows a stable sort by `lanes` would put first, `limit` of them,
+    as int32 (out_capacity,) row indices in that order (`capacity`, out of
+    range, beyond them): found by selection, `limit` times the smallest key
+    not yet taken (a masked minimum a lane, the lowest index among equals).
+    No sort: a top-N under a small limit compiles in seconds and reads the
+    batch `limit` x lanes times."""
+    iota = jnp.arange(capacity, dtype=jnp.int32)
+    top = jnp.uint32(0xFFFFFFFF)
+
+    def take_next(i, carry):
+        taken, out = carry
+        cand = ~taken
+        for lane in lanes:
+            cand = cand & (lane == jnp.min(jnp.where(cand, lane, top)))
+        row = jnp.min(jnp.where(cand, iota, capacity))
+        return taken | (iota == row), out.at[i].set(row)
+
+    _, out = jax.lax.fori_loop(
+        0, limit, take_next,
+        (jnp.zeros((capacity,), jnp.bool_),
+         jnp.full((out_capacity,), capacity, jnp.int32)))
+    return out
+
+
 def sort_permutation(columns: Sequence[Column], orders: Sequence[SortOrder],
                      num_rows, capacity: int,
                      key_bytes: int = 8 * DEFAULT_STRING_WORDS):
     """Stable sort permutation: int32 (capacity,) such that gathering by it
     yields rows in the requested order, inactive rows last."""
     lanes = packed_key_lanes(columns, orders, num_rows, capacity, key_bytes)
-    iota = jnp.arange(capacity, dtype=jnp.int32)
-    out = jax.lax.sort(tuple(lanes) + (iota,), num_keys=len(lanes))
-    return out[-1]
+    return lexsort_permutation(lanes, capacity)
 
 
 def sort_batch_columns(columns: Sequence[Column], orders: Sequence[SortOrder],
@@ -336,14 +383,20 @@ def sort_batch_columns(columns: Sequence[Column], orders: Sequence[SortOrder],
                        ) -> Tuple[List[Column], jnp.ndarray]:
     """Sort all columns of a batch; returns (sorted columns, permutation).
 
-    Round 4: fixed-width payload columns ride INSIDE lax.sort as packed
-    u32/f64 lanes (ops/rowpack) instead of being gathered by the
-    permutation afterwards — on v5e a multi-operand sort costs a few ms
-    while each per-column random gather costs ~26 ms. The iota lane stays
+    Keys that pack into ONE lane: fixed-width payload columns ride INSIDE
+    lax.sort as packed u32/f64 lanes (ops/rowpack) instead of being
+    gathered by the permutation afterwards (round 4). The iota lane stays
     a KEY so the sort is stable and varlen columns still gather by it.
+    Keys of several lanes: `lexsort_permutation`, then one packed row
+    gather of the fixed-width columns (`ops/gather.py`): every operand of
+    a sort costs the chip's compiler tens of seconds.
     """
     from .rowpack import pack_rows, split_packable, unpack_rows
     lanes = packed_key_lanes(columns, orders, num_rows, capacity, key_bytes)
+    if len(lanes) > 1:
+        from .gather import gather_batch_columns
+        perm = lexsort_permutation(lanes, capacity)
+        return gather_batch_columns(columns, perm), perm
     iota = jnp.arange(capacity, dtype=jnp.int32)
     p_idx, o_idx = split_packable(columns)
     out: List = [None] * len(columns)

@@ -198,23 +198,3 @@ def _no_leaked_lifecycle_state():
         f"module leaked registered query contexts: {leaked_queries}")
     assert not leaked_breakers, (
         f"module left circuit breakers open: {leaked_breakers}")
-
-
-# tests/benchmark_harness/test_decode_busy_share.py:58 pins PR 32's entry to
-# the END of `per_layer`. The driver takes a program PR's new entries only at
-# the end of that list (PR 35 was refused for putting its five before it), and
-# no program PR may edit that file, so the pin and the rule cannot both hold.
-# The first `benchmark` PR drops line 58 and this hook together (strict: a test
-# that passes again fails until the hook goes). What the line was there to
-# guard, that nothing already in the list moved, is held by
-# tests/benchmark_harness/test_tpch_q1.py::test_the_manifest_only_gained_entries_at_the_end.
-_PINNED_LAST = ("tests/benchmark_harness/test_decode_busy_share.py"
-                "::test_the_manifest_reports_it_in_the_q14_cell_alone")
-
-
-def pytest_collection_modifyitems(items):
-    for item in items:
-        if item.nodeid == _PINNED_LAST:
-            item.add_marker(pytest.mark.xfail(
-                strict=True, reason="pins decode_busy_share last in "
-                "per_layer; new entries may only be appended (PERF.md 7)"))

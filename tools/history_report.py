@@ -43,7 +43,8 @@ PHASES = (
     "admission-wait", "compile", "device-compute", "host-pack-serialize",
     "shuffle-io", "ici-collective", "spill-wait", "semaphore-wait",
     "pipeline-stall", "retry-backoff", "spec-wait", "plan", "scan-decode",
-    "upload", "device-wait", "join-build", "group-agg", "sort", "other",
+    "upload", "device-wait", "join-build", "group-agg", "sort", "plan-rerun",
+    "other",
 )
 
 
