@@ -165,7 +165,9 @@ def concat_columns(a: Column, b: Column, a_rows, b_rows, out_capacity: int
 
     Fixed-width lanes (data, validity, dictionary codes, struct / decimal128
     children) move as two contiguous blocks at a traced offset — no per-row
-    gather; strings and arrays keep their own bodies. The contract is
+    gather; so do a string column's bytes, offsets and validity
+    (`strings.concat_string`). Arrays keep a body of their own
+    (`collection.concat_arrays` gathers the kept elements). The contract is
     a_rows <= a.capacity, b_rows <= b.capacity and
     a_rows + b_rows <= out_capacity; out_capacity may be smaller than either
     input's capacity (concat_batches' exact lane buckets the known row

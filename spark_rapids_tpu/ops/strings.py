@@ -12,11 +12,11 @@ decode of a string column goes through, needs no search (ISSUE 32): the
 source of output byte p is p + (src_start - out_start) of p's row, a step
 function of p that changes only at row starts, so it marks each row start
 with the step's change (N-wide) and takes ONE prefix sum over the byte
-bucket. `concat_string`, the coalesce's primitive for a scanned string
-column, takes the same form (ISSUE 35): its rows' lengths, validity and
-starts move as two contiguous blocks, and one gather reads the two inputs'
-bytes laid end to end. The other kernels keep the search until a benchmark
-cell runs them.
+bucket. `concat_string`, the primitive of the coalesce and of every other
+concat of a string column, needs neither marks nor a gather (ISSUE 39): the
+active rows of each input own one contiguous byte range, so the output is
+two block moves at traced offsets, for the bytes as for the offsets and
+validity. The other kernels keep the search until a benchmark cell runs them.
 """
 
 from __future__ import annotations
@@ -101,34 +101,48 @@ def gather_string(col: StringColumn, indices, out_valid,
     return StringColumn(data, new_offsets, out_valid, col.dtype)
 
 
+def _placed(x, start, size: int):
+    """(size,) lane holding x from traced `start` on: out[p] = x[p - start]
+    where that index is in x, else 0. A start below -len(x) or above size
+    leaves x wholly outside and is clamped to such a start, so the lane is
+    right for any start."""
+    n = x.shape[0]
+    buf = jnp.zeros((n + size + n,), x.dtype)
+    buf = jax.lax.dynamic_update_slice(buf, x, (n + start,))
+    return buf[n:n + size]
+
+
 def concat_string(a: StringColumn, b: StringColumn, a_rows, b_rows,
                   out_capacity: int,
                   out_byte_capacity: int | None = None) -> StringColumn:
     """Concatenate active rows of two string columns.
 
-    The per-row lanes (lengths, validity, source starts) are a's first
-    a_rows then b's, two contiguous blocks at a traced offset as for a
-    fixed-width lane (`basic._concat_fixed`): no per-row index gather. The
-    bytes come from ONE table, a's buffer then b's: the source of output
-    byte p is p + (start in the table - out_start) of p's row, found from
-    row-start marks and one prefix sum (`_bytes_by_row_marks`). The per-byte
-    `searchsorted` this replaces was ~23 B-wide gathers at the top of a
-    32-batch coalesce: 25.1 s of a TPC-H Q1 query's 47.9 s on v5e
-    (PERF.md, PR 35)."""
+    The active rows of a own the contiguous bytes
+    a.data[a.offsets[0]:a.offsets[a_rows]], and likewise b's, so the output
+    bytes are a's block at 0 followed by b's at a_bytes: two block moves at
+    traced offsets (`_placed`), zeros past the total. The offsets are a's
+    and b's shifted to those places, two blocks as for a fixed-width lane
+    (`basic._concat_fixed`), the total at and past row a_rows + b_rows. No
+    scatter, gather or scan: the row-start marks and per-byte gather this
+    replaces cost 1.462 s of a TPC-H Q1 query's 3.8 s on v5e (PERF.md, PR
+    36). An offsets[0] other than 0 is shifted out; padding rows' offsets
+    and bytes are never read."""
     from .basic import _concat_fixed
     byte_cap = out_byte_capacity or (a.byte_capacity + b.byte_capacity)
+    a0, b0 = a.offsets[0], b.offsets[0]
+    a_bytes = a.offsets[a_rows] - a0
+    total = a_bytes + b.offsets[b_rows] - b0
+    pos = jnp.arange(byte_cap, dtype=jnp.int32)
+    data = jnp.where(pos < a_bytes, _placed(a.data, -a0, byte_cap),
+                     _placed(b.data, a_bytes - b0, byte_cap))
+    data = jnp.where(pos < total, data, jnp.uint8(0))
+
     live = jnp.arange(out_capacity, dtype=jnp.int32) < a_rows + b_rows
-    lengths = jnp.where(live, _concat_fixed(
-        string_lengths(a), string_lengths(b), a_rows, out_capacity), 0)
+    starts = _concat_fixed(a.offsets[:-1] - a0, b.offsets[:-1] - b0 + a_bytes,
+                           a_rows, out_capacity)
+    new_offsets = jnp.append(jnp.where(live, starts, total), total)
     validity = _concat_fixed(a.validity, b.validity, a_rows,
                              out_capacity) & live
-    new_offsets = _rebuild_offsets(lengths)
-    src_starts = _concat_fixed(a.offsets[:-1],
-                               b.offsets[:-1] + a.byte_capacity,
-                               a_rows, out_capacity)
-
-    data = _bytes_by_row_marks(jnp.concatenate([a.data, b.data]), src_starts,
-                               new_offsets, byte_cap)
     return StringColumn(data, new_offsets, validity, a.dtype)
 
 
