@@ -117,6 +117,9 @@ QUEUE_BLOCKING_ATTRS = frozenset({"get", "put"})
 QUEUE_RECEIVER_RE = re.compile(r"(^|\.)_?(write_)?q(ueue)?$")
 #: bare-name calls that do IO
 BLOCKING_NAMES = frozenset({"open"})
+#: bare-name calls that wait for the device (obs.dispatch.host_read: the
+#: one door of a device-to-host read, ISSUE 40)
+DEVICE_SYNC_NAMES = frozenset({"host_read"})
 #: `.emit(...)` on an event-bus-ish receiver — the PR 6 r4 class: the
 #: bus takes its own lock and writes a file, never do that under an
 #: engine lock
@@ -260,6 +263,15 @@ def _build_rules() -> Dict[str, RuleMeta]:
             "ISSUE 13 (dispatch & compile observability plane)",
             "self._jit = jax.jit(self._kernel) in an exec",
             rules_dispatch.check),
+        RuleMeta(
+            "host-sync-chokepoint", "dispatch-discipline",
+            "jax.device_get / .block_until_ready() that does not go "
+            "through the host-read chokepoint (obs.dispatch.host_read) "
+            "— a host sync the ledger cannot count and the device trace "
+            "cannot name",
+            "ISSUE 40 (host syncs get one chokepoint)",
+            "sizes = jax.device_get(_partial_size(batch)) in an exec",
+            rules_dispatch.check_host_sync),
         RuleMeta(
             "stage-governance", "dispatch-discipline",
             "per-batch governance hook (lifecycle tick, chaos fault "

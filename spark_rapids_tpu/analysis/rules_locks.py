@@ -43,6 +43,8 @@ def _blocking_reason(call: ast.Call, reg, held) -> Optional[str]:
     if isinstance(func, ast.Name):
         if func.id in reg_mod.BLOCKING_NAMES:
             return f"file IO `{func.id}(...)`"
+        if func.id in reg_mod.DEVICE_SYNC_NAMES:
+            return f"device transfer `{func.id}(...)`"
         if func.id in reg.extra_blocking_calls:
             return (f"`{func.id}(...)` — "
                     f"{reg.extra_blocking_calls[func.id]}")

@@ -61,11 +61,10 @@ class TpuSession:
             mesh = device_mesh(mesh_devices)
         self.mesh = mesh
         set_active_mesh(mesh)
-        #: per-query metric roll-up of the LAST collect() on this
-        #: session (exec/task_metrics.py; reference GpuTaskMetrics)
-        self._last_query_metrics = None
-        #: per-query profile of the LAST collect() (obs/profile.py)
-        self._last_query_profile = None
+        #: what the LAST collect() on this session left for its metric
+        #: roll-up and profile, built on first read
+        #: (exec/task_metrics.QueryHarvest; reference GpuTaskMetrics)
+        self._last_harvest = None
         #: lifecycle-governor ownership token: every governed collect
         #: registers its QueryContext under it, so cancel_query() (from
         #: any thread) can find and cancel THIS session's queries
@@ -134,16 +133,21 @@ class TpuSession:
         semaphore wait, OOM-retry counts, spill volumes (per-query
         deltas) plus per-operator metric sums — the engine's
         GpuTaskMetrics surface (GpuTaskMetrics.scala:81-103). Honors
-        spark.rapids.sql.metrics.level (GpuExec.scala:36-47)."""
-        return self._last_query_metrics
+        spark.rapids.sql.metrics.level (GpuExec.scala:36-47). Built on
+        the first call after the collect (its reads of device row counts
+        are host syncs the collect itself does not pay), then cached."""
+        h = self._last_harvest
+        return h.metrics() if h is not None else None
 
     def last_query_profile(self):
         """QueryProfile of the most recent DataFrame.collect(): the
         executed plan tree annotated with per-operator metrics, with
         `.text()` (explain-with-metrics, the Spark-SQL-UI analog),
         `.to_json()` and `.top_operators()` renderers (obs/profile.py).
-        None before the first collect."""
-        return self._last_query_profile
+        None before the first collect. Built on first call, like
+        `last_query_metrics()`."""
+        h = self._last_harvest
+        return h.profile() if h is not None else None
 
     # -- ingestion ---------------------------------------------------------
     def from_pydict(self, data: Dict, schema: Schema,
@@ -482,8 +486,7 @@ class DataFrame:
             if store is not None:
                 # a query failing before its harvest must not write the
                 # PREVIOUS query's plan/metrics into its capsule
-                self.session._last_query_metrics = None
-                self.session._last_query_profile = None
+                self.session._last_harvest = None
             t0 = _time.perf_counter_ns()
             ok = False
             try:
@@ -535,7 +538,7 @@ class DataFrame:
                 # drag p50 down, under-reporting real latency
                 telemetry.note_query_latency(priority, wall_ns)
             if store is not None:
-                profile = self.session._last_query_profile
+                profile = self.session.last_query_profile()
                 deltas = obs_history.counters_delta(
                     before, obs_history.process_counters())
                 mesh = self.session.mesh
@@ -547,7 +550,7 @@ class DataFrame:
                     ok=ok, priority=priority, attempts=ctx.attempt_no,
                     wall_ns=wall_ns, phases=phases,
                     stats=ctx.runtime_stats,
-                    summary=self.session._last_query_metrics,
+                    summary=self.session.last_query_metrics(),
                     deltas=deltas))
         except Exception:  # noqa: BLE001 — observability never masks
             pass
@@ -556,10 +559,9 @@ class DataFrame:
         import time as _time
 
         from ..exec import lifecycle
-        from ..exec.task_metrics import query_snapshot, query_summary
+        from ..exec.task_metrics import QueryHarvest, query_snapshot
         from ..obs import events as obs_events
         from ..obs import op_span
-        from ..obs.profile import QueryProfile
         from ..obs.stats import RuntimeStats
         with obs_events.query_scope() as qid:
             # conversion inside the scope: plan_fallback / plan_not_on_tpu
@@ -590,12 +592,11 @@ class DataFrame:
             finally:
                 # metrics are harvested even on failure: a half-run
                 # query's spill/retry spend is exactly what an operator
-                # debugging it wants to see
+                # debugging it wants to see. Only host values are taken
+                # here; the roll-up waits for its first reader
                 try:
-                    summary = query_summary(plan, before)
-                    self.session._last_query_metrics = summary
-                    self.session._last_query_profile = QueryProfile(
-                        plan, summary, statistics=stats,
+                    self.session._last_harvest = QueryHarvest(
+                        plan, before, statistics=stats,
                         phases=ctx.phase_ledger
                         if ctx is not None else None)
                 except Exception:  # noqa: BLE001 — must never mask

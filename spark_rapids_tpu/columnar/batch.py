@@ -56,7 +56,8 @@ class ColumnarBatch:
     def num_rows_host(self) -> int:
         """Logical row count as a host int; syncs if produced on device."""
         if self._host_rows is None:
-            self._host_rows = int(self.num_rows)
+            from ..obs.dispatch import host_read
+            self._host_rows = int(host_read("batch.num_rows", self.num_rows))
         return self._host_rows
 
     def column(self, name_or_idx) -> Column:

@@ -129,6 +129,14 @@ def bucket_capacity(n: int) -> int:
     return 1 << (int(n - 1).bit_length())
 
 
+def _host(*leaves) -> tuple:
+    """The host copies of a column's leaves, for `to_pylist`: device
+    leaves in ONE read through the host-read door (site
+    `column.to_pylist`), numpy leaves as they are."""
+    from ..obs.dispatch import host_read
+    return tuple(np.asarray(x) for x in host_read("column.to_pylist", leaves))
+
+
 def _pad_np(arr: np.ndarray, capacity: int, fill=0) -> np.ndarray:
     if arr.shape[0] == capacity:
         return arr
@@ -188,8 +196,7 @@ class Column:
 
     # -- host materialization (test/debug surface) -------------------------
     def to_pylist(self, num_rows: int) -> List:
-        data = np.asarray(self.data[:num_rows])
-        valid = np.asarray(self.validity[:num_rows])
+        data, valid = _host(self.data[:num_rows], self.validity[:num_rows])
         return [data[i].item() if valid[i] else None for i in range(num_rows)]
 
     def __repr__(self):
@@ -257,9 +264,7 @@ class StringColumn(Column):
         return StringColumn(data, self.offsets, self.validity, self.dtype)
 
     def to_pylist(self, num_rows: int) -> List[Optional[str]]:
-        data = np.asarray(self.data)
-        offsets = np.asarray(self.offsets)
-        valid = np.asarray(self.validity)
+        data, offsets, valid = _host(self.data, self.offsets, self.validity)
         out: List[Optional[str]] = []
         binary = isinstance(self.dtype, BinaryType)
         for i in range(num_rows):
@@ -315,7 +320,7 @@ class StructColumn(Column):
         return StructColumn(tuple(kids), _dev(validity), dtype)
 
     def to_pylist(self, num_rows: int) -> List:
-        valid = np.asarray(self.validity[:num_rows])
+        (valid,) = _host(self.validity[:num_rows])
         kids = [c.to_pylist(num_rows) for c in self.children]
         names = [f.name for f in self.dtype.fields]
         return [
@@ -378,9 +383,8 @@ class Decimal128Column(StructColumn):
 
     def to_pylist(self, num_rows: int) -> List:
         """Unscaled 128-bit ints (arbitrary-precision Python ints)."""
-        hi = np.asarray(self.hi.data[:num_rows])
-        lo = np.asarray(self.lo.data[:num_rows])
-        valid = np.asarray(self.validity[:num_rows])
+        hi, lo, valid = _host(self.hi.data[:num_rows], self.lo.data[:num_rows],
+                              self.validity[:num_rows])
         out: List = []
         for i in range(num_rows):
             if not valid[i]:
@@ -427,8 +431,7 @@ class ArrayColumn(Column):
         return ArrayColumn(child, _dev(off), _dev(validity), dtype)
 
     def to_pylist(self, num_rows: int) -> List:
-        offsets = np.asarray(self.offsets)
-        valid = np.asarray(self.validity[:num_rows])
+        offsets, valid = _host(self.offsets, self.validity[:num_rows])
         child_n = int(offsets[num_rows]) if num_rows else 0
         kid = self.child.to_pylist(child_n)
         return [
@@ -493,8 +496,7 @@ class MapColumn(Column):
                          self.dtype)
 
     def to_pylist(self, num_rows: int) -> List:
-        offsets = np.asarray(self.offsets)
-        valid = np.asarray(self.validity[:num_rows])
+        offsets, valid = _host(self.offsets, self.validity[:num_rows])
         entry_n = int(offsets[num_rows]) if num_rows else 0
         ks = self.keys.to_pylist(entry_n)
         vs = self.values.to_pylist(entry_n)

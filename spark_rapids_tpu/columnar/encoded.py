@@ -153,10 +153,10 @@ class DictionaryColumn(Column):
 
     # -- host materialization (test/debug surface) -------------------------
     def to_pylist(self, num_rows: int) -> List:
-        codes = np.asarray(self.codes[:num_rows])
-        valid = np.asarray(self.validity[:num_rows])
-        data = np.asarray(self.dict_data)
-        off = np.asarray(self.dict_offsets)
+        from .column import _host
+        codes, valid, data, off = _host(
+            self.codes[:num_rows], self.validity[:num_rows], self.dict_data,
+            self.dict_offsets)
         binary = isinstance(self.dtype, BinaryType)
         out: List = []
         for i in range(num_rows):
@@ -386,7 +386,9 @@ def decoded_byte_bucket(col: DictionaryColumn) -> int:
     """Byte bucket a full decode of `col` needs (host sync — the
     materialize seams are host-level by design, so the decoded buffer
     is sized tight instead of to a static worst case)."""
-    return bucket_capacity(max(int(_decoded_bytes_jit(col)), 1))
+    from ..obs.dispatch import host_read
+    need = int(host_read("encoded.decoded_bytes", _decoded_bytes_jit(col)))
+    return bucket_capacity(max(need, 1))
 
 
 def materialize_column(col, fault_key: Optional[str] = None,

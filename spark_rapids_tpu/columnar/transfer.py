@@ -116,6 +116,7 @@ def _pack_impl(batch) -> jnp.ndarray:
 
 
 from ..obs import op_span
+from ..obs.dispatch import host_read
 from ..obs.dispatch import instrument as _instrument
 
 _pack_jit = _instrument(_pack_impl, label="transfer.pack_batch")
@@ -217,7 +218,8 @@ def fetch_split_host(counts, columns) -> Tuple[np.ndarray, List[Column]]:
     Returns (counts int64 numpy, numpy-backed columns).
     """
     n_parts = int(counts.shape[0])
-    buf = np.asarray(_pack_split_jit(counts, list(columns)))  # ONE d2h
+    buf = host_read("result.fetch",
+                    _pack_split_jit(counts, list(columns)))  # ONE d2h
     note_d2h(buf.nbytes)
     return unpack_split_host(buf, columns, n_parts)
 
@@ -241,7 +243,7 @@ def fetch_batch_host(batch) -> Tuple[List[Column], int]:
     # program the query enqueued has to finish before these bytes exist
     with op_span("result.fetch", phase="device-wait"):
         packed = _pack_jit(batch)
-        buf = np.asarray(packed)  # the single transfer
+        buf = host_read("result.fetch", packed)  # the single transfer
     note_d2h(buf.nbytes)
     n = int(buf[:4].view(np.int32)[0])
     pos = 4

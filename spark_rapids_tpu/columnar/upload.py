@@ -68,6 +68,7 @@ from .column import (ArrayColumn, Column, Decimal128Column, MapColumn,
 from .encoded import DictionaryColumn
 from . import transfer as _transfer
 from ..obs import op_span
+from ..obs.dispatch import host_read
 
 __all__ = [
     "StagingPool", "staging_pool", "reset_staging_pool", "counters",
@@ -161,7 +162,7 @@ class StagingPool:
         still = []
         for buf, leaves in pending:
             if block:
-                jax.block_until_ready(leaves)
+                host_read("upload.staging", leaves, fetch=False)
             if all(a.is_ready() for a in leaves):
                 self.release(buf)
             else:

@@ -37,7 +37,7 @@ from ..ops.aggregate import groupby_aggregate, groupby_aggregate_hash
 from ..ops.basic import active_mask, sanitize
 from ..ops.sort import string_words_for
 from ..types import DataType, LongType, Schema, StructField
-from ..obs.dispatch import instrument
+from ..obs.dispatch import host_read, instrument
 from ..obs.span import op_span
 from .base import (AGG_TIME, CONCAT_TIME, DEBUG, DISPATCH_METRICS,
                    NUM_INPUT_BATCHES, NUM_INPUT_ROWS, TpuExec)
@@ -754,7 +754,7 @@ class AggregateExec(TpuExec):
                 or not known_to_trip(fingerprint):
             return batch
         from ..columnar.column import bucket_capacity
-        sizes = jax.device_get(_partial_size(batch))
+        sizes = host_read("agg.partial_size", _partial_size(batch))
         if 4 * bucket_capacity(max(int(sizes[0]), 1)) > batch.capacity:
             return batch
         return _tight_partial(batch, sizes)
@@ -955,7 +955,8 @@ class AggregateExec(TpuExec):
         for tier, program in tiers:
             out, leftover = program(batch)
             # one host read: the flag, and what a tight bucket needs
-            leftover, sizes = jax.device_get((leftover, _partial_size(out)))
+            leftover, sizes = host_read("agg.leftover",
+                                        (leftover, _partial_size(out)))
             if not leftover:
                 if tier == "lanes":
                     _note(lane_updates=1)

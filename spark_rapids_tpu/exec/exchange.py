@@ -36,7 +36,7 @@ from ..types import Schema
 from ..obs import events as obs_events
 from ..obs import phase as obs_phase
 from ..obs import op_span
-from ..obs.dispatch import instrument
+from ..obs.dispatch import host_read, instrument
 from .base import (BROADCAST_TIME, DEBUG, DISPATCH_METRICS, ESSENTIAL,
                    GATHER_METRICS,
                    GATHER_TIME, MODERATE,
@@ -93,8 +93,8 @@ def _host_key_array(col, n: int, idx=None):
     from ..columnar.column import Column, StringColumn
     from ..types import BinaryType
     if type(col) is Column:
-        data = np.asarray(col.data)[:n]
-        valid = np.asarray(col.validity)[:n]
+        data, valid = host_read("exchange.keys", (col.data, col.validity))
+        data, valid = np.asarray(data)[:n], np.asarray(valid)[:n]
         if idx is not None:
             data, valid = data[idx], valid[idx]
         if data.dtype.kind == "f":
@@ -103,9 +103,9 @@ def _host_key_array(col, n: int, idx=None):
         out[~valid] = None
         return out
     if isinstance(col, StringColumn):
-        offsets = np.asarray(col.offsets)
-        valid = np.asarray(col.validity)
-        buf = np.asarray(col.data).tobytes()
+        offsets, valid, buf = host_read(
+            "exchange.keys", (col.offsets, col.validity, col.data))
+        buf = np.asarray(buf).tobytes()
         binary = isinstance(col.dtype, BinaryType)
         rows = range(n) if idx is None else idx
         out = np.empty(n if idx is None else len(idx), dtype=object)
@@ -232,7 +232,8 @@ class ShuffleExchangeExec(TpuExec):
         per_dev = [b.sized_to(cap) for b in per_dev]
         stacked = stack_batches(per_dev)
 
-        max_count, max_len, totals = self._jit_measure(stacked)
+        max_count, max_len, totals = host_read(
+            "exchange.round", self._jit_measure(stacked))
         # one host sync per ROUND: size the receive buffer to the
         # measured max partition load, and string lanes to the measured
         # max byte length (truncation structurally impossible)
@@ -564,7 +565,7 @@ class HostShuffleExchangeExec(TpuExec):
                 self._split_templates[key] = tmpl
             with self._gather_track.observe(key):
                 buf_dev = self._jit_split(b, jnp.int32(off))
-            buf = np.asarray(buf_dev)  # the ONE d2h copy
+            buf = host_read("exchange.split", buf_dev)  # the ONE d2h copy
             transfer.note_d2h(buf.nbytes)
             counts, cols = transfer.unpack_split_host(
                 buf, tmpl, self.n_partitions)
@@ -658,7 +659,8 @@ class HostShuffleExchangeExec(TpuExec):
         import numpy as np
         mode = self.partitioning
         if mode == "hash":
-            return np.asarray(self._jit_pid(batch))[:n]
+            return np.asarray(host_read("exchange.pid",
+                                        self._jit_pid(batch)))[:n]
         if mode == "single":
             return np.zeros(n, np.int64)
         if mode == "roundrobin":
@@ -808,9 +810,9 @@ class HostShuffleExchangeExec(TpuExec):
                 # host-pack/serialize except the writer's file-IO share,
                 # which the nested add() carves out as shuffle-io (and
                 # the span excludes from its own exclusive time)
-                with op_span("exchange.map_write",
-                             phase="host-pack-serialize",
-                             metric=self.metrics[SHUFFLE_WRITE_TIME]):
+                with self.metrics[SHUFFLE_WRITE_TIME].ns_timer(), \
+                        op_span("exchange.map_write",
+                                phase="host-pack-serialize"):
                     writer, lane, pack_ns, rows_pp = self._write_map(
                         b, n, bounds, handle, mgr, map_id)
                     obs_phase.add("shuffle-io", writer.io_ns)
@@ -1129,8 +1131,8 @@ class HostShuffleExchangeExec(TpuExec):
                                    fault_key=f"r{round_idx}",
                                    metric_scope=True):
             stacked = stack_batches(per_dev)
-            max_count, max_len, per_map = self._get_ici_measure()(
-                stacked, rr)
+            max_count, max_len, per_map = host_read(
+                "exchange.ici_round", self._get_ici_measure()(stacked, rr))
             # one host sync per round; the running high-water hints
             # keep later (smaller) rounds on the SAME compiled step
             self._ici_cap_hint = max(self._ici_cap_hint, int(max_count))

@@ -20,7 +20,7 @@ from ..columnar.column import (ArrayColumn, Column, MapColumn,
 from ..expr.core import Expression, resolve
 from ..ops.basic import active_mask, compaction_order, gather_column
 from ..types import ArrayType, IntegerType, Schema, StructField
-from ..obs.dispatch import instrument
+from ..obs.dispatch import host_read, instrument
 from .base import (DEBUG, DISPATCH_METRICS, NUM_INPUT_BATCHES, OP_TIME,
                    TpuExec)
 
@@ -120,8 +120,8 @@ class GenerateExec(TpuExec):
         if not any(isinstance(c, (StringColumn, ArrayColumn, MapColumn))
                    for c in batch.columns):
             return (None,) * len(batch.columns)
-        needs = iter(int(n) for n in jax.device_get(
-            self._jit_measure(batch)))
+        needs = iter(int(n) for n in host_read(
+            "generate.payload", self._jit_measure(batch)))
         caps = []
         for c in batch.columns:
             if isinstance(c, StringColumn):

@@ -29,6 +29,7 @@ from ..columnar.column import Column, StringColumn, bucket_capacity
 from ..columnar.encoded import DictionaryColumn
 from ..expr.core import Expression, resolve
 from ..memory.spillable import SpillableBatch
+from ..obs.dispatch import host_read
 from ..ops.basic import active_mask, compaction_order, gather_column
 from ..ops.strings import string_equal
 from ..ops.join import (
@@ -685,7 +686,8 @@ class HashJoinExec(TpuExec):
         else:
             # ONE host sync per stream batch sizes the candidate bucket AND
             # the string byte buckets (exact measured needs, no truncation)
-            total, (s_needs, b_needs) = jax.device_get((total_dev, needs_dev))
+            total, (s_needs, b_needs) = host_read("join.candidates",
+                                                  (total_dev, needs_dev))
             cand_cap = bucket_capacity(max(int(total), 1))
             s_caps = _byte_cap_tuple(stream_batch.columns, s_needs)
             b_caps = _byte_cap_tuple(build.payload, b_needs)
@@ -804,7 +806,7 @@ class NestedLoopJoinExec(TpuExec):
                 maxes.append(jnp.max(jnp.where(act, string_lengths(c), 0)))
         if not maxes:
             return [None] * len(batch.columns)
-        fetched = iter(jax.device_get(jnp.stack(maxes)).tolist())
+        fetched = iter(host_read("join.key_max", jnp.stack(maxes)).tolist())
         return [int(next(fetched)) if isinstance(c, StringColumn) else None
                 for c in batch.columns]
 

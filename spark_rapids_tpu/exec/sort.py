@@ -25,6 +25,7 @@ from ..expr.core import BoundReference, Expression, resolve
 from ..memory.retry import split_in_half_by_rows, with_retry, with_retry_no_split
 from ..memory.spillable import SpillableBatch
 from ..ops.basic import active_mask, slice_rows
+from ..obs.dispatch import host_read
 from ..obs.span import op_span
 from ..ops.sort import (
     SortOrder, first_rows, lexsort_permutation, order_key_lanes,
@@ -343,7 +344,7 @@ class SortExec(TpuExec):
                 leq = _lex_leq(lane_cache[i], bound) \
                     & active_mask(h.num_rows, h.capacity)
                 counts.append(jnp.sum(leq.astype(jnp.int32)))
-            fetched = [int(c) for c in jax.device_get(counts)]
+            fetched = [int(c) for c in host_read("sort.counts", counts)]
             for i, cnt in zip(live, fetched):
                 h = heads[i]
                 n = h.num_rows_host

@@ -78,8 +78,10 @@ class SpeculationScope:
         if not self.flags:
             return False
         import jax.numpy as jnp
+
+        from ..obs.dispatch import host_read
         flags = self.drain()
-        hit = np.asarray(jnp.stack([f for f, _ in flags]))
+        hit = host_read("spec.flags", jnp.stack([f for f, _ in flags]))
         for (_, owner), h in zip(flags, hit):
             if h and owner is not None:
                 _note_tripped(owner)

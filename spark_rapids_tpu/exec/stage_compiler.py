@@ -73,6 +73,7 @@ import warnings
 from typing import Dict, List, Optional, Tuple
 
 from ..columnar.batch import ColumnarBatch
+from ..obs.dispatch import host_read
 from ..types import Schema
 from .base import (AGG_TIME, DISPATCH_METRICS, GATHER_METRICS,
                    GATHER_TIME, NUM_DISPATCHES, NUM_GATHERS, TpuExec)
@@ -745,8 +746,8 @@ class CompiledStageExec(TpuExec):
         with op_span("join.sizing", phase="device-wait"):
             total_dev, needs_dev = self._jit_sizing(build_batch,
                                                     stream_batch)
-            total, (s_needs, b_needs) = jax.device_get(
-                (total_dev, needs_dev))
+            total, (s_needs, b_needs) = host_read(
+                "stage.candidates", (total_dev, needs_dev))
         cand_cap = bucket_capacity(max(int(total), 1))
         s_caps = _byte_cap_tuple(stream_batch.columns, s_needs)
         b_caps = _byte_cap_tuple(build_batch.columns, b_needs)

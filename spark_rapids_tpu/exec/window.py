@@ -37,7 +37,7 @@ from ..ops.window import (
     windowed_sum_count,
 )
 from ..types import DoubleType, IntegerType, LongType, Schema, StructField
-from ..obs.dispatch import instrument
+from ..obs.dispatch import host_read, instrument
 from .base import (DISPATCH_METRICS, GATHER_METRICS, GATHER_TIME,
                    NUM_GATHERS, OP_TIME,
                    TpuExec)
@@ -536,7 +536,8 @@ class WindowExec(TpuExec):
             self._jit_lps = instrument(lps,
                                        label="WindowExec.last_part_start",
                                        owner=self, static_argnums=(1,))
-        return int(self._jit_lps(batch, words))
+        return int(host_read("window.partition_start",
+                             self._jit_lps(batch, words)))
 
     def internal_execute(self) -> Iterator[ColumnarBatch]:
         try:

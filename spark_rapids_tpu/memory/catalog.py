@@ -41,6 +41,7 @@ import numpy as np
 from ..config import (HOST_SPILL_LIMIT, SPILL_ASYNC_WRITE, SPILL_DIR,
                       active_conf)
 from .. import faults
+from ..obs.dispatch import host_read
 
 
 class StorageTier(IntEnum):
@@ -362,8 +363,8 @@ class BufferCatalog:
                 # catalog lock: the entry must not be observable
                 # mid-hop, and the async lane exists precisely for
                 # callers that cannot afford this hold
-                entry.host_leaves = [np.asarray(jax.device_get(x))
-                                     for x in leaves]
+                host = host_read("spill.d2h", list(leaves))
+                entry.host_leaves = [np.asarray(x) for x in host]
             except Exception as e:  # noqa: BLE001 — transient device
                 # error mid-copy: the data never left the device — put
                 # the entry back intact and surface a task-level retry
@@ -660,7 +661,8 @@ class BufferCatalog:
                 # contract: ok lock-blocking-call — lock-free on the
                 # writer thread (steady state); under the catalog RLock
                 # only on the dead-writer synchronous drain (recovery)
-                host = [np.asarray(jax.device_get(x)) for x in pending]
+                host = [np.asarray(x) for x in host_read("spill.d2h",
+                                                         list(pending))]
             except Exception as e:  # noqa: BLE001 — transient device
                 # error: the data never left the device; put the entry
                 # back on the DEVICE tier intact (budget never released)

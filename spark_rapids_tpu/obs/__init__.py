@@ -6,11 +6,11 @@ GpuMetric/GpuTaskMetrics/NVTX stack joined into one subsystem (ISSUE 2):
     exchange volumes), gated by the
     spark.rapids.tpu.eventLog.{enabled,dir,level} confs and costing one
     pointer check per batch when disabled.
-  * `span` — op_span(name, phase=, metric=): the ONE span primitive —
+  * `span` — op_span(name, phase=): the ONE span primitive —
     one context manager that writes the host event into the profiler's
     trace (TraceAnnotation: the device trace's clock, any thread),
-    accrues the block's exclusive time to a wall-clock phase, bumps a
-    TpuMetric, and appends a DEBUG `span` event record. The ingest
+    accrues the block's exclusive time to a wall-clock phase, and
+    appends a DEBUG `span` event record. The ingest
     path's boundaries open it: `session.plan` (phase `plan`),
     `scan.decode` (`scan-decode`), `upload.pack` / `upload.put`
     (`upload`), `result.fetch` (`device-wait`).
@@ -39,7 +39,10 @@ GpuMetric/GpuTaskMetrics/NVTX stack joined into one subsystem (ISSUE 2):
     QueryProfile.dispatch_summary() on top of it. Each program record
     names the XLA `module` it carries in a device trace, and
     `dispatch.module_labels()` maps module -> labels, so device time
-    joins to the engine's own names.
+    joins to the engine's own names. `dispatch.host_read(site, value)`
+    is the one door of a device-to-host read: span `sync.<site>`, phase
+    `host-sync` outside every other span, counted by site
+    (`dispatch.sync_sites()`).
 
 Render an event-log file with tools/profile_report.py (`--format json`
 for the machine-readable summary) and telemetry samples with

@@ -37,6 +37,14 @@ times inside `spark.rapids.tpu.dispatch.storm.windowMs` — the
 shape-bucket-churn failure mode that silently destroys TPU throughput
 (every batch a new exact shape, every dispatch a fresh XLA compile).
 
+Host reads (ISSUE 40): `host_read(site, value)` is the ONE door a
+device-to-host read takes — `jax.device_get` (or, with `fetch=False`,
+a wait that fetches nothing) inside the span `sync.<site>`, counted per
+site (`sync_sites()`: reads and ns; `counters()`: `syncs`, `sync_ns`).
+A read outside every other span accrues to phase `host-sync`; inside
+one (`agg.update`, `result.fetch`, ...) that span's phase keeps it, the
+rule `note_dispatch` applies to `device-compute`.
+
 Cost discipline: `spark.rapids.tpu.dispatch.ledger.enabled` defaults
 ON (the ledger is host-side bookkeeping, ~one dict update per program
 dispatch — noise against jit dispatch overhead); explicitly false =
@@ -60,7 +68,8 @@ __all__ = [
     "DispatchLedger", "InstrumentedJit", "instrument", "active_ledger",
     "configure", "reset_dispatch_ledger", "counters", "programs",
     "health_section", "metric_scope", "site_cache_counters",
-    "reset_site_cache", "module_labels",
+    "reset_site_cache", "module_labels", "host_read", "sync_sites",
+    "in_host_read",
 ]
 
 #: canonical per-exec metric names (exec/base.py re-exports them into
@@ -221,6 +230,10 @@ class DispatchLedger:
         self._compile_ns = 0
         self._trace_ns = 0
         self._storms = 0
+        #: host reads by site: {site: [reads, ns]}
+        self._syncs: Dict[str, List[int]] = {}
+        self._sync_reads = 0
+        self._sync_ns = 0
 
     # -- the per-call accounting (InstrumentedJit.__call__ fast path) --
     def dispatch(self, site: "InstrumentedJit", args, kwargs):
@@ -314,6 +327,16 @@ class DispatchLedger:
                 for kind, fields in out_events:
                     obs_events.emit(kind, **fields)
 
+    def note_sync(self, site: str, ns: int) -> None:
+        with self._lock:
+            rec = self._syncs.get(site)
+            if rec is None:
+                rec = self._syncs[site] = [0, 0]
+            rec[0] += 1
+            rec[1] += ns
+            self._sync_reads += 1
+            self._sync_ns += ns
+
     def _note_trace_locked(self, prog: ProgramStats):
         """Caller holds self._lock. Slide the storm window; past the
         conf'd trace count one `recompile_storm` fires and the key goes
@@ -347,7 +370,14 @@ class DispatchLedger:
                     "cache_hits": self._cache_hits,
                     "compile_ns": self._compile_ns,
                     "trace_ns": self._trace_ns,
-                    "storms": self._storms}
+                    "storms": self._storms,
+                    "syncs": self._sync_reads,
+                    "sync_ns": self._sync_ns}
+
+    def sync_sites(self) -> Dict[str, Dict[str, int]]:
+        with self._lock:
+            return {site: {"reads": r, "ns": ns}
+                    for site, (r, ns) in self._syncs.items()}
 
     def programs(self) -> List[Dict[str, Any]]:
         with self._lock:
@@ -379,6 +409,9 @@ def _timed_dispatch(site: "InstrumentedJit", args, kwargs,
         try:
             out = site._jit(*args, **kwargs)
             import jax
+            # contract: ok host-sync-chokepoint — the hang bound waits for
+            # the program it dispatched (a timing, not a read): counting it
+            # as a sync would book every bounded dispatch twice
             jax.block_until_ready(out)
             return out
         finally:
@@ -442,8 +475,15 @@ def counters() -> Dict[str, int]:
     if led is None:
         return {"programs": 0, "dispatches": 0, "traces": 0,
                 "cache_hits": 0, "compile_ns": 0, "trace_ns": 0,
-                "storms": 0}
+                "storms": 0, "syncs": 0, "sync_ns": 0}
     return led.counters()
+
+
+def sync_sites() -> Dict[str, Dict[str, int]]:
+    """{site: {"reads", "ns"}} of every `host_read` since the ledger
+    was (re)made; empty with the ledger off."""
+    led = _ledger
+    return led.sync_sites() if led is not None else {}
 
 
 def programs() -> List[Dict[str, Any]]:
@@ -471,7 +511,52 @@ def health_section() -> Dict[str, Any]:
         progs = led.programs()
         progs.sort(key=lambda p: -p["compile_ns"])
         out["top_programs"] = progs[:5]
+        sites = sorted(led.sync_sites().items(), key=lambda kv: -kv[1]["ns"])
+        out["top_sync_sites"] = [{"site": site, **rec}
+                                 for site, rec in sites[:5]]
     return out
+
+
+def in_host_read() -> bool:
+    """Is this thread inside `host_read`'s fetch? (tier-1 holds every
+    device-to-host conversion of a collect() to this door.)"""
+    return getattr(_tls, "host_read", False)
+
+
+def _read(value, fetch: bool):
+    import jax
+    # contract: ok host-sync-chokepoint — the door itself
+    return jax.device_get(value) if fetch else jax.block_until_ready(value)
+
+
+def host_read(site: str, value, *, fetch: bool = True):
+    """THE door for a device-to-host read: `jax.device_get(value)` (host
+    ints and numpy arrays for the caller to take apart), or with
+    `fetch=False` a wait that fetches nothing (`jax.block_until_ready`,
+    returning `value`). With the ledger on it runs inside
+    `op_span(f"sync.{site}")`, so a kept trace names the runtime's read
+    events by the engine site that issued them, and counts the read and
+    its ns under `site`. Its time accrues to phase `host-sync` only
+    outside every other span. A value with no device array in it is no
+    read: it passes through uncounted. With the ledger off: a plain
+    `device_get` / `block_until_ready`, no count and no span."""
+    import jax
+    led = _ledger
+    if led is None or not any(isinstance(x, jax.Array)
+                              for x in jax.tree_util.tree_leaves(value)):
+        return _read(value, fetch)
+    from . import phase as obs_phase
+    from .span import op_span
+    phase = None if obs_phase.in_span() else "host-sync"
+    prev = getattr(_tls, "host_read", False)
+    t0 = time.perf_counter_ns()
+    try:
+        with op_span(f"sync.{site}", phase=phase):
+            _tls.host_read = True
+            return _read(value, fetch)
+    finally:
+        _tls.host_read = prev
+        led.note_sync(site, time.perf_counter_ns() - t0)
 
 
 @contextmanager

@@ -84,6 +84,8 @@ PHASES = (
     "plan-rerun",          # a whole plan run a second time after its
                            # speculation flag tripped: what of the second
                            # pass no inner phase claims (exec/base.py)
+    "host-sync",           # a device-to-host read outside every other
+                           # span (obs/dispatch.host_read, span sync.*)
     "other",               # derived remainder — never negative
 )
 

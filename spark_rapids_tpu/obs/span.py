@@ -10,7 +10,6 @@ One context manager:
     phase (obs/phase.py: child time subtracted, global counters always,
     the query's PhaseLedger when one is attached; a span on a
     non-driving thread folds into the pipeline-stall budget),
-  * with `metric=`, adds the elapsed ns to a TpuMetric,
   * appends a `span` event record (DEBUG level) to the event bus when
     logging is enabled.
 
@@ -20,7 +19,9 @@ span's time is exactly what an operator debugging it wants attributed
 
 The span names the engine opens (docs/observability.md has the table):
 `session.plan`, `scan.decode`, `upload.pack`, `upload.put`,
-`result.fetch`, `exchange.map_write`, `exchange.ici_round`.
+`result.fetch`, `exchange.map_write`, `exchange.ici_round`, and
+`sync.<site>` around every device-to-host read
+(`obs.dispatch.host_read`).
 `utils.tracing.annotate_op` stays as the annotation-only form for the
 per-batch operator loop (exec/base._drive).
 """
@@ -37,7 +38,7 @@ from . import phase as obs_phase
 
 
 @contextlib.contextmanager
-def op_span(name: str, *, phase: Optional[str] = None, metric=None,
+def op_span(name: str, *, phase: Optional[str] = None,
             **fields: Any) -> Iterator[None]:
     bus = events.active_bus()
     t0 = time.perf_counter_ns()
@@ -51,8 +52,6 @@ def op_span(name: str, *, phase: Optional[str] = None, metric=None,
         ok = False
         raise
     finally:
-        dt = time.perf_counter_ns() - t0
-        if metric is not None:
-            metric.add(dt)
         if bus is not None:
-            bus.emit("span", op=name, wall_ns=dt, ok=ok, **fields)
+            bus.emit("span", op=name, wall_ns=time.perf_counter_ns() - t0,
+                     ok=ok, **fields)

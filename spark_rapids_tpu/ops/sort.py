@@ -153,7 +153,9 @@ def string_key_bytes(columns: Sequence[Column],
                     if isinstance(columns[i], StringColumn))
     if not offsets:
         return 1
-    need = max(1, int(_max_string_length_jit(offsets)))
+    from ..obs.dispatch import host_read
+    need = max(1, int(host_read("sort.key_width",
+                                _max_string_length_jit(offsets))))
     return 1 << (need - 1).bit_length()
 
 

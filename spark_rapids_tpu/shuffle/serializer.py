@@ -63,7 +63,8 @@ def schema_fingerprint(schema: Schema) -> int:
 # ---------------------------------------------------------------------------
 
 def _np(x) -> np.ndarray:
-    return np.asarray(x)
+    from ..obs.dispatch import host_read
+    return np.asarray(host_read("shuffle.encode", x))
 
 
 def _rebase_offsets(off: np.ndarray, n: int, start: int = 0) -> np.ndarray:

@@ -134,6 +134,18 @@ def test_dispatch_rule_fires():
     assert keys == ["jax.jit", "jax.jit", "pallas_call"], keys
 
 
+def test_host_sync_rule_fires():
+    """ISSUE 40 satellite: every jax.device_get / .block_until_ready()
+    must go through the host-read chokepoint (obs.dispatch.host_read) or
+    carry a justified suppression — a bare read is a sync the ledger
+    cannot count by site and the trace cannot name."""
+    rep = run_fixture("fx_host_sync.py")
+    assert rules_fired(rep) == ["host-sync-chokepoint"]
+    keys = sorted(f.key for f in rep.findings)
+    assert keys == ["block_until_ready", "block_until_ready",
+                    "jax.device_get"], keys
+
+
 def test_stage_governance_rule_fires():
     """ISSUE 14 satellite: per-batch governance hooks (lifecycle tick,
     chaos fault points, metric timers, event emits, gather observes)
@@ -173,6 +185,7 @@ def test_registry_rules_fire():
     ("fx_accounting_ok.py", 2),
     ("fx_registry_ok.py", 2),
     ("fx_dispatch_ok.py", 2),
+    ("fx_host_sync_ok.py", 1),
     ("fx_stage_ok.py", 1),
 ])
 def test_suppressions_silence(fname, n_suppressed):
@@ -361,7 +374,8 @@ def test_every_rule_family_is_fixture_proven():
     fired = set()
     for fname in ("fx_locks.py", "fx_bounded_wait.py", "fx_threads.py",
                   "fx_trace.py", "fx_conf.py", "fx_accounting.py",
-                  "fx_registry.py", "fx_dispatch.py", "fx_stage.py"):
+                  "fx_registry.py", "fx_dispatch.py", "fx_stage.py",
+                  "fx_host_sync.py"):
         for f in run_fixture(fname).findings:
             fired.add(f.rule)
     non_meta = {rid for rid, m in reg_mod.RULES.items()

@@ -126,7 +126,8 @@ def test_off_path_is_pointer_check_and_results_identical():
     np.testing.assert_array_equal(on, off)
     assert dispatch.counters() == {
         "programs": 0, "dispatches": 0, "traces": 0, "cache_hits": 0,
-        "compile_ns": 0, "trace_ns": 0, "storms": 0}
+        "compile_ns": 0, "trace_ns": 0, "storms": 0, "syncs": 0,
+        "sync_ns": 0}
     # a default conf re-enables (the conf defaults ON)
     dispatch.configure(__import__(
         "spark_rapids_tpu.config", fromlist=["RapidsConf"]).RapidsConf({}))

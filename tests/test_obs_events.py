@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 from spark_rapids_tpu.api.session import TpuSession
-from spark_rapids_tpu.exec.base import TpuMetric
 from spark_rapids_tpu.expr.aggexprs import Count, Sum
 from spark_rapids_tpu.expr.core import col, lit
 from spark_rapids_tpu.obs import events, op_span
@@ -134,16 +133,16 @@ def test_event_level_filters_span_records(tmp_path):
 
 def test_span_nesting_and_attribution(tmp_path):
     """op_span is the NvtxWithMetrics analog: nested spans all record,
-    each bumps its metric, and every record carries the enclosing query
-    id."""
+    each accrues its block's EXCLUSIVE time to its phase, and every
+    record carries the enclosing query id."""
+    from spark_rapids_tpu.obs import phase as obs_phase
     events.enable(str(tmp_path), "DEBUG")
-    outer_m = TpuMetric("opTime")
-    inner_m = TpuMetric("opTime")
+    before = obs_phase.counters()
     with events.query_scope(77):
-        with op_span("outer", metric=outer_m, detail="a"):
-            with op_span("inner", metric=inner_m):
+        with op_span("outer", phase="plan", detail="a"):
+            with op_span("inner", phase="sort"):
                 pass
-    assert outer_m.value >= inner_m.value > 0
+    after = obs_phase.counters()
     recs = _read_log(tmp_path)
     spans = {r["op"]: r for r in recs if r["kind"] == "span"}
     assert set(spans) == {"outer", "inner"}
@@ -151,18 +150,25 @@ def test_span_nesting_and_attribution(tmp_path):
     assert spans["outer"]["detail"] == "a"
     # inner closes first (nesting), and its wall time is contained
     assert spans["inner"]["ts_ns"] <= spans["outer"]["ts_ns"]
-    assert spans["inner"]["wall_ns"] <= spans["outer"]["wall_ns"]
+    assert 0 < spans["inner"]["wall_ns"] <= spans["outer"]["wall_ns"]
+    # each phase took its own span's exclusive time, and no more than
+    # the outer span's wall between them
+    grew = {p: after[p] - before[p] for p in ("plan", "sort")}
+    assert grew["sort"] > 0 and grew["plan"] > 0
+    assert grew["sort"] + grew["plan"] <= spans["outer"]["wall_ns"] + 10**6
 
 
-def test_span_records_failure_and_still_bumps_metric(tmp_path):
+def test_span_records_failure_and_still_accrues_its_phase(tmp_path):
+    from spark_rapids_tpu.obs import phase as obs_phase
     events.enable(str(tmp_path), "DEBUG")
-    m = TpuMetric("opTime")
+    before = obs_phase.counters()["plan-rerun"]
     with pytest.raises(ValueError):
-        with op_span("boom", metric=m):
+        with op_span("boom", phase="plan-rerun"):
             raise ValueError("x")
-    assert m.value > 0
+    assert obs_phase.counters()["plan-rerun"] > before
     (rec,) = _read_log(tmp_path)
     assert rec["op"] == "boom" and rec["ok"] is False
+    assert rec["wall_ns"] > 0
 
 
 def test_memory_events_spill_and_retry(tmp_path):

@@ -20,7 +20,6 @@ import pytest
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.api.session import TpuSession
 from spark_rapids_tpu.exec import lifecycle
-from spark_rapids_tpu.exec.base import TpuMetric
 from spark_rapids_tpu.expr.core import col, lit
 from spark_rapids_tpu.obs import dispatch, events, op_span, phase
 from spark_rapids_tpu.obs.phase import PHASES
@@ -98,16 +97,14 @@ def test_a_cached_dispatch_inside_a_phase_span_stays_with_the_span():
 
 
 @pytest.mark.parametrize("with_phase", [False, True])
-def test_metric_and_event_with_and_without_a_phase(tmp_path, with_phase):
+def test_event_with_and_without_a_phase(tmp_path, with_phase):
     phase.reset_phase_counters()
     events.enable(str(tmp_path), "DEBUG")
-    m = TpuMetric("opTime")
     with pytest.raises(ValueError):
         with op_span("boom", phase="plan" if with_phase else None,
-                     metric=m, side="left"):
+                     side="left"):
             _spin(200_000)
             raise ValueError("x")
-    assert m.value >= 200_000
     assert (phase.counters()["plan"] >= 200_000) is with_phase
     assert not phase.in_span()                   # the frame was popped
     events.reset_event_bus()
@@ -115,6 +112,27 @@ def test_metric_and_event_with_and_without_a_phase(tmp_path, with_phase):
     (rec,) = [json.loads(ln) for ln in open(log) if ln.strip()]
     assert rec["kind"] == "span" and rec["op"] == "boom"
     assert rec["ok"] is False and rec["side"] == "left"
+    assert rec["wall_ns"] >= 200_000
+
+
+def test_the_map_write_times_its_metric_beside_its_span():
+    """The shuffle map write, the one engine caller of op_span's former
+    `metric=` (PR 40), times `shuffleWriteTime` with the metric's own
+    timer; its span still accrues host-pack-serialize."""
+    from spark_rapids_tpu.types import LONG, Schema
+    phase.reset_phase_counters()
+    sess = TpuSession({"spark.rapids.sql.shuffle.partitions": "2",
+                       "spark.rapids.sql.broadcastSizeThreshold": "-1",
+                       "spark.rapids.tpu.adaptive.enabled": "false"})
+    df = sess.from_pydict({"k": [i % 7 for i in range(200)],
+                           "v": list(range(200))},
+                          Schema.of(k=LONG, v=LONG), batch_rows=50)
+    out = df.group_by("k").agg((F.sum("v"), "s")).collect()
+    assert sorted(out) == sorted(
+        (k, sum(v for v in range(200) if v % 7 == k)) for k in range(7))
+    m = sess.last_query_metrics()
+    assert m["total.shuffleWriteTime"] > 0
+    assert phase.counters()["host-pack-serialize"] > 0
 
 
 def test_a_span_on_a_non_driving_thread_lands_in_the_ledgers_folded_map():

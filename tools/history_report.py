@@ -44,7 +44,7 @@ PHASES = (
     "shuffle-io", "ici-collective", "spill-wait", "semaphore-wait",
     "pipeline-stall", "retry-backoff", "spec-wait", "plan", "scan-decode",
     "upload", "device-wait", "join-build", "group-agg", "sort", "plan-rerun",
-    "other",
+    "host-sync", "other",
 )
 
 
