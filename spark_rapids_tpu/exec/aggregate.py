@@ -87,6 +87,12 @@ _COUNTERS = {
     # the ones the lane tier ran on and left keys over (more distinct keys
     # than its slots): they went on to the hash rounds
     "lane_leftovers": 0,
+    # of `lane_updates`, the ones that ran on the absorbed filter/project
+    # chain's row mask, the batch never compacted
+    "lane_masked_updates": 0,
+    # batches of such a chain compacted once by its mask because a tier
+    # after the lane tier (hash rounds, sort path) needed them packed
+    "mask_compactions": 0,
 }
 
 #: what a tier that left keys over counts, by its key in the tiers' sites
@@ -264,8 +270,11 @@ class AggregateExec(TpuExec):
         # whole-stage fusion: inline upstream filter/project chains into
         # this operator's program (one XLA program per source batch; the
         # reference's analog is whole-stage codegen — XLA is the codegen).
-        # Only for the masked tier: the string tiers consume child batches.
-        if self._fusion_enabled and mode != "final" and self._masked_ok:
+        # For the masked tier, and for the lane tier (`_pre_project` hands
+        # it the filters' row mask); the tiers after the lane tier take the
+        # batch compacted by that mask (`_compact`).
+        if self._fusion_enabled and mode != "final" \
+                and (self._masked_ok or self._lane_ok):
             steps, node = [], child
             while hasattr(node, "fused_step"):
                 steps.append(node.fused_step())
@@ -319,6 +328,8 @@ class AggregateExec(TpuExec):
             label="AggregateExec.merge_auto")
         self._jit_pre = self._site(self._pre_project,
                                    label="AggregateExec.pre_project")
+        self._jit_compact = self._site(self._compact,
+                                       label="AggregateExec.compact")
         self._jit_concat_merge = self._site(
             self._concat_merge_pair,
             label="AggregateExec.concat_merge", static_argnums=(2,))
@@ -411,8 +422,18 @@ class AggregateExec(TpuExec):
                 (NUM_INPUT_BATCHES, DEBUG)) + DISPATCH_METRICS
 
     # -- kernels -----------------------------------------------------------
-    def _pre_project(self, batch: ColumnarBatch) -> ColumnarBatch:
-        return eval_projection(self._pre_bound, batch, self._pre_schema)
+    def _pre_project(self, batch: ColumnarBatch):
+        """(the pre-projected batch, the absorbed filters' row mask or
+        None): the inlined chain, then keys and aggregate inputs."""
+        cur, mask = self._apply_fused(batch)
+        return eval_projection(self._pre_bound, cur, self._pre_schema), mask
+
+    def _compact(self, batch: ColumnarBatch, mask) -> ColumnarBatch:
+        """The rows `mask` keeps, packed to a prefix: for a tier that
+        ignores a row mask (the hash rounds, the sort path)."""
+        from ..ops.basic import compact_columns
+        cols, n = compact_columns(batch.columns, mask, batch.num_rows)
+        return ColumnarBatch(cols, n, batch.schema)
 
     def _update_inputs(self, batch: ColumnarBatch):
         keys = list(batch.columns[: self._key_count])
@@ -474,8 +495,7 @@ class AggregateExec(TpuExec):
         """Exact tier, one program: fused steps -> pre-project -> masked
         bucket group-by with in-program lax.cond sort fallback."""
         assert self.mode != "final", "final mode merges via _merge_jitted"
-        cur, mask = self._apply_fused(batch)
-        pre = eval_projection(self._pre_bound, cur, self._pre_schema)
+        pre, mask = self._pre_project(batch)
         return self._update_batch(pre, auto_path=True, row_mask=mask)
 
     def _small_cap(self) -> int:
@@ -505,10 +525,8 @@ class AggregateExec(TpuExec):
             cur, mask = batch, None
             keys, agg_inputs = self._merge_inputs(batch)
         else:
-            cur, mask = self._apply_fused(batch)
-            pre = eval_projection(self._pre_bound, cur, self._pre_schema)
-            keys, agg_inputs = self._update_inputs(pre)
-            cur = pre
+            cur, mask = self._pre_project(batch)
+            keys, agg_inputs = self._update_inputs(cur)
 
         if not keys:
             results = [("raw", r) for r in masked_reduce(
@@ -568,23 +586,25 @@ class AggregateExec(TpuExec):
         return self._merge_batch(both, auto_path=True)
 
     def _lane_groupby(self, keys, agg_inputs, batch: ColumnarBatch,
-                      key_bytes: int):
+                      key_bytes: int, row_mask=None):
         """The lane tier's program: (partial in the SMALL bucket, leftover).
         Where `leftover` is set the partial is not an answer."""
         from ..ops.maskedagg import masked_groupby_lanes
         out_keys, results, num_groups, leftover = masked_groupby_lanes(
             keys, agg_inputs, batch.num_rows, batch.capacity, key_bytes,
-            self._slots, self._rounds)
+            self._slots, self._rounds, row_mask)
         return self._build_small_batch(out_keys, results, num_groups), \
             leftover
 
-    def _lane_update(self, batch: ColumnarBatch, key_bytes: int):
+    def _lane_update(self, batch: ColumnarBatch, key_bytes: int,
+                     row_mask=None):
         return self._lane_groupby(*self._update_inputs(batch), batch,
-                                  key_bytes)
+                                  key_bytes, row_mask)
 
-    def _lane_merge(self, batch: ColumnarBatch, key_bytes: int):
+    def _lane_merge(self, batch: ColumnarBatch, key_bytes: int,
+                    row_mask=None):
         return self._lane_groupby(*self._merge_inputs(batch), batch,
-                                  key_bytes)
+                                  key_bytes, row_mask)
 
     def _run_groupby(self, keys, agg_inputs, batch, out_schema, words: int,
                      hash_path: bool = False, hash_rounds: int = 2,
@@ -898,13 +918,15 @@ class AggregateExec(TpuExec):
                         or isinstance(f.data_type, StringType)
                         for f in fields[: self._key_count]))
 
-    def _lane_key_bytes(self, batch: ColumnarBatch) -> Optional[int]:
+    def _lane_key_bytes(self, batch: ColumnarBatch,
+                        row_mask=None) -> Optional[int]:
         """The static key width the lane tier runs this batch at (one host
-        read of the keys' longest string, a power of two), or None where
-        the keys are too wide for it."""
+        read of the longest key string of the rows `row_mask` keeps, a
+        power of two), or None where the keys are too wide for it."""
         from ..ops.maskedagg import key_lane_count
         from ..ops.sort import string_key_bytes
-        key_bytes = string_key_bytes(batch.columns, range(self._key_count))
+        key_bytes = string_key_bytes(batch.columns, range(self._key_count),
+                                     row_mask)
         lanes = sum(key_lane_count(f.data_type, key_bytes)
                     for f in self._buffer_schema.fields[: self._key_count])
         if key_bytes > LANE_KEY_BYTES or lanes > LANE_KEY_COLUMNS:
@@ -926,49 +948,58 @@ class AggregateExec(TpuExec):
             note_many_groups(self.plan_fingerprint())
             return self._jit_step_exact(
                 self._tight_input(batch, self.plan_fingerprint()))
-        pre = self._jit_pre(batch)
+        # a mask comes only with the lane tier (`_lane_ok`, absorbed chain),
+        # whose schema the hash path always takes
+        pre, mask = self._jit_pre(batch)
         if self._hash_path_ok:
-            out = self._hash_tiers(self._jit_update_hash, pre)
+            out, pre = self._hash_tiers(self._jit_update_hash, pre, mask)
             if out is not None:
                 return out
             # unresolved hash collisions: exact sort fallback (reference
             # duality: hash primary, sort fallback)
         return self._jit_update(pre, self._key_words(pre))
 
-    def _hash_tiers(self, sites, batch: ColumnarBatch
-                    ) -> Optional[ColumnarBatch]:
+    def _hash_tiers(self, sites, batch: ColumnarBatch, mask=None
+                    ) -> Tuple[Optional[ColumnarBatch], ColumnarBatch]:
         """The hash path's tiers, counted: the lane tier where the schema
         and the measured keys allow it, then 2 hash rounds, then 6, each
         where the one before left keys over (each reads `leftover` on the
         host, and with it the partial's size, so that it leaves in a tight
-        bucket); None where 6 left some too and the caller falls to the
-        sort path."""
+        bucket). `mask` (the absorbed chain's row mask, or None) rides into
+        the lane tier; the hash rounds ignore a mask, so before the first
+        of them the batch is compacted by it, once. Returns the partial (None
+        where 6 rounds left keys over too) and the batch as the last tier
+        took it, which the caller's sort path then takes."""
         _note(hash_updates=1)
         tiers = [(r, sites[r]) for r in (2, 6)]
         if "lanes" in sites:
-            key_bytes = self._lane_key_bytes(batch)
+            key_bytes = self._lane_key_bytes(batch, mask)
             if key_bytes is None:
                 _note(lane_declines=1)
             else:
-                tiers.insert(0, ("lanes",
-                                 lambda b: sites["lanes"](b, key_bytes)))
+                tiers.insert(0, ("lanes", lambda b, m=mask: sites["lanes"](
+                    b, key_bytes, m)))
         for tier, program in tiers:
+            if tier != "lanes" and mask is not None:
+                _note(mask_compactions=1)
+                batch, mask = self._jit_compact(batch, mask), None
             out, leftover = program(batch)
             # one host read: the flag, and what a tight bucket needs
             leftover, sizes = host_read("agg.leftover",
                                         (leftover, _partial_size(out)))
             if not leftover:
                 if tier == "lanes":
-                    _note(lane_updates=1)
-                return _tight_partial(out, sizes)
+                    _note(lane_updates=1,
+                          lane_masked_updates=int(mask is not None))
+                return _tight_partial(out, sizes), batch
             _note(**{_LEFTOVER_COUNTER[tier]: 1})
-        return None
+        return None, batch
 
     def _merge_jitted(self, batch: ColumnarBatch) -> ColumnarBatch:
         if self._masked_ok:
             return self._jit_merge_auto(batch)
         if self._hash_path_ok:
-            out = self._hash_tiers(self._jit_merge_hash, batch)
+            out, _ = self._hash_tiers(self._jit_merge_hash, batch)
             if out is not None:
                 return out
         return self._jit_merge(batch, self._key_words(batch))

@@ -897,6 +897,18 @@ def compile_stages(root: TpuExec, conf=None) -> TpuExec:
 
 def _rewrite(node: TpuExec) -> TpuExec:
     stage = _try_stage(node)
+    if stage is None and getattr(node, "_fused_steps", None):
+        # an aggregate that runs its filter/project chain inline as a row
+        # mask without forming a stage (the lane tier's): it drives its
+        # `_source`, so the rewrite goes on from there, and the chain it
+        # absorbed never becomes a map stage as well
+        src = node._source
+        new = _rewrite(src)
+        if new is not src:
+            node._source = new
+            last = _chain_between(node, src)[-1]
+            last.children = type(last.children)([new])
+        return node
     target = stage if stage is not None else node
     kids = list(target.children)
     changed = False

@@ -562,7 +562,8 @@ def string_from_key_lanes(lanes: Sequence[Column], key_bytes: int,
 def masked_groupby_lanes(key_columns: Sequence[Column],
                          agg_inputs: Sequence[Tuple[str, Optional[Column]]],
                          num_rows, capacity: int, key_bytes: int,
-                         group_slots: int = 32, rounds: int = 2):
+                         group_slots: int = 32, rounds: int = 2,
+                         row_mask=None):
     """`masked_groupby` for keys of which some are strings no longer than
     `key_bytes` bytes (measured by the caller: `sort.string_key_bytes`):
     each rides as `string_key_lanes`, and the string key columns of the
@@ -570,7 +571,8 @@ def masked_groupby_lanes(key_columns: Sequence[Column],
     gather and no compare by bytes over the source rows beyond the
     `key_bytes` byte gathers that read a key. The aggregates are swept
     only where no key was left over: the caller reads `leftover` and runs
-    another kernel where it is set."""
+    another kernel where it is set. Rows outside `row_mask` take no part,
+    so `key_bytes` need only hold the kept rows' keys."""
     spans, lanes = [], []
     for c in key_columns:
         own = string_key_lanes(c, key_bytes) \
@@ -578,8 +580,8 @@ def masked_groupby_lanes(key_columns: Sequence[Column],
         spans.append(len(own))
         lanes.extend(own)
     out_lanes, results, num_groups, leftover = masked_groupby(
-        lanes, agg_inputs, num_rows, capacity, None, group_slots, rounds,
-        sweep_unless_leftover=True)
+        lanes, agg_inputs, num_rows, capacity, row_mask, group_slots,
+        rounds, sweep_unless_leftover=True)
     out_keys, at = [], 0
     for c, n in zip(key_columns, spans):
         own = out_lanes[at:at + n]

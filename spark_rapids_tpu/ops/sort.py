@@ -131,8 +131,11 @@ def string_prefix_lanes(col: StringColumn, num_words: int) -> List[jnp.ndarray]:
     return lanes
 
 
-def _max_string_length(offsets):
-    return jnp.max(jnp.stack([jnp.max(o[1:] - o[:-1]) for o in offsets]))
+def _max_string_length(offsets, row_mask=None):
+    lengths = [o[1:] - o[:-1] for o in offsets]
+    if row_mask is not None:
+        lengths = [jnp.where(row_mask, n, 0) for n in lengths]
+    return jnp.max(jnp.stack([jnp.max(n) for n in lengths]))
 
 
 # through the dispatch ledger like every other program: run eagerly the
@@ -142,20 +145,21 @@ _max_string_length_jit = instrument(_max_string_length,
 
 
 def string_key_bytes(columns: Sequence[Column],
-                     ordinals: Sequence[int]) -> int:
+                     ordinals: Sequence[int], row_mask=None) -> int:
     """Byte width making string ordering EXACT for these batches: measures
     the longest string of the key columns on device (one program, one host
     sync, outside jit) and rounds UP to a power of two (1, 2, 4, 8, ...)
     so the static width buckets like capacities do. Goes DOWN to what the
     keys need: CHAR(1) keys sort on one byte each, not on 32. 1 where no
-    key is a string (no sync)."""
+    key is a string (no sync). With `row_mask` only the rows it keeps are
+    measured (a dropped row's length counts as 0)."""
     offsets = tuple(columns[i].offsets for i in ordinals
                     if isinstance(columns[i], StringColumn))
     if not offsets:
         return 1
     from ..obs.dispatch import host_read
     need = max(1, int(host_read("sort.key_width",
-                                _max_string_length_jit(offsets))))
+                                _max_string_length_jit(offsets, row_mask))))
     return 1 << (need - 1).bit_length()
 
 

@@ -2,9 +2,11 @@
 group keys no longer than 16 bytes ride as packed fixed-width lanes through
 the masked-bucket kernel. The oracle is the sort path (`groupby_aggregate`),
 reached by the same exec with its hash path closed; the counters of
-`exec/aggregate.counters()` say which tier answered. The last tests guard
-the compiled text (no loop, no sort, no row-wide scatter) and run TPC-H Q1's
-own plan at the benchmark's rehearsal scale."""
+`exec/aggregate.counters()` say which tier answered. A filter/project chain
+under such a group-by rides into the lane tier as a row mask; those tests
+hold it to the per-operator plan and to numpy. The last tests guard the
+compiled text (no loop, no sort, no row-wide scatter) and run TPC-H Q1's own
+plan at the benchmark's rehearsal scale."""
 
 import math
 import sys
@@ -19,10 +21,11 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import Column, StringColumn
 from spark_rapids_tpu.exec import aggregate
 from spark_rapids_tpu.exec.aggregate import AggregateExec
-from spark_rapids_tpu.exec.basic import InMemoryScanExec
+from spark_rapids_tpu.exec.basic import (FilterExec, InMemoryScanExec,
+                                         ProjectExec)
 from spark_rapids_tpu.expr.aggexprs import (
     Average, Count, First, Max, Min, Sum)
-from spark_rapids_tpu.expr.core import col
+from spark_rapids_tpu.expr.core import col, lit
 from spark_rapids_tpu.ops import maskedagg
 from spark_rapids_tpu.ops.sort import _pack_fields, _unpack_fields
 from spark_rapids_tpu.types import (
@@ -301,6 +304,135 @@ def test_fixed_width_keys_keep_the_one_program_masked_path():
     assert "lanes" not in plan._jit_update_hash
 
 
+# -- a filter/project chain absorbed as a row mask --------------------------------
+
+CUT = 90
+#: Sum, Sum and Count: one buffer each, so a partial's row is its answer
+PARTIAL_AGGS = [(Sum(col("d")), "sum_d"), (Sum(col("v")), "sum_v"),
+                (Count(), "n")]
+
+
+def _stages(plan):
+    """The kinds of the compiled stages in `plan`, the chain an aggregate
+    drives through its `_source` included."""
+    from spark_rapids_tpu.exec.base import TpuExec
+    from spark_rapids_tpu.exec.stage_compiler import CompiledStageExec
+    kinds, todo = [], [plan]
+    while todo:
+        node = todo.pop()
+        if isinstance(node, CompiledStageExec):
+            kinds.append(node._kind)
+        todo.extend(node.children)
+        if isinstance(getattr(node, "_source", None), TpuExec):
+            todo.append(node._source)
+    return kinds
+
+
+def _chain(batches, schema, aggs, mode):
+    """Filter(t <= CUT) -> Project(a, b, v, d * (1 - x) AS d) -> group-by on
+    the two string keys: Q1's plan below its sort."""
+    scan = InMemoryScanExec(batches, schema)
+    filt = FilterExec(col("t") <= lit(CUT), scan)
+    proj = ProjectExec([col("a"), col("b"), col("v"),
+                        (col("d") * (lit(1.0) - col("x"))).alias("d")], filt)
+    return AggregateExec([col("a"), col("b")], aggs, proj, mode=mode)
+
+
+def _chain_data(case, n):
+    rng = np.random.default_rng(len(case))
+    v, d = _values(n, len(case))
+    x = (rng.random(n) * 0.1).tolist()
+    t = rng.integers(0, 100, n).tolist()               # about 91% kept
+    a = [["A", "N", "R"][i] for i in rng.integers(0, 3, n)]
+    b = [["F", "O"][i] for i in rng.integers(0, 2, n)]
+    if case == "null_predicate":
+        t = [None if i % 7 == 0 else ti for i, ti in enumerate(t)]
+    elif case == "dropped_key_longer_than_kept":
+        # 40 bytes: measured over every row, the lane tier would decline
+        a = ["x" * 40 if ti > CUT else ai for ai, ti in zip(a, t)]
+    elif case == "more_groups_than_lanes":
+        # 100 groups: over the lane tier's 2 x 32 slots, 2 hash rounds do
+        a = [f"k{i % 100:02d}" for i in range(n)]
+        b = ["F"] * n
+    elif case == "keeps_nothing":
+        t = [CUT + 1 + ti for ti in t]
+    return {"a": a, "b": b, "t": t, "v": v, "d": d, "x": x}
+
+
+def _chain_by_numpy(data, aggs):
+    """The filter and the product in numpy, the groups by hand."""
+    t = np.array([np.nan if ti is None else ti for ti in data["t"]])
+    kept = np.flatnonzero(t <= CUT)                    # NULL drops the row
+    prod = np.array([np.nan if di is None else di for di in data["d"]]) \
+        * (1.0 - np.array(data["x"]))
+    d = [None if data["d"][i] is None else float(prod[i]) for i in kept]
+    v = [data["v"][i] for i in kept]
+    rows = _by_hand([[data["a"][i] for i in kept],
+                     [data["b"][i] for i in kept]], v, d, Q1_AGGS)
+    if aggs is PARTIAL_AGGS:                           # sum_d, sum_v, n
+        rows = [r[:4] + r[6:] for r in rows]
+    return rows
+
+
+CHAIN_CASES = {
+    # name: (batches, mode, aggregates, the counters that move)
+    "q1_shape": (2, "complete", Q1_AGGS,
+                 # two updates on the mask, and the merge of their partials
+                 dict(hash_updates=3, lane_updates=3, lane_masked_updates=2)),
+    "null_predicate": (1, "complete", Q1_AGGS,
+                       dict(hash_updates=1, lane_updates=1,
+                            lane_masked_updates=1)),
+    "dropped_key_longer_than_kept": (1, "complete", Q1_AGGS,
+                                     dict(hash_updates=1, lane_updates=1,
+                                          lane_masked_updates=1)),
+    "more_groups_than_lanes": (1, "complete", Q1_AGGS,
+                               dict(hash_updates=1, lane_leftovers=1,
+                                    mask_compactions=1)),
+    "keeps_nothing": (1, "complete", Q1_AGGS,
+                      dict(hash_updates=1, lane_updates=1,
+                           lane_masked_updates=1)),
+    "partial_mode": (1, "partial", PARTIAL_AGGS,
+                     dict(hash_updates=1, lane_updates=1,
+                          lane_masked_updates=1)),
+}
+
+
+@pytest.mark.parametrize("case", list(CHAIN_CASES))
+def test_a_filtered_projection_rides_into_the_lane_tier_as_a_mask(case):
+    from spark_rapids_tpu.config import (RapidsConf, active_conf,
+                                         set_active_conf)
+    from spark_rapids_tpu.exec.stage_compiler import compile_stages
+    n_batches, mode, aggs, moved = CHAIN_CASES[case]
+    n = 600
+    schema = _schema(a=STRING, b=STRING, t=INT, v=LONG, d=DOUBLE, x=DOUBLE)
+    data = _chain_data(case, n_batches * n)
+    batches = [ColumnarBatch.from_pydict(
+        {k: vals[i * n:(i + 1) * n] for k, vals in data.items()}, schema)
+        for i in range(n_batches)]
+
+    conf = active_conf()
+    plan = compile_stages(_chain(batches, schema, aggs, mode), conf)
+    assert isinstance(plan, AggregateExec) and plan._lane_ok
+    assert [s[0] for s in plan._fused_steps] == ["filter", "project"]
+    assert _stages(plan) == []                     # no map stage under it
+    before = aggregate.counters()
+    got = _rows(plan)
+    _moved(before, **moved)
+
+    set_active_conf(RapidsConf({"spark.rapids.tpu.fusion.enabled": "false",
+                                "spark.rapids.tpu.stage.fusion.enabled":
+                                    "false"}))
+    try:
+        per_op = _chain(batches, schema, aggs, mode)
+        assert not per_op._fused_steps
+        want = _rows(per_op)
+    finally:
+        set_active_conf(conf)
+    _same(got, want)
+    _same(got, _chain_by_numpy(data, aggs))
+    assert (len(got) == 0) == (case == "keeps_nothing")
+
+
 # -- the lanes themselves -------------------------------------------------------
 
 @pytest.mark.parametrize("key_bytes", [1, 2, 4, 8, 16])
@@ -365,7 +497,8 @@ def test_the_lane_update_holds_no_loop_sort_or_row_wide_scatter():
                  [(Sum(col("q")), "sq"), (Sum(col("p")), "sp"),
                   (Average(col("q")), "aq"), (Average(col("c")), "ac"),
                   (Count(), "n")], [small], schema)
-    pre = plan._jit_pre(small)
+    pre, mask = plan._jit_pre(small)
+    assert mask is None                            # no filter absorbed
     key_bytes = plan._lane_key_bytes(pre)
     assert key_bytes == 1
 
@@ -413,11 +546,14 @@ def test_q1s_plan_takes_the_lane_tier_once_a_query(tmp_path):
     paths = datagen.write_tables(str(tmp_path / "data"), tables,
                                  cfg["schema"], cfg["layout"])
     sess = TpuSession(dict(cfg.get("session_conf", {})))
+    # the date filter and the two products ride into the group-by as its
+    # row mask: no map stage compacts the batch before it
+    assert not _stages(query_mod.build(sess, paths, cfg)._exec())
     before = aggregate.counters()
     rows = query_mod.build(sess, paths, cfg).collect()
-    _moved(before, hash_updates=1, lane_updates=1)
+    _moved(before, hash_updates=1, lane_updates=1, lane_masked_updates=1)
     rows2 = query_mod.build(sess, paths, cfg).collect()
-    _moved(before, hash_updates=2, lane_updates=2)
+    _moved(before, hash_updates=2, lane_updates=2, lane_masked_updates=2)
     assert rows == rows2
 
     answer = ref_mod.reference(tables, cfg)

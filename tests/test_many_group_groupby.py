@@ -84,7 +84,7 @@ def test_the_exact_update_is_exact_at_any_number_of_groups(groups, path):
     keyed, vals = _rows(groups, max(groups + 7, 3 * groups // 2), groups)
     scan = InMemoryScanExec([_batch(keyed, vals)], SCHEMA)
     agg = _agg(FilterExec(col("v") >= lit(0.0), scan) if masked else scan)
-    out = agg._jit_update(agg._jit_pre(scan._batches[0]), 4) \
+    out = agg._jit_update(agg._jit_pre(scan._batches[0])[0], 4) \
         if path == "sort_path" else agg._jit_step_exact(scan._batches[0])
     got = _as_dict(agg._evaluate(out))
     want = _want(keyed, vals, (lambda v: v >= 0.0) if masked

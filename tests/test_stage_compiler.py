@@ -411,6 +411,38 @@ def test_map_stage_fuses_filter_project_chain():
     assert row["dispatches_per_batch"] == 1.0, row
 
 
+def test_a_string_group_by_over_a_fused_stage_drives_that_stage():
+    """A group-by on a string key runs its filter/project chain as a row
+    mask and forms no stage: the rewrite goes on from its `_source`, here a
+    fused `agg` stage of its own, and the group-by drives that stage; the
+    chain between them forms no map stage."""
+    from spark_rapids_tpu.exec.aggregate import AggregateExec
+    from spark_rapids_tpu.types import StringType
+
+    def q(sess):
+        schema = Schema((StructField("k", LONG), StructField("v", LONG)))
+        df = sess.from_pydict({"k": [i % 5 for i in range(200)],
+                               "v": list(range(200))}, schema)
+        inner = (df.filter(col("v") > lit(10))
+                   .group_by("k").agg((Sum(col("v")), "s")))
+        return (inner.filter(col("s") > lit(3900))
+                     .select(col("k").cast(StringType()).alias("ks"),
+                             col("s"))
+                     .group_by("ks").agg((Sum(col("s")), "t"), (Count(), "n"))
+                     .sort("ks"))
+    sess = TpuSession()
+    plan = q(sess)._exec()
+    outer = plan.children[0]
+    assert isinstance(outer, AggregateExec) and outer._lane_ok
+    assert isinstance(outer._source, CompiledStageExec)
+    assert outer._source._kind == "agg"
+    # the absorbed chain's last link leads to the stage too
+    assert outer.children[0].children[0].children[0] is outer._source
+    on = q(sess).collect()
+    assert on == q(TpuSession(OFF)).collect()
+    assert [r[0] for r in on] == ["1", "2", "3", "4"]   # 0's sum is 3885
+
+
 def test_map_stage_expand_fans_out_from_one_program():
     """An expand inside a map chain emits ALL its projections from ONE
     program per input batch (grouping-sets shape)."""
