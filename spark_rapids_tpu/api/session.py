@@ -32,12 +32,26 @@ class _InMemorySource:
         return sum(b.num_rows_host for b in self._batches)
 
 
+def conf_mesh_devices(conf: RapidsConf) -> Optional[int]:
+    """The chips a session's conf asks to span: under
+    `spark.rapids.shuffle.mode=ICI` the shuffle is the resident mesh
+    all-to-all, so the deployment's `spark.rapids.sql.shuffle.partitions`
+    partitions are its chips; None under any other mode."""
+    from ..config import SHUFFLE_MODE, SHUFFLE_PARTITIONS
+    if conf.get(SHUFFLE_MODE).upper() != "ICI":
+        return None
+    return conf.get(SHUFFLE_PARTITIONS)
+
+
 class TpuSession:
     def __init__(self, conf: Optional[Dict] = None,
                  mesh_devices: Optional[int] = None, mesh=None):
         """mesh_devices/mesh: enable distributed planning — group-bys and
         equi-joins compile to partial → ICI all-to-all exchange → final
-        SPMD stages over the device mesh (exec/exchange.py). Default: the
+        SPMD stages over the device mesh (exec/exchange.py). Without
+        either, the conf decides: `spark.rapids.shuffle.mode=ICI` spans a
+        mesh of `spark.rapids.sql.shuffle.partitions` chips, one partition
+        a chip (`conf_mesh_devices`); any other mode plans the
         single-partition plan (no exchange nodes)."""
         from .. import faults
         from ..columnar import upload
@@ -57,6 +71,8 @@ class TpuSession:
         # batchSizeBytes (ISSUE 14 satellite): steady-state scans hit
         # zero grow-on-miss staging allocations
         upload.configure(self.conf)
+        if mesh is None and mesh_devices is None:
+            mesh_devices = conf_mesh_devices(self.conf)
         if mesh is None and mesh_devices is not None:
             mesh = device_mesh(mesh_devices)
         self.mesh = mesh

@@ -182,6 +182,15 @@ def _batch_unflatten(schema, children):
 jax.tree_util.register_pytree_node(ColumnarBatch, _batch_flatten, _batch_unflatten)
 
 
+def committed_device(batch: ColumnarBatch):
+    """The one device a batch's arrays are committed to, or None."""
+    leaves = jax.tree_util.tree_leaves(batch.columns[:1])
+    if not leaves or not getattr(leaves[0], "committed", False):
+        return None
+    devs = leaves[0].devices()
+    return next(iter(devs)) if len(devs) == 1 else None
+
+
 def empty_batch(schema: Schema, capacity: int = 128) -> ColumnarBatch:
     from .column import build_column
     cols = [build_column([], f.data_type, capacity)

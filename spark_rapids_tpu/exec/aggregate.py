@@ -698,14 +698,23 @@ class AggregateExec(TpuExec):
     SHRINK_THRESHOLD_CAP = 1 << 16
 
     def internal_execute(self) -> Iterator[ColumnarBatch]:
+        from .exchange import onto_one_chip, partition_streams
         from .speculation import speculation_allowed
-        if (self._masked_ok and self._spec_enabled
-                and speculation_allowed(self.plan_fingerprint())):
-            yield from self._execute_speculative()
-            return
-        yield from self._execute_exact()
+        drive = self._execute_speculative \
+            if (self._masked_ok and self._spec_enabled
+                and speculation_allowed(self.plan_fingerprint())) \
+            else self._execute_exact
+        # over partitions on chips of their own a partial group-by runs once
+        # a partition, and so does the final one, whose input the exchange
+        # partitioned by its keys (every group is formed on one chip); a
+        # complete one takes every partition's rows on one chip
+        streams = [onto_one_chip(self._source.execute())] \
+            if self.mode == "complete" \
+            else partition_streams(self._source)
+        for stream in streams:
+            yield from drive(stream)
 
-    def _execute_speculative(self) -> Iterator[ColumnarBatch]:
+    def _execute_speculative(self, source) -> Iterator[ColumnarBatch]:
         """Streaming speculative drive: ONE program per source batch folds
         into an O(1)-size device state; the overflow flag is recorded with
         the active speculation scope and never read here."""
@@ -717,7 +726,7 @@ class AggregateExec(TpuExec):
         evaluated = None
         saw_input = False
         with agg_time.ns_timer():
-            for batch in self._source.execute():
+            for batch in source:
                 in_batches.add(1)
                 if batch._host_rows is not None:
                     in_rows.add(batch._host_rows)
@@ -819,7 +828,7 @@ class AggregateExec(TpuExec):
                                        merged.schema)
             aggregated[:] = [SpillableBatch.from_batch(merged)]
 
-    def _execute_exact(self) -> Iterator[ColumnarBatch]:
+    def _execute_exact(self, source) -> Iterator[ColumnarBatch]:
         agg_time = self.metrics[AGG_TIME]
         in_rows = self.metrics[NUM_INPUT_ROWS]
         in_batches = self.metrics[NUM_INPUT_BATCHES]
@@ -829,7 +838,7 @@ class AggregateExec(TpuExec):
         with agg_time.ns_timer():
             first_pass = self._merge_jitted if self.mode == "final" \
                 else self._update_spanned
-            for batch in self._source.execute():
+            for batch in source:
                 in_batches.add(1)
                 if batch._host_rows is not None:
                     in_rows.add(batch._host_rows)

@@ -195,6 +195,12 @@ class TpuExec:
     #: off).
     _encoded_ok_for_parent: bool = False
 
+    #: True where this exec hands out its rows as partitions that each sit
+    #: on a chip of their own (`execute_partitions()`: a mesh exchange, a
+    #: shuffled join over two): a consumer that merges batches merges those
+    #: of one partition (`exchange.partition_streams`)
+    partitions_on_chips: bool = False
+
     def __init__(self, *children: "TpuExec"):
         self.children: List[TpuExec] = list(children)
         self._op_id = next(_OP_IDS)

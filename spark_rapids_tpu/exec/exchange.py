@@ -18,14 +18,15 @@ the data and fixed-width string lanes can never truncate.
 
 from __future__ import annotations
 
-from typing import Iterator, List, Optional, Sequence
+from typing import Iterable, Iterator, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..columnar.batch import ColumnarBatch, empty_batch
-from ..columnar.column import StringColumn, bucket_capacity
+from ..columnar.batch import ColumnarBatch, committed_device, empty_batch
+from ..columnar.column import (Column, StringColumn, StructColumn,
+                               bucket_capacity)
 from ..expr.core import Expression
 from ..ops.basic import active_mask
 from ..ops.strings import string_lengths
@@ -56,6 +57,33 @@ def _squeeze0(tree):
 
 def _expand0(tree):
     return jax.tree_util.tree_map(lambda x: x[None], tree)
+
+
+def _stack_piece(batch):
+    """A device's batch as its piece of the mesh program's input (a leading
+    axis of 1), on the chip the batch sits on."""
+    return _expand0(batch)
+
+
+def _head(col, cap: int):
+    """A column's first `cap` rows (the exchange compacts its rows to the
+    front): its lanes sliced, a string column's bytes left as they are."""
+    if isinstance(col, StringColumn):
+        return StringColumn(col.data, col.offsets[:cap + 1],
+                            col.validity[:cap], col.dtype)
+    if isinstance(col, StructColumn):
+        return type(col)(tuple(_head(k, cap) for k in col.children),
+                         col.validity[:cap], col.dtype)
+    return Column(col.data[:cap], col.validity[:cap], col.dtype)
+
+
+def _unstack_shard(shard, cap: int):
+    """A device's shard of the mesh program's output as a batch of its own,
+    on that device, in the bucket of `cap` rows (every partition's rows
+    fit: the caller sizes it to the largest)."""
+    local = _squeeze0(shard)
+    return ColumnarBatch([_head(c, cap) for c in local.columns],
+                         local.num_rows, local.schema)
 
 
 #: where the collective steps' output shards sat, process-cumulative:
@@ -117,15 +145,92 @@ def _host_key_array(col, n: int, idx=None):
     return None
 
 
+#: what the mesh exchanges moved, process-cumulative (`counters()`): rounds
+#: run; rows in; rows whose partition is not the chip they came from, and
+#: the bytes of their lanes in the all-to-all; summed over exchange
+#: executions, each one's largest partition in rows and its partition count;
+#: and what consumers that merge partitions moved to one chip
+#: (`onto_one_chip`): batches, their row slots and their bytes
+_COUNTERS = {"rounds": 0, "rows_in": 0, "rows_off_chip": 0,
+             "bytes_off_chip": 0, "max_partition_rows": 0, "partitions": 0,
+             "batches_moved": 0, "rows_moved": 0, "bytes_moved": 0}
+
+
+def counters() -> dict:
+    return dict(_COUNTERS)
+
+
+def reset_counters() -> None:
+    for k in _COUNTERS:
+        _COUNTERS[k] = 0
+
+
+def _lane_bytes_per_row(batch: ColumnarBatch, width: int) -> int:
+    """Bytes one row's slot carries through the all-to-all: each column's
+    data (a string as its fixed-width padding and its length) and validity,
+    and the slot's occupancy flag."""
+    import numpy as np
+    total = 1
+    for c in batch.columns:
+        if isinstance(c, StringColumn):
+            total += width + 4 + 1
+            continue
+        for leaf in jax.tree_util.tree_leaves(c):
+            total += leaf.dtype.itemsize * int(np.prod(leaf.shape[1:]))
+    return total
+
+
+def partition_streams(node: TpuExec) -> Iterator[Iterator[ColumnarBatch]]:
+    """`node`'s output as one batch stream per partition where its
+    partitions sit on chips of their own, so that a consumer that merges
+    batches merges those of one chip; else its one stream."""
+    if node.partitions_on_chips:
+        yield from node.execute_partitions()
+    else:
+        yield node.execute()
+
+
+def onto_one_chip(batches: Iterable[ColumnarBatch]
+                  ) -> Iterator[ColumnarBatch]:
+    """The batches on the chip of the first one that sits on a single
+    chip, for a consumer that merges partitions on chips of their own in
+    one program (the top-N's last selection, a `complete` group-by over
+    partitions): no program takes arrays of two chips, so each batch that
+    sits elsewhere is moved there and counted (`batches_moved`, its
+    capacity in `rows_moved`, `bytes_moved`). Batches on no single chip
+    pass as they are, and so does everything outside a mesh session."""
+    mesh = active_mesh()
+    if mesh is None or mesh.size < 2:
+        yield from batches
+        return
+    to = None
+    for b in batches:
+        at = committed_device(b)
+        if to is None:
+            to = at
+        elif at is not None and at != to:
+            _COUNTERS["batches_moved"] += 1
+            _COUNTERS["rows_moved"] += b.capacity
+            _COUNTERS["bytes_moved"] += sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(b))
+            b = ColumnarBatch(*jax.device_put((b.columns, b.num_rows), to),
+                              b.schema, b._host_rows)
+        yield b
+
+
 class ShuffleExchangeExec(TpuExec):
     """Hash-repartition child output across the mesh so rows with equal
     partition-key values colocate on one device shard.
 
     With no active mesh (or a 1-device mesh) the exchange is the identity —
-    the single-partition plan needs no data movement. Otherwise the flat
-    stream yields each shard's staged PIECES in partition order (round 5:
-    one piece at a time, a skewed shard is never concatenated whole);
-    consumers that need partition boundaries use execute_partitions()."""
+    the single-partition plan needs no data movement. Otherwise each round
+    runs ONE measure program and ONE all-to-all step over the mesh, and
+    partition i comes out as arrays on mesh device i alone (its shards of
+    the step's output), so every program downstream runs on that one chip.
+    The flat stream yields each partition's staged PIECES in partition
+    order (round 5: one piece at a time, a skewed shard is never
+    concatenated whole); consumers that merge batches take partition
+    boundaries from execute_partitions() (`partition_streams`)."""
 
     def __init__(self, partition_exprs: Sequence[Expression], child: TpuExec,
                  mesh=None):
@@ -134,10 +239,22 @@ class ShuffleExchangeExec(TpuExec):
         self._mesh = mesh if mesh is not None else active_mesh()
         self._bound = bind_projection(self.partition_exprs,
                                       child.output_schema)
-        self._jit_measure = instrument(
-            self._measure_kernel,
-            label="ShuffleExchangeExec.measure", owner=self)
         self._steps = {}
+        # program sites, built last: the plan fingerprint they key on sees
+        # the final fields. The per-device pieces in and out of the mesh
+        # programs are programs of their own, run on the piece's chip.
+        self._jit_stack = self._site(_stack_piece,
+                                     label="ShuffleExchangeExec.stack")
+        self._jit_unstack = self._site(_unstack_shard,
+                                       label="ShuffleExchangeExec.unstack",
+                                       static_argnums=(1,))
+        self._jit_measure = None
+        if self.n_partitions > 1:
+            from ..parallel.mesh import shard_map_compat
+            self._jit_measure = self._site(shard_map_compat(
+                self._measure_kernel, mesh=self._mesh,
+                in_specs=P(DATA_AXIS), out_specs=P(DATA_AXIS)),
+                label="ShuffleExchangeExec.measure")
 
     @property
     def output_schema(self) -> Schema:
@@ -147,6 +264,16 @@ class ShuffleExchangeExec(TpuExec):
         return ((NUM_INPUT_BATCHES, DEBUG), (NUM_INPUT_ROWS, DEBUG),
                 (PARTITION_SIZE, ESSENTIAL)) + PIPELINE_STAGE_METRICS \
             + DISPATCH_METRICS
+
+    def _fingerprint_extras(self):
+        # what the traced programs depend on beyond the child subtree: the
+        # bound keys, the partition count and the mesh the collectives
+        # run over (its axis and its devices, in order)
+        mesh = self._mesh
+        devices = () if mesh is None else \
+            (mesh.axis_names, tuple(d.id for d in mesh.devices.flat))
+        return ("mesh_exchange", self.n_partitions,
+                tuple(b.semantic_key() for b in self._bound), devices)
 
     @property
     def runs_own_pipeline_stage(self) -> bool:
@@ -158,35 +285,34 @@ class ShuffleExchangeExec(TpuExec):
     def n_partitions(self) -> int:
         return 1 if self._mesh is None else mesh_axis_size(self._mesh)
 
+    @property
+    def partitions_on_chips(self) -> bool:
+        return self.n_partitions > 1
+
     # -- kernels -----------------------------------------------------------
     def _local_pid(self, local: ColumnarBatch, n: int):
         keys = [e.columnar_eval(local) for e in self._bound]
         return partition_ids(keys, local.num_rows, local.capacity, n)
 
     def _measure_kernel(self, stacked):
-        """Per-device partition histogram + max string byte length. Runs
-        vmapped over the device axis (it is pure per-device measurement —
-        no collective), one host sync for both scalars."""
+        """Per device (inside shard_map, like the step: no program sees a
+        mesh-sharded operand it must partition by itself): the rows this
+        device sends to each partition and its longest string in bytes.
+        One host read of both sizes the round's receive slots."""
         n = self.n_partitions
-
-        def per_dev(local: ColumnarBatch):
-            pid = self._local_pid(local, n)
-            ones = jnp.where(pid < n, jnp.int32(1), jnp.int32(0))
-            counts = jax.ops.segment_sum(ones, pid.astype(jnp.int32),
-                                         num_segments=n + 1)
-            max_count = jnp.max(counts[:n])
-            max_len = jnp.int32(0)
-            act = active_mask(local.num_rows, local.capacity)
-            for c in local.columns:
-                if isinstance(c, StringColumn):
-                    lens = string_lengths(c)
-                    max_len = jnp.maximum(
-                        max_len, jnp.max(jnp.where(act, lens, 0)))
-            return max_count, max_len, counts[:n]
-
-        max_count, max_len, totals = jax.vmap(per_dev)(stacked)
-        return jnp.max(max_count), jnp.max(max_len), jnp.sum(totals,
-                                                             axis=0)
+        local = _squeeze0(stacked)
+        pid = self._local_pid(local, n)
+        ones = jnp.where(pid < n, jnp.int32(1), jnp.int32(0))
+        counts = jax.ops.segment_sum(ones, pid.astype(jnp.int32),
+                                     num_segments=n + 1)[:n]
+        max_len = jnp.int32(0)
+        act = active_mask(local.num_rows, local.capacity)
+        for c in local.columns:
+            if isinstance(c, StringColumn):
+                lens = string_lengths(c)
+                max_len = jnp.maximum(max_len,
+                                      jnp.max(jnp.where(act, lens, 0)))
+        return counts[None], max_len[None]
 
     def _get_step(self, cap: int, slot_cap: int, width: int):
         key = (cap, slot_cap, width)
@@ -196,7 +322,7 @@ class ShuffleExchangeExec(TpuExec):
         n = self.n_partitions
         schema = self.output_schema
 
-        def spmd(stacked):
+        def _exchange_step(stacked):
             local = _squeeze0(stacked)
             pid = self._local_pid(local, n)
             cols, n_recv = exchange_columns(
@@ -206,44 +332,83 @@ class ShuffleExchangeExec(TpuExec):
             return _expand0(ColumnarBatch(cols, n_recv, schema))
 
         from ..parallel.mesh import shard_map_compat
-        step = instrument(shard_map_compat(
-            spmd, mesh=self._mesh, in_specs=P(DATA_AXIS),
+        step = self._site(shard_map_compat(
+            _exchange_step, mesh=self._mesh, in_specs=P(DATA_AXIS),
             out_specs=P(DATA_AXIS)),
-            label="ShuffleExchangeExec.exchange_step", owner=self)
+            "ShuffleExchangeExec.exchange_step", key_salt=key)
         self._steps[key] = step
         return step
 
-    def _exchange_round(self, batches: List[ColumnarBatch]):
-        """One SPMD exchange over a bounded group of input batches;
-        returns the n received shard batches."""
-        from ..parallel.distributed import stack_batches, unstack_batches
+    def _round_inputs(self, batches: List[ColumnarBatch]):
+        """The round's batches grouped one group a device: each by the
+        chip it sits on where they sit on several (a partitioned
+        producer's output stays where it is), else round-robin (ingest
+        lands on one chip). A device without a batch gets an empty one,
+        made on that device."""
         n = self.n_partitions
+        devices = list(self._mesh.devices.flat)
+        where = [committed_device(b) for b in batches]
+        if all(w in devices for w in where) and len(set(where)) > 1:
+            groups = [[b for b, w in zip(batches, where) if w == d]
+                      for d in devices]
+        else:
+            groups = [batches[d::n] for d in range(n)]
         schema = self.output_schema
-        groups = [batches[d::n] for d in range(n)]
         per_dev = []
-        for g in groups:
+        for d, g in enumerate(groups):
             if not g:
-                per_dev.append(empty_batch(schema))
+                per_dev.append(jax.device_put(empty_batch(schema),
+                                              devices[d]))
             elif len(g) == 1:
                 per_dev.append(g[0])
             else:
                 per_dev.append(concat_batches(g, schema))
-        cap = max(b.capacity for b in per_dev)
-        per_dev = [b.sized_to(cap) for b in per_dev]
-        stacked = stack_batches(per_dev)
+        return per_dev
 
-        max_count, max_len, totals = host_read(
-            "exchange.round", self._jit_measure(stacked))
-        # one host sync per ROUND: size the receive buffer to the
-        # measured max partition load, and string lanes to the measured
-        # max byte length (truncation structurally impossible)
-        slot_cap = negotiate_slot_cap(int(max_count), cap)
-        width = max(8, (int(max_len) + 7) // 8 * 8)
-
-        out = self._get_step(cap, slot_cap, width)(stacked)
-        _note_exchange_placement(out)
-        import numpy as _np
-        return list(unstack_batches(out, n)), _np.asarray(totals)
+    def _exchange_round(self, batches: List[ColumnarBatch]):
+        """One SPMD exchange over a bounded group of input batches;
+        returns the n received partitions, partition i on mesh device i
+        alone, and the round's rows per partition."""
+        import numpy as np
+        from ..parallel.distributed import (align_batches, device_shards,
+                                            place_on_mesh)
+        n = self.n_partitions
+        schema = self.output_schema
+        with op_span("exchange.mesh_round", phase="ici-collective"):
+            per_dev = align_batches(self._round_inputs(batches))
+            cap = per_dev[0].capacity
+            stacked = place_on_mesh([self._jit_stack(b) for b in per_dev],
+                                    self._mesh)
+            counts, max_len = host_read("exchange.round",
+                                        self._jit_measure(stacked))
+            # one host sync per ROUND: size the receive buffer to the
+            # measured max partition load, and string lanes to the
+            # measured max byte length (truncation structurally
+            # impossible)
+            counts = np.asarray(counts)
+            slot_cap = negotiate_slot_cap(int(counts.max()), cap)
+            width = max(8, (int(np.max(max_len)) + 7) // 8 * 8)
+            out = self._get_step(cap, slot_cap, width)(stacked)
+            _note_exchange_placement(out)
+            totals = counts.sum(axis=0)
+            # every row of partition i lands on device i (the slots hold
+            # the largest load), so the host knows each partition's rows,
+            # and the partitions leave the receive grid (n x slot_cap
+            # rows) in the bucket of the largest
+            part_cap = min(bucket_capacity(max(int(totals.max()), 1)),
+                           n * slot_cap)
+            shards = [ColumnarBatch(s.columns, s.num_rows, schema,
+                                    int(totals[i]))
+                      for i, s in enumerate(
+                          self._jit_unstack(t, part_cap)
+                          for t in device_shards(out, self._mesh))]
+        off_chip = int(totals.sum() - np.trace(counts))
+        _COUNTERS["rounds"] += 1
+        _COUNTERS["rows_in"] += int(totals.sum())
+        _COUNTERS["rows_off_chip"] += off_chip
+        _COUNTERS["bytes_off_chip"] += off_chip * _lane_bytes_per_row(
+            per_dev[0], width)
+        return shards, totals
 
     # -- drive -------------------------------------------------------------
     def internal_execute(self) -> Iterator[ColumnarBatch]:
@@ -374,6 +539,8 @@ class ShuffleExchangeExec(TpuExec):
         flush()
         if self._part_totals is not None:
             max_part = int(self._part_totals.max())
+            _COUNTERS["max_partition_rows"] += max_part
+            _COUNTERS["partitions"] += n
             self.metrics[PARTITION_SIZE].add(max_part)
             obs_events.emit("exchange", exec="ShuffleExchangeExec",
                             op_id=self._op_id, partitions=self.n_partitions,
@@ -1653,7 +1820,26 @@ class ShuffledHashJoinExec(TpuExec):
     def output_schema(self) -> Schema:
         return self._join.output_schema
 
+    @property
+    def partitions_on_chips(self) -> bool:
+        # both sides come from mesh exchanges: partition p of each sits on
+        # chip p, and so does what the join makes of them
+        return all(c.partitions_on_chips for c in self.children)
+
+    def _fingerprint_extras(self):
+        # the per-partition join's own programs, keyed by its fingerprint
+        # (its leaves are replay scans, which depend on their schema alone)
+        inner = self._join.plan_fingerprint()
+        return None if inner is None else ("shuffled_hash_join", inner)
+
     def internal_execute(self) -> Iterator[ColumnarBatch]:
+        for part in self.execute_partitions():
+            yield from part
+
+    def execute_partitions(self) -> Iterator[Iterator[ColumnarBatch]]:
+        """One lazy batch-generator per partition pair, in partition
+        order; each must be drained before the next starts (one inner
+        join serves them all)."""
         # lazy zip over PARTITION STREAMS: only one partition pair is
         # resident at a time, and within it the stream side's pieces
         # flow through the inner join one batch at a time (round 5 —
@@ -1703,7 +1889,7 @@ class ShuffledHashJoinExec(TpuExec):
                     else:
                         self._lscan._batches = batches
                         self._rscan.set_stream(probe)
-                    yield from self._join.execute()
+                    yield self._join.execute()
                     return
         if build_gens is None:
             lit_ = self.children[0].execute_partitions()
@@ -1722,13 +1908,17 @@ class ShuffledHashJoinExec(TpuExec):
                     "both sides must use the same partitioning")
             if lp is None:
                 return
-            if build_right:
-                self._lscan.set_stream(lp)
-                self._rscan._batches = list(rp)
-            else:
-                self._lscan._batches = list(lp)
-                self._rscan.set_stream(rp)
-            yield from self._join.execute()
+            yield self._join_partition(lp, rp, build_right)
+
+    def _join_partition(self, lp, rp, build_right: bool
+                        ) -> Iterator[ColumnarBatch]:
+        if build_right:
+            self._lscan.set_stream(lp)
+            self._rscan._batches = list(rp)
+        else:
+            self._lscan._batches = list(lp)
+            self._rscan.set_stream(rp)
+        yield from self._join.execute()
 
     def node_description(self):
         return f"ShuffledHashJoinExec[{self.join_type}]"
@@ -1749,6 +1939,11 @@ class _ReplayScanExec(TpuExec):
     def set_stream(self, gen) -> None:
         self._stream = gen
         self._batches = []
+
+    def _fingerprint_extras(self):
+        # what it replays is data: the programs over it depend on the
+        # schema alone, which the fingerprint holds
+        return ()
 
     @property
     def output_schema(self) -> Schema:

@@ -200,10 +200,15 @@ class SortExec(TpuExec):
             s.release()
 
     def _merge(self, runs: List[SpillableBatch]) -> ColumnarBatch:
+        from .exchange import onto_one_chip
+
         def do(items):
             batches = [s.get_batch() for s in items]
             try:
-                merged = concat_batches(batches, self.output_schema)
+                # the runs of partitions on chips of their own (under a
+                # limit, each one's first rows) meet on one chip
+                merged = concat_batches(list(onto_one_chip(batches)),
+                                        self.output_schema)
                 return self._sort_one(merged)
             finally:
                 for s in items:

@@ -77,11 +77,16 @@ class SpeculationScope:
         that tripped are remembered (`known_to_trip`)."""
         if not self.flags:
             return False
+        import jax
         import jax.numpy as jnp
 
         from ..obs.dispatch import host_read
         flags = self.drain()
-        hit = host_read("spec.flags", jnp.stack([f for f, _ in flags]))
+        values = [f for f, _ in flags]
+        if len({d for f in values for d in f.devices()}) > 1:
+            # partitions of a mesh exchange flag on chips of their own
+            values = jax.device_put(values, next(iter(values[0].devices())))
+        hit = host_read("spec.flags", jnp.stack(values))
         for (_, owner), h in zip(flags, hit):
             if h and owner is not None:
                 _note_tripped(owner)

@@ -559,19 +559,37 @@ class CompiledStageExec(TpuExec):
                                    split_policy=split_in_half_by_rows))
 
     def _execute_agg(self):
+        """The fused group-by over the source's one stream, or, as the
+        per-operator one (`AggregateExec.internal_execute`), once a
+        partition where the source hands out partitions on chips of their
+        own and the group-by is not `complete`."""
+        from .aggregate import _note as note_agg
+        from .exchange import onto_one_chip, partition_streams
+        note_agg(executions=1)
+        streams = [onto_one_chip(self.children[0].execute())] \
+            if self._agg.mode == "complete" \
+            else partition_streams(self.children[0])
+        saw = False
+        for stream in streams:
+            for out in self._agg_over(stream):
+                saw = True
+                yield out
+        if not saw:
+            yield _FALLBACK
+
+    def _agg_over(self, stream):
+        """One stream's group-by; nothing where the stream is empty."""
         from ..memory.spillable import SpillableBatch
         from .speculation import current_scope
-        from .aggregate import _note as note_agg
         agg = self._agg
         live = self._live_stats
         spec = self._spec_allowed()
-        note_agg(executions=1)
         saw = False
-        n_in = 0
+        n_in = live[0]
         if spec:
             state, flag = self._fresh_state()
             ev = None
-            for batch in self.children[0].execute():
+            for batch in stream:
                 saw = True
                 n_in += 1
                 live[0] = n_in
@@ -595,7 +613,6 @@ class CompiledStageExec(TpuExec):
                     sp.close()
                 state, flag, ev = box
             if not saw:
-                yield _FALLBACK
                 return
             scope = current_scope()
             if scope is not None:
@@ -609,7 +626,7 @@ class CompiledStageExec(TpuExec):
             parts: List = []
             n_parts = 0
             last_ev = None
-            for batch in self.children[0].execute():
+            for batch in stream:
                 saw = True
                 n_in += 1
                 live[0] = n_in
@@ -638,7 +655,6 @@ class CompiledStageExec(TpuExec):
             if not saw:
                 for p in parts:
                     p.close()
-                yield _FALLBACK
                 return
             yield self._finish_exact(
                 parts, last_ev if n_parts == 1 else None)

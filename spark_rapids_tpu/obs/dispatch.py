@@ -45,6 +45,16 @@ A read outside every other span accrues to phase `host-sync`; inside
 one (`agg.update`, `result.fetch`, ...) that span's phase keeps it, the
 rule `note_dispatch` applies to `device-compute`.
 
+Compiles ahead (mesh sessions): XLA compiles a program once per device
+it runs on. When a dispatch traces a program whose array arguments are
+all committed to one chip of the active mesh (downstream of a mesh
+exchange: partition i on chip i; ingest leaves its arrays uncommitted),
+the same program is compiled for the mesh's other chips on background
+threads (`_compile_ahead`), and a later dispatch on one of them waits for
+that compile instead of starting its own (`_await_ahead`). Partition 0's
+programs then compile once on the dispatching thread while the other
+chips' copies compile beside them, instead of four compiles in a row.
+
 Cost discipline: `spark.rapids.tpu.dispatch.ledger.enabled` defaults
 ON (the ledger is host-side bookkeeping, ~one dict update per program
 dispatch — noise against jit dispatch overhead); explicitly false =
@@ -69,7 +79,7 @@ __all__ = [
     "configure", "reset_dispatch_ledger", "counters", "programs",
     "health_section", "metric_scope", "site_cache_counters",
     "reset_site_cache", "module_labels", "host_read", "sync_sites",
-    "in_host_read",
+    "in_host_read", "ahead_counters",
 ]
 
 #: canonical per-exec metric names (exec/base.py re-exports them into
@@ -255,9 +265,12 @@ class DispatchLedger:
                                        self.timeout_ms)
             _tls.pending = pend
             try:
-                return site._jit(*args, **kwargs)
+                out = site._jit(*args, **kwargs)
             finally:
                 _tls.pending = None
+            if pend.traced:
+                _compile_ahead(site, args, kwargs)
+            return out
         finally:
             if pend.traced and site_first:
                 site._seen_buckets.add(bucket)
@@ -643,6 +656,147 @@ def reset_site_cache() -> None:
         _site_cache_misses = 0
 
 
+_ahead_lock = threading.Lock()
+_ahead_pool = None
+_ahead_counts = {"started": 0, "awaited": 0}
+
+
+def _ahead_executor():
+    global _ahead_pool
+    with _ahead_lock:
+        if _ahead_pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+            _ahead_pool = ThreadPoolExecutor(
+                max_workers=8, thread_name_prefix="compile-ahead")
+        return _ahead_pool
+
+
+def ahead_counters() -> Dict[str, int]:
+    """Compiles started ahead for other chips of a mesh, and dispatches
+    that waited for one."""
+    return dict(_ahead_counts)
+
+
+def _one_chip_signature(site: "InstrumentedJit", args, kwargs):
+    """(signature, device) of a dispatch whose committed array arguments
+    all sit on one device (uncommitted ones follow them there), else None.
+    The signature is what the jit cache keys a trace on: static arguments
+    whole, every other leaf by shape, dtype and weak type (a Python scalar
+    by its type)."""
+    import jax
+    import numpy as np
+    dev = None
+
+    def leaf(x):
+        nonlocal dev
+        if isinstance(x, jax.Array):
+            if not x.committed:
+                return ("u", x.shape, x.dtype, x.weak_type)
+            # the sharding, not `devices()`: a donated argument is deleted
+            # by the time a dispatch that traced looks at it
+            devs = x.sharding.device_set
+            if len(devs) != 1:
+                raise LookupError
+            (d,) = devs
+            if dev is None:
+                dev = d
+            elif d != dev:
+                raise LookupError
+            return ("a", x.shape, x.dtype, x.weak_type)
+        if isinstance(x, np.ndarray):
+            return ("n", x.shape, x.dtype)
+        return ("p", type(x))
+
+    def dynamic(a):
+        leaves, tree = jax.tree_util.tree_flatten(a)
+        return tree, tuple(leaf(x) for x in leaves)
+
+    try:
+        sig = (tuple(("s", a) if i in site._static_nums else dynamic(a)
+                     for i, a in enumerate(args)),
+               tuple(sorted((k, ("s", v) if k in site._static_names
+                             else dynamic(v)) for k, v in kwargs.items())))
+        hash(sig)
+    except (LookupError, TypeError):
+        return None
+    return None if dev is None else (sig, dev)
+
+
+def _compile_ahead(site: "InstrumentedJit", args, kwargs) -> None:
+    """After `site` traced a program for one chip of the active mesh:
+    compile it for the mesh's other chips on background threads. Never
+    raises: a compile that fails here is made again by its dispatch."""
+    try:
+        from ..parallel.mesh import active_mesh
+        mesh = active_mesh()
+        if mesh is None or mesh.size < 2:
+            return
+        found = _one_chip_signature(site, args, kwargs)
+        if found is None:
+            return
+        sig, here = found
+        devices = list(mesh.devices.flat)
+        if here not in devices:
+            return
+        import jax
+        from jax.sharding import SingleDeviceSharding
+        ahead = site._ahead if site._ahead is not None else {}
+        for d in devices:
+            if d == here or (sig, d.id) in ahead:
+                continue
+
+            def spec(x, d=d):
+                if not isinstance(x, jax.Array):
+                    return x
+                return jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, weak_type=x.weak_type,
+                    sharding=SingleDeviceSharding(d) if x.committed else None)
+            a = tuple(x if i in site._static_nums
+                      else jax.tree_util.tree_map(spec, x)
+                      for i, x in enumerate(args))
+            k = {n: v if n in site._static_names
+                 else jax.tree_util.tree_map(spec, v)
+                 for n, v in kwargs.items()}
+            # contract: ok thread-adopt — the thread lowers and compiles a
+            # program the dispatching thread has traced already (the jit
+            # cache holds its trace): no engine code runs there
+            ahead[(sig, d.id)] = _ahead_executor().submit(
+                _compile_for, site, a, k)
+            _ahead_counts["started"] += 1
+        site._ahead = ahead or None
+    except Exception:  # noqa: BLE001 — the dispatch compiles for itself
+        return
+
+
+def _compile_for(site: "InstrumentedJit", args, kwargs) -> None:
+    try:
+        site._jit.lower(*args, **kwargs).compile()
+    except Exception:  # noqa: BLE001 — the dispatch compiles for itself
+        pass
+
+
+def _await_ahead(site: "InstrumentedJit", args, kwargs) -> None:
+    """Before a dispatch of `site`: wait for the compile started ahead for
+    this signature on this chip, if one is running, rather than start a
+    second. Finished compiles are dropped, so a site whose compiles are
+    all done pays nothing more."""
+    ahead = site._ahead
+    for key in [k for k, f in list(ahead.items()) if f.done()]:
+        ahead.pop(key, None)
+    if not ahead:
+        site._ahead = None
+        return
+    found = _one_chip_signature(site, args, kwargs)
+    if found is None:
+        return
+    fut = ahead.pop((found[0], found[1].id), None)
+    if fut is not None:
+        _ahead_counts["awaited"] += 1
+        # contract: ok bounded-wait — the wait is the XLA compile this
+        # dispatch would otherwise run itself, on this thread
+        fut.result()
+
+
 def _no_trace_in_progress() -> bool:
     """True when no trace is in progress on this thread: a call made
     while another program is being traced (jit, eval_shape, a pallas
@@ -668,7 +822,8 @@ class InstrumentedJit:
     # __weakref__: jax.eval_shape weakly caches the callable it is
     # given — an un-weakref-able wrapper would reject abstract eval
     __slots__ = ("label", "module", "_owner", "_jit", "_donate",
-                 "_seen_buckets", "__weakref__")
+                 "_seen_buckets", "_static_nums", "_static_names", "_ahead",
+                 "__weakref__")
 
     def __init__(self, fn, label: str, owner=None, **jit_kwargs):
         import jax
@@ -683,6 +838,16 @@ class InstrumentedJit:
         #: program (first trace of a bucket here) from shape churn (a
         #: re-trace the site's own jit cache rejected)
         self._seen_buckets = set()
+        #: what the jit cache keys whole, and the compiles started for
+        #: other chips of a mesh ((signature, device id) -> future), None
+        #: while none is pending (`_compile_ahead`)
+        static = jit_kwargs.get("static_argnums", ()) or ()
+        self._static_nums = frozenset(
+            (static,) if isinstance(static, int) else static)
+        names = jit_kwargs.get("static_argnames", ()) or ()
+        self._static_names = frozenset(
+            (names,) if isinstance(names, str) else names)
+        self._ahead = None
 
         @functools.wraps(fn)
         def _traced(*a, **k):
@@ -771,6 +936,8 @@ class InstrumentedJit:
             # traced inline into an un-instrumented outer program, or
             # abstractly evaluated (eval_shape) — not a device dispatch
             return self._jit(*args, **kwargs)
+        if self._ahead is not None:
+            _await_ahead(self, args, kwargs)
         return led.dispatch(self, args, kwargs)
 
 

@@ -66,18 +66,23 @@ def _use_pallas() -> bool:
     open `pallas_hash` circuit breaker (exec/lifecycle.FAMILY_DOMAINS
     entry for the `murmur3` family, ISSUE 8) demotes NEW traces to the
     XLA formulation."""
-    from ..config import PALLAS_ENABLED, active_conf
+    from ..config import PALLAS_ENABLED, SHUFFLE_ICI_ENABLED, active_conf
     from ..parallel.mesh import active_mesh
     from .pallas_kernels import on_tpu
-    if not (on_tpu() and active_conf().get(PALLAS_ENABLED)):
+    conf = active_conf()
+    if not (on_tpu() and conf.get(PALLAS_ENABLED)):
         return False
-    # A mesh session's per-partition programs take shards of mesh-sharded
-    # arrays, so XLA partitions them — and "Mosaic kernels cannot be
-    # automatically partitioned" (the four-chip run of PR 23 died in the
-    # shuffled join's build). Until shards are single-device arrays, a
-    # mesh of more than one device hashes with the XLA formulation.
+    # "Mosaic kernels cannot be automatically partitioned" (a four-chip
+    # run died so in the shuffled join's build): a program that takes
+    # a mesh-sharded operand outside shard_map must hash with XLA. The
+    # mesh exchange's programs are shard_map bodies and hand each
+    # partition on as arrays of its own chip, so a mesh session's programs
+    # see no such operand; the host exchange's ICI lane still hands its
+    # partitions on as shards of the mesh-sharded output (the second
+    # exchange, ROADMAP Queue 3 item 7), so with that lane on a mesh of
+    # more than one device hashes with the XLA formulation.
     mesh = active_mesh()
-    if mesh is not None and mesh.size > 1:
+    if mesh is not None and mesh.size > 1 and conf.get(SHUFFLE_ICI_ENABLED):
         return False
     from ..exec import lifecycle
     if not lifecycle.breaker_allows(lifecycle.FAMILY_DOMAINS["murmur3"]):

@@ -30,6 +30,13 @@ def stack_batches(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
     """Stack n same-capacity batches along a new leading device axis; the
     result's leaves have shape (n, ...) ready for shard_map over 'data'.
     Row and string-byte buckets are aligned across batches first."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                  *align_batches(batches))
+
+
+def align_batches(batches: Sequence[ColumnarBatch]) -> List[ColumnarBatch]:
+    """The batches in one row bucket and, column by column, one string-byte
+    bucket: pieces of one stacked array must have equal shapes."""
     cap = max(b.capacity for b in batches)
     batches = [b.sized_to(cap) for b in batches]
     aligned = []
@@ -43,7 +50,34 @@ def stack_batches(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
                 if isinstance(c, StringColumn) else c
                 for i, c in enumerate(b.columns)]
         aligned.append(b.with_columns(cols, b.schema))
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *aligned)
+    return aligned
+
+
+def place_on_mesh(pieces: Sequence, mesh: Mesh, axis_name: str = DATA_AXIS):
+    """One tree of global arrays sharded over `axis_name` from per-device
+    trees whose leaves have a leading axis of 1: piece i becomes mesh
+    device i's shard, moved there where it sits elsewhere and left in
+    place (no copy) where it is there already."""
+    devs = list(mesh.devices.flat)
+    sharding = NamedSharding(mesh, P(axis_name))
+    placed = [jax.device_put(p, d) for p, d in zip(pieces, devs)]
+
+    def glue(*xs):
+        shape = (sum(x.shape[0] for x in xs),) + xs[0].shape[1:]
+        return jax.make_array_from_single_device_arrays(shape, sharding,
+                                                        list(xs))
+    return jax.tree_util.tree_map(glue, *placed)
+
+
+def device_shards(tree, mesh: Mesh) -> List:
+    """For each device of the mesh, in mesh order, the tree of that
+    device's own shards of `tree`'s sharded leaves: single-device arrays on
+    that device, the shard's buffer itself (no copy, nothing through the
+    host)."""
+    def own(x, d):
+        return next(s.data for s in x.addressable_shards if s.device == d)
+    return [jax.tree_util.tree_map(lambda x, d=d: own(x, d), tree)
+            for d in mesh.devices.flat]
 
 
 def _squeeze0(tree):

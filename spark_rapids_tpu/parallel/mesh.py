@@ -31,8 +31,9 @@ def device_mesh(n_devices: Optional[int] = None,
     exchanges ride this axis as all-to-all collectives."""
     devs = jax.devices()
     if n_devices is not None:
-        assert len(devs) >= n_devices, \
-            f"need {n_devices} devices, have {len(devs)}"
+        if len(devs) < n_devices:
+            raise ValueError(f"a mesh of {n_devices} devices was asked for; "
+                             f"{len(devs)} are visible")
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis_name,))
 

@@ -223,9 +223,10 @@ def test_mesh_exchange_step_compiles_for_the_2x2_mesh(topo, monkeypatch):
     compact) over the four described chips, at the per-chip share of
     chip_smoke's 2M-row q3 stream side: the collective is in the compiled
     text, and so is the murmur3 Mosaic kernel — inside shard_map it needs
-    no partitioning. (A mesh SESSION hashes with XLA today, because its
-    other programs are partitioned automatically: `hashing._use_pallas`;
-    this exec is handed its mesh directly, so the kernel stays.)"""
+    no partitioning. (A mesh session keeps the kernel too, since the
+    exchange hands each partition on as arrays of its own chip; only with
+    the host exchange's ICI lane on does it hash with XLA:
+    `hashing._use_pallas`.)"""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from spark_rapids_tpu.columnar.batch import ColumnarBatch
     from spark_rapids_tpu.exec.basic import InMemoryScanExec

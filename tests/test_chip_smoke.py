@@ -180,22 +180,32 @@ def test_backend_failure_reaches_the_hash_tier_choice(monkeypatch):
 
 
 def test_mesh_session_hashes_with_xla_not_mosaic(monkeypatch):
-    """Found by the four-chip run of PR 23: a mesh session's per-partition
-    programs take shards of mesh-sharded arrays, XLA partitions them, and
-    Mosaic kernels cannot be partitioned automatically. The tier choice
-    reads the active mesh; one chip (no mesh) keeps the Pallas kernels."""
+    """Found by a four-chip run: programs that take shards of
+    mesh-sharded arrays are partitioned by XLA, and Mosaic kernels cannot
+    be partitioned automatically. The mesh exchange hands each partition
+    on as arrays of its own chip, so a mesh session keeps the Pallas
+    kernels; the host exchange's ICI lane still hands on mesh shards, so a
+    mesh session with that lane on hashes with XLA."""
+    from spark_rapids_tpu.config import RapidsConf, set_active_conf
     from spark_rapids_tpu.ops import hashing, pallas_kernels
     from spark_rapids_tpu.parallel.mesh import device_mesh, set_active_mesh
     monkeypatch.setattr(pallas_kernels, "on_tpu", lambda: True)
     try:
+        set_active_conf(RapidsConf({}))
         set_active_mesh(None)
         assert hashing._use_pallas()
         set_active_mesh(device_mesh(1))
         assert hashing._use_pallas()
         set_active_mesh(device_mesh(4))
+        assert hashing._use_pallas()
+        set_active_conf(RapidsConf(
+            {"spark.rapids.tpu.shuffle.ici.enabled": "true"}))
         assert not hashing._use_pallas()
+        set_active_mesh(device_mesh(1))
+        assert hashing._use_pallas()
     finally:
         set_active_mesh(None)
+        set_active_conf(RapidsConf({}))
 
 
 def test_bench_probe_requires_a_tpu():
