@@ -25,11 +25,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..columnar.batch import ColumnarBatch, committed_device, empty_batch
-from ..columnar.column import (Column, StringColumn, StructColumn,
-                               bucket_capacity)
+from ..columnar.column import (MIN_BUCKET, Column, StringColumn,
+                               StructColumn, bucket_capacity)
 from ..expr.core import Expression
 from ..ops.basic import active_mask
-from ..ops.strings import string_lengths
+from ..ops.strings import _placed, string_lengths
 from ..parallel.exchange import (exchange_columns, negotiate_slot_cap,
                                  partition_ids)
 from ..parallel.mesh import DATA_AXIS, active_mesh, mesh_axis_size
@@ -75,6 +75,32 @@ def _head(col, cap: int):
         return type(col)(tuple(_head(k, cap) for k in col.children),
                          col.validity[:cap], col.dtype)
     return Column(col.data[:cap], col.validity[:cap], col.dtype)
+
+
+def _rows_at(col, start, rows, cap: int):
+    """`cap` rows of a column from traced row `start` on, the first `rows`
+    of them live: lanes sliced at the offset (the caller keeps
+    `start + cap` inside the column, so no slice is clamped); a string
+    column's live bytes one block move to 0 in its source's byte bucket,
+    zeros past them, its offsets shifted by as much and constant past the
+    live rows."""
+    live = jnp.arange(cap, dtype=jnp.int32) < rows
+    valid = jax.lax.dynamic_slice_in_dim(col.validity, start, cap) & live
+    if isinstance(col, StringColumn):
+        offs = jax.lax.dynamic_slice_in_dim(col.offsets, start, cap + 1)
+        base, end = offs[0], offs[rows]
+        offs = jnp.where(jnp.arange(cap + 1, dtype=jnp.int32) <= rows,
+                         offs, end) - base
+        size = col.byte_capacity
+        pos = jnp.arange(size, dtype=jnp.int32)
+        data = jnp.where(pos < end - base, _placed(col.data, -base, size),
+                         jnp.uint8(0))
+        return StringColumn(data, offs, valid, col.dtype)
+    if isinstance(col, StructColumn):
+        return type(col)(tuple(_rows_at(k, start, rows, cap)
+                               for k in col.children), valid, col.dtype)
+    return Column(jax.lax.dynamic_slice_in_dim(col.data, start, cap), valid,
+                  col.dtype)
 
 
 def _unstack_shard(shard, cap: int):
@@ -146,14 +172,17 @@ def _host_key_array(col, n: int, idx=None):
 
 
 #: what the mesh exchanges moved, process-cumulative (`counters()`): rounds
-#: run; rows in; rows whose partition is not the chip they came from, and
-#: the bytes of their lanes in the all-to-all; summed over exchange
-#: executions, each one's largest partition in rows and its partition count;
-#: and what consumers that merge partitions moved to one chip
-#: (`onto_one_chip`): batches, their row slots and their bytes
+#: run; rows in; rows whose partition is not the chip they entered the
+#: all-to-all from, and the bytes of their lanes in the all-to-all; summed
+#: over exchange executions, each one's largest partition in rows and its
+#: partition count; what consumers that merge partitions moved to one chip
+#: (`onto_one_chip`): batches, their row slots and their bytes; and the
+#: rounds dealt out by rows before the all-to-all (`spread_rounds`), the
+#: rows they put on a chip other than their source and their lane bytes
 _COUNTERS = {"rounds": 0, "rows_in": 0, "rows_off_chip": 0,
              "bytes_off_chip": 0, "max_partition_rows": 0, "partitions": 0,
-             "batches_moved": 0, "rows_moved": 0, "bytes_moved": 0}
+             "batches_moved": 0, "rows_moved": 0, "bytes_moved": 0,
+             "spread_rounds": 0, "rows_spread": 0, "bytes_spread": 0}
 
 
 def counters() -> dict:
@@ -224,7 +253,9 @@ class ShuffleExchangeExec(TpuExec):
 
     With no active mesh (or a 1-device mesh) the exchange is the identity —
     the single-partition plan needs no data movement. Otherwise each round
-    runs ONE measure program and ONE all-to-all step over the mesh, and
+    runs ONE measure program and ONE all-to-all step over the mesh (a round
+    whose input sits on one chip is first dealt out by rows over the
+    chips, `_round_inputs`), and
     partition i comes out as arrays on mesh device i alone (its shards of
     the step's output), so every program downstream runs on that one chip.
     The flat stream yields each partition's staged PIECES in partition
@@ -248,13 +279,15 @@ class ShuffleExchangeExec(TpuExec):
         self._jit_unstack = self._site(_unstack_shard,
                                        label="ShuffleExchangeExec.unstack",
                                        static_argnums=(1,))
-        self._jit_measure = None
+        self._jit_measure = self._jit_spread = None
         if self.n_partitions > 1:
             from ..parallel.mesh import shard_map_compat
             self._jit_measure = self._site(shard_map_compat(
                 self._measure_kernel, mesh=self._mesh,
                 in_specs=P(DATA_AXIS), out_specs=P(DATA_AXIS)),
                 label="ShuffleExchangeExec.measure")
+            self._jit_spread = self._site(self._spread_kernel,
+                                          label="ShuffleExchangeExec.spread")
 
     @property
     def output_schema(self) -> Schema:
@@ -314,6 +347,25 @@ class ShuffleExchangeExec(TpuExec):
                                       jnp.max(jnp.where(act, lens, 0)))
         return counts[None], max_len[None]
 
+    def _spread_kernel(self, batch: ColumnarBatch):
+        """On the chip the batch sits on: its n pieces of `capacity // n`
+        rows, each with the leading axis of a piece of the mesh programs'
+        input. Active rows are a prefix: with q, r = divmod(rows, n),
+        piece d holds q + (d < r) rows from d * q + min(d, r) on, so the
+        pieces differ by at most one row and no count is read to the host.
+        With n dividing the capacity, every slice lies inside the batch."""
+        n = self.n_partitions
+        assert batch.capacity % n == 0, (batch.capacity, n)
+        cap = batch.capacity // n
+        q, r = batch.num_rows // n, batch.num_rows % n
+        pieces = []
+        for d in range(n):
+            rows = q + (d < r).astype(q.dtype)
+            start = d * q + jnp.minimum(d, r)
+            cols = [_rows_at(c, start, rows, cap) for c in batch.columns]
+            pieces.append(_expand0(ColumnarBatch(cols, rows, batch.schema)))
+        return pieces
+
     def _get_step(self, cap: int, slot_cap: int, width: int):
         key = (cap, slot_cap, width)
         step = self._steps.get(key)
@@ -340,20 +392,38 @@ class ShuffleExchangeExec(TpuExec):
         return step
 
     def _round_inputs(self, batches: List[ColumnarBatch]):
-        """The round's batches grouped one group a device: each by the
-        chip it sits on where they sit on several (a partitioned
-        producer's output stays where it is), else round-robin (ingest
-        lands on one chip). A device without a batch gets an empty one,
-        made on that device."""
+        """The round's input as one piece a device of the mesh, each with
+        a leading axis of 1 on the chip it sits on, and their row bucket;
+        with the mesh index of the chip a spread round's rows sat on (-1
+        for a chip outside the mesh), None where the round was not spread.
+
+        Batches on several chips of the mesh are grouped by the chip they
+        sit on (a partitioned producer's output stays where it is).
+        Batches on one chip, or on none (ingest lands on one), are
+        concatenated there and dealt out BY ROWS in one program (`spread`)
+        where each chip's piece holds at least a smallest bucket; below
+        that floor the one batch is device 0's piece. Batches on several
+        chips not all of the mesh are dealt out round-robin. A device
+        without a batch gets an empty one, made on that device."""
+        from ..parallel.distributed import align_batches
         n = self.n_partitions
         devices = list(self._mesh.devices.flat)
+        schema = self.output_schema
         where = [committed_device(b) for b in batches]
-        if all(w in devices for w in where) and len(set(where)) > 1:
+        if len({w for w in where if w is not None}) <= 1:
+            one = batches[0] if len(batches) == 1 \
+                else concat_batches(batches, schema)
+            if one.capacity % n == 0 and one.capacity // n >= MIN_BUCKET:
+                at = next(iter(jax.tree_util.tree_leaves(one.columns)[0]
+                               .devices()))
+                source = devices.index(at) if at in devices else -1
+                return self._jit_spread(one), one.capacity // n, source
+            groups = [[one]] + [[] for _ in range(n - 1)]
+        elif all(w in devices for w in where):
             groups = [[b for b, w in zip(batches, where) if w == d]
                       for d in devices]
         else:
             groups = [batches[d::n] for d in range(n)]
-        schema = self.output_schema
         per_dev = []
         for d, g in enumerate(groups):
             if not g:
@@ -363,22 +433,21 @@ class ShuffleExchangeExec(TpuExec):
                 per_dev.append(g[0])
             else:
                 per_dev.append(concat_batches(g, schema))
-        return per_dev
+        per_dev = align_batches(per_dev)
+        return ([self._jit_stack(b) for b in per_dev], per_dev[0].capacity,
+                None)
 
     def _exchange_round(self, batches: List[ColumnarBatch]):
         """One SPMD exchange over a bounded group of input batches;
         returns the n received partitions, partition i on mesh device i
         alone, and the round's rows per partition."""
         import numpy as np
-        from ..parallel.distributed import (align_batches, device_shards,
-                                            place_on_mesh)
+        from ..parallel.distributed import device_shards, place_on_mesh
         n = self.n_partitions
         schema = self.output_schema
         with op_span("exchange.mesh_round", phase="ici-collective"):
-            per_dev = align_batches(self._round_inputs(batches))
-            cap = per_dev[0].capacity
-            stacked = place_on_mesh([self._jit_stack(b) for b in per_dev],
-                                    self._mesh)
+            pieces, cap, source = self._round_inputs(batches)
+            stacked = place_on_mesh(pieces, self._mesh)
             counts, max_len = host_read("exchange.round",
                                         self._jit_measure(stacked))
             # one host sync per ROUND: size the receive buffer to the
@@ -402,12 +471,19 @@ class ShuffleExchangeExec(TpuExec):
                       for i, s in enumerate(
                           self._jit_unstack(t, part_cap)
                           for t in device_shards(out, self._mesh))]
-        off_chip = int(totals.sum() - np.trace(counts))
+        # counts[d] are the rows that entered the all-to-all from chip d
+        rows = int(totals.sum())
+        row_bytes = _lane_bytes_per_row(batches[0], width)
+        off_chip = rows - int(np.trace(counts))
         _COUNTERS["rounds"] += 1
-        _COUNTERS["rows_in"] += int(totals.sum())
+        _COUNTERS["rows_in"] += rows
         _COUNTERS["rows_off_chip"] += off_chip
-        _COUNTERS["bytes_off_chip"] += off_chip * _lane_bytes_per_row(
-            per_dev[0], width)
+        _COUNTERS["bytes_off_chip"] += off_chip * row_bytes
+        if source is not None:
+            spread = rows - (int(counts[source].sum()) if source >= 0 else 0)
+            _COUNTERS["spread_rounds"] += 1
+            _COUNTERS["rows_spread"] += spread
+            _COUNTERS["bytes_spread"] += spread * row_bytes
         return shards, totals
 
     # -- drive -------------------------------------------------------------

@@ -23,6 +23,18 @@ needs_8 = pytest.mark.skipif(len(jax.devices()) < 8,
                              reason="needs 8 virtual devices")
 
 
+@pytest.fixture(autouse=True)
+def _drop_compiled_programs():
+    """Each case compiles its programs for eight devices, and the file's
+    compiled CPU programs together outgrow one process's memory maps
+    (`vm.max_map_count`, 65,530: the whole file reached ~63,600 without
+    this): drop them between cases."""
+    yield
+    from spark_rapids_tpu.obs import dispatch
+    dispatch.reset_dispatch_ledger()
+    jax.clear_caches()
+
+
 def _sorted(rows):
     return sorted(rows, key=lambda r: tuple((x is None, x) for x in r))
 
