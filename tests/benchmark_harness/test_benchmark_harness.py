@@ -22,6 +22,7 @@ from benchmarks.lib import harness, peaks, trace, window  # noqa: E402
 from benchmarks.lib.manifest import (Manifest, ManifestError,  # noqa: E402
                                      apply_rehearsal)
 from benchmarks.tools import spread_readings  # noqa: E402
+from appending import NEXT_CELL, appended_copy  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 DOC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -342,17 +343,29 @@ def test_trace_fed_readers_on_the_recorded_chip_trace(tmp_path, metric, want):
 
 # -- the work models -----------------------------------------------------------
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_work_model_counts_the_querys_bytes_from_the_generated_rows(cell):
-    m = Manifest()
+def work_model_counts_the_generated_rows(m, cell):
+    """The cell's configuration at its rehearsal size: every table with a
+    `<table>_rows` entry in `scale` has that many rows in every column,
+    whatever its columns are, and every entry of the work model is
+    memory-bound bytes. Returns the configuration, tables and work model."""
     cfg = apply_rehearsal(m.config(m.cell(cell)["config"]))
     ref = m.config_module(cfg, "reference")
     tables = ref.generate(7, cfg)
     work = ref.work_model(cfg, tables)
     assert all(w["bound"] == "memory" and w["bytes"] > 0 for w in work.values())
-    rows = cfg["scale"]["lineitem_rows"]
-    assert len(tables["lineitem"]["l_shipdate"]) == rows
+    counted = [t for t in tables if f"{t}_rows" in cfg["scale"]]
+    assert counted, cfg["scale"]
+    for t in counted:
+        assert {len(c) for c in tables[t].values()} == \
+            {cfg["scale"][f"{t}_rows"]}, t
+    return cfg, tables, work
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_work_model_counts_the_querys_bytes_from_the_generated_rows(cell):
+    cfg, tables, work = work_model_counts_the_generated_rows(Manifest(), cell)
     if cfg["name"] == "tpch-q6":
+        rows = cfg["scale"]["lineitem_rows"]
         assert work["agg_stage"]["bytes"] == rows * (3 * 8 + 4) + 8
 
 
@@ -433,10 +446,11 @@ def test_a_sound_run_is_correct_and_prints_the_contracts_line(cell):
     json.loads(json.dumps(res))                  # the line is plain JSON
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_a_traced_run_reports_the_cells_layer_metrics(cell):
-    res = _rehearse(cell, trace=True)
-    m = Manifest()
+def traced_run_reports_the_layer_metrics(m, cell):
+    """A traced rehearsal of `cell` under the manifest `m` reads every
+    per-layer metric the cell is listed for, and no other. Returns the
+    result line."""
+    res = _rehearse(cell, trace=True, manifest=m)
     # on the CPU there is no device plane and no memory peak: the readers
     # fed by the trace or the allocator stay silent
     silent = {x["name"] for x in m.metrics_of(cell, "per_layer")
@@ -448,6 +462,12 @@ def test_a_traced_run_reports_the_cells_layer_metrics(cell):
     assert res["metrics"]["ingest_dispatches"]["value"] > 0
     assert res["metrics"]["stage_dispatches"]["value"] > 0
     assert res["correct"] is True
+    return res
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_traced_run_reports_the_cells_layer_metrics(cell):
+    traced_run_reports_the_layer_metrics(Manifest(), cell)
 
 
 def _break_collect(monkeypatch, fault):
@@ -530,72 +550,17 @@ def test_the_float32_control_in_the_programs_place_is_not_correct(
 
 # -- later PRs add files and entries, and edit nothing -------------------------
 
-def appended_copy(tmp_path):
-    """What the next `model_config` PR does, on a copy: a configuration, a
-    traffic mix, a cell and a per-layer metric as NEW files, their entries
-    appended to `BENCHMARK.json`, and the cell appended to the list of every
-    metric all accepted cells report and of `groupby_ms`, `sort_ms` and
-    `join_busy_share` (the next cell has a join, a group-by and a sort).
-    Returns the copy's manifest and the files it started from."""
-    bench = tmp_path / "benchmarks"
-    shutil.copytree(ROOT / "benchmarks", bench,
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
-
-    newcfg = bench / "configs" / "tpch-q6-other"
-    shutil.copytree(bench / "configs" / "tpch-q6", newcfg)
-    cfg = json.loads((newcfg / "config.json").read_text())
-    cfg["name"] = "tpch-q6-other"
-    cfg["rehearse"]["scale"]["lineitem_rows"] = 23000
-    (newcfg / "config.json").write_text(json.dumps(cfg))
-    (bench / "traffic" / "closed1b.json").write_text(json.dumps(
-        {"name": "closed1b", "loop": "closed", "clients": 1}))
-    (bench / "workloads" / "q6_other.json").write_text(json.dumps(
-        {"name": "q6_other", "config": "tpch-q6-other",
-         "traffic": "closed1b", "chips": 1, "trace_queries": 3}))
-    (bench / "layer_metrics" / "all_dispatches.py").write_text(
-        "def read(obs):\n"
-        "    return obs.dispatches(lambda label: True) / obs.queries\n")
-
-    doc = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cells = {w["name"] for w in doc["workloads"]}
-    for m in doc["per_layer"]:
-        if set(m["workloads"]) == cells or \
-                m["name"] in ("groupby_ms", "sort_ms", "join_busy_share"):
-            m["workloads"].append("q6_other")
-    doc["configs"].append({"name": "tpch-q6-other", "source": cfg["source"],
-                           "file": "benchmarks/configs/tpch-q6-other/config.json",
-                           "reduced": list(cfg["reduced"]), "why": "test"})
-    doc["workloads"].append({"name": "q6_other", "config": "tpch-q6-other",
-                             "traffic": "closed1b", "chips": 1, "why": "test"})
-    doc["per_layer"].append({"name": "all_dispatches", "unit": "count/query",
-                             "better": "lower", "source": "program_counter",
-                             "layer": "fused stages", "moves": "query_s",
-                             "workloads": ["q6_other"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
-    return Manifest(root=str(tmp_path), bench=str(bench)), before
-
-
 def test_a_cell_config_traffic_and_metric_added_as_new_files_only(tmp_path):
+    """The next PR's cell, appended to every list a Q3-shaped cell reports,
+    is held to what every accepted cell is held to."""
     own, before = appended_copy(tmp_path)
-    res = harness.run_cell("q6_other", 11, 0.5, True, require_tpu=False,
-                           rehearse=True, manifest=own)
-    assert res["correct"] is True
-    assert res["attempted"] == 3                  # the new cell's own trace_queries
-    got = res["metrics"]
-    # every list the cell was appended to whose reader finds something on the
-    # CPU (no device plane, no allocator peak) and in Q6 (no join; a phase of
-    # a group-by or a sort only where one ran in this process before)
-    listed = {m["name"]: m for m in own.metrics_of("q6_other", "per_layer")}
-    assert {"all_dispatches", "ingest_dispatches", "direct_pack_share",
-            "groupby_ms", "sort_ms", "join_busy_share"} <= set(listed)
-    silent = {n for n, m in listed.items()
-              if m["source"] == "device_trace"} | {"hbm_peak_gib"}
-    assert set(listed) - silent - {"groupby_ms", "sort_ms"} <= set(got) \
-        <= set(listed) - silent
+    listed = {m["name"] for m in own.metrics_of(NEXT_CELL, "per_layer")}
+    assert {"all_dispatches", "plan_reruns", "topn_busy_share",
+            "groupby_fallbacks", "join_build_ms"} <= listed
+    got = traced_run_reports_the_layer_metrics(own, NEXT_CELL)["metrics"]
     assert got["all_dispatches"]["value"] == pytest.approx(
         got["ingest_dispatches"]["value"] + got["stage_dispatches"]["value"])
-    assert got["direct_pack_share"] == {"value": 100.0, "unit": "%"}
+    assert 0 < got["direct_pack_share"]["value"] < 100  # the segment strings
     assert {p: p.read_bytes() for p in before} == before  # nothing edited
 
 

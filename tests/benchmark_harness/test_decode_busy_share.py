@@ -48,7 +48,7 @@ def read():
     return Manifest().reader("decode_busy_share")
 
 
-def manifest_reports_it_from_the_q14_cell_on(doc):
+def test_the_manifest_reports_it_from_the_q14_cell_on(doc):
     """The entry as PR 32 wrote it, wherever it stands; the cell it was added
     for comes first in its list, and later cells with a dictionary decode
     follow (`q1_groupby_string_keys` since PR 37)."""
@@ -58,10 +58,6 @@ def manifest_reports_it_from_the_q14_cell_on(doc):
                      "better": "lower", "source": "device_trace",
                      "layer": "kernels", "moves": "query_s"}
     assert cells[0] == CELL and "q6_scan_filter_sum" not in cells
-
-
-def test_the_manifest_reports_it_from_the_q14_cell_on():
-    manifest_reports_it_from_the_q14_cell_on(Manifest().doc)
 
 
 @pytest.mark.parametrize("module_s, want", [
@@ -118,4 +114,3 @@ def test_the_decode_reaches_the_ledger_under_the_labels_it_reads():
     served = {label for module in ("jit__decode", "jit__decoded_bytes")
               for label in dispatch.module_labels().get(module, ())}
     assert served == DECODE_LABELS
-

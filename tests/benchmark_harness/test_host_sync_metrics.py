@@ -71,10 +71,9 @@ def test_a_snapshot_carries_the_sync_counter_and_phase_and_delta_reads_them():
 
 
 @pytest.mark.parametrize("metric", NEW)
-def test_the_entries_are_appended_for_every_accepted_cell(metric):
-    doc = Manifest().doc
+def test_the_entries_are_appended_for_every_accepted_cell(doc, metric):
     names = [e["name"] for e in doc["per_layer"]]
-    # after the last metric accepted before PR 40, in their order
+    # after the last metric accepted before the two, in their order
     at = names.index("topn_busy_share") + 1
     assert names[at:at + 2] == list(NEW)
     entry = doc["per_layer"][names.index(metric)]

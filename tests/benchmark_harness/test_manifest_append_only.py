@@ -3,10 +3,13 @@
 `BENCHMARK.json`, unchanged but for cells appended to its `workloads`, and in
 its order, at the head of its list. `accepted_manifest.json` beside this
 file is what was accepted; only a `benchmark` PR rewrites it, a PR that
-appends never needs to. The second half keeps the harness OPEN: on a copy
-with the next PR's configuration, cell and metric appended, the rule and
-every manifest assertion of the other test files hold."""
+appends never needs to. The second half keeps the harness OPEN: every test
+of a cell's entries runs, through the `doc` fixture, on a copy with the next
+PR's configuration, cell and metric appended too; here the copy is held to
+the rule and the contract, and a test file that reads the manifest's lists
+around `doc` is refused."""
 
+import ast
 import copy
 import json
 import sys
@@ -21,9 +24,9 @@ for p in (str(ROOT), str(HERE)):
         sys.path.insert(0, p)
 
 import test_benchmark_harness as the_harness  # noqa: E402
-import test_decode_busy_share as the_decode  # noqa: E402
-import test_tpch_q1 as the_q1  # noqa: E402
-from benchmarks.lib.manifest import LISTS, append_only  # noqa: E402
+from appending import NEXT_CELL  # noqa: E402
+from benchmarks.lib.manifest import (LISTS, Manifest,  # noqa: E402
+                                     append_only)
 
 ACCEPTED = json.loads((HERE / "accepted_manifest.json").read_text())
 DOC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -113,7 +116,7 @@ FORBIDDEN = [
     ("inserted_in_per_layer", _insert("per_layer", 25),
      "per_layer: ['pushed_in'] stand before the accepted entry"),
     ("inserted_in_configs", _insert("configs"),
-     "configs: ['pushed_in'] stand before the accepted entry 'tpch-q1'"),
+     "configs: ['pushed_in'] stand before the accepted entry 'tpch-q3-x4'"),
     ("inserted_in_workloads", _insert("workloads", 0),
      "workloads: ['pushed_in'] stand before the accepted entry"),
     ("inserted_in_end_to_end", _insert("end_to_end"),
@@ -167,41 +170,82 @@ def test_an_edit_of_what_was_accepted_is_named_wrong(edit, named):
 
 # -- the harness stays open: the next PR's copy passes everything ---------------
 
-@pytest.fixture(scope="module")
-def next_pr(tmp_path_factory):
-    """`BENCHMARK.json` as the next `model_config` PR would leave it, with
-    its new files, in a root of its own."""
-    root = tmp_path_factory.mktemp("next_pr")
-    own, _ = the_harness.appended_copy(root)
-    appended = [m["name"] for m in own.doc["per_layer"]
-                if "q6_other" in m["workloads"]]
+def test_the_next_prs_copy_appends_its_cell_where_a_q3_shaped_cell_reports(
+        next_pr):
+    appended = [m["name"] for m in next_pr.doc["per_layer"]
+                if NEXT_CELL in m["workloads"]]
     assert len(appended) >= 20 and appended[-1] == "all_dispatches"
-    return own.doc, root
+    assert {"plan_reruns", "topn_busy_share",
+            "groupby_fallbacks"} <= set(appended)
 
 
-def _rule(doc, root):
-    assert append_only(doc, ACCEPTED) == []
+def _rule(own):
+    assert append_only(own.doc, ACCEPTED) == []
 
 
-def _contract(doc, root):
-    the_harness.manifest_within_the_contract(doc, root)
+def _contract(own):
+    the_harness.manifest_within_the_contract(own.doc, own.root)
 
 
-def _decode(doc, root):
-    the_decode.manifest_reports_it_from_the_q14_cell_on(doc)
-
-
-def _q1_cell(doc, root):
-    the_q1.manifest_names_the_cell_and_its_configuration(doc)
-
-
-def _q1_lists(doc, root):
-    for metric in the_q1.REPORTS + the_q1.ITS_OWN + the_q1.NOT_THE_CELLS:
-        the_q1.manifest_lists_the_cell(doc, metric)
-
-
-@pytest.mark.parametrize("holds", [_rule, _contract, _decode, _q1_cell,
-                                   _q1_lists])
+@pytest.mark.parametrize("holds", [_rule, _contract])
 def test_the_next_prs_manifest_passes_what_the_real_one_passes(next_pr, holds):
-    """The same functions each file runs on the real manifest."""
-    holds(*next_pr)
+    """What every test of a cell's entries asserts runs on the copy through
+    the `doc` fixture; these two take the copy's root as well."""
+    holds(next_pr)
+
+
+#: the files whose subject is the manifest as a whole, its contract and what
+#: was accepted; every other test file reads its lists through `doc`
+THE_MANIFESTS_OWN = {"test_benchmark_harness.py", Path(__file__).name}
+
+
+def _reads_around_doc(path):
+    """Where a test file reads `BENCHMARK.json` other than through the `doc`
+    fixture: the file named, a `Manifest`'s `.doc`, a module's `DOC`."""
+    return [f"{path.name}:{n.lineno}" for n in ast.walk(ast.parse(
+        path.read_text()))
+        if isinstance(n, ast.Constant) and n.value == "BENCHMARK.json"
+        or isinstance(n, ast.Attribute) and n.attr == "doc"
+        or isinstance(n, ast.Name) and n.id == "DOC"]
+
+
+def _takes_doc(path):
+    return any(isinstance(n, ast.FunctionDef) and n.name.startswith("test_")
+               and "doc" in [a.arg for a in n.args.args]
+               for n in ast.walk(ast.parse(path.read_text())))
+
+
+@pytest.mark.parametrize("path", [p for p in sorted(HERE.glob("test_*.py"))
+                                  if p.name not in THE_MANIFESTS_OWN],
+                         ids=lambda path: path.stem)
+def test_a_test_file_reads_the_manifests_lists_only_through_doc(path):
+    """So that no test can hold a list to its end, or the manifest to the
+    cells it has now, without failing on the next PR's copy."""
+    assert _reads_around_doc(path) == []
+
+
+@pytest.mark.parametrize("path", sorted(HERE.glob("test_tpch_*.py")),
+                         ids=lambda path: path.stem)
+def test_every_cells_test_file_checks_its_entries_on_the_copy(path):
+    assert _takes_doc(path)
+
+
+def test_the_guard_sees_a_list_read_around_doc(tmp_path):
+    pinned = tmp_path / "test_tpch_next.py"
+    pinned.write_text(
+        "def test_pinned():\n"
+        "    doc = Manifest().doc\n"
+        "    assert doc['workloads'][-1]['name'] == 'next'\n")
+    assert _reads_around_doc(pinned) == ["test_tpch_next.py:2"]
+    assert not _takes_doc(pinned)
+
+
+def test_the_next_prs_configuration_passes_the_work_model_check(next_pr):
+    """Its lineitem has no ship date, and its schema is no accepted
+    configuration's: the rows are counted from `scale`."""
+    cfg, tables, _ = the_harness.work_model_counts_the_generated_rows(
+        next_pr, NEXT_CELL)
+    assert "l_shipdate" not in tables["lineitem"]
+    real = Manifest()
+    assert all(cfg["schema"] != real.config(c["name"])["schema"]
+               for c in ACCEPTED["configs"])

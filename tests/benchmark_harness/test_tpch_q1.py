@@ -22,6 +22,7 @@ from benchmarks.lib.groupby_programs import (  # noqa: E402
     GROUPBY_LABELS, UPDATE_LABELS)
 from benchmarks.lib.manifest import (Manifest, apply_rehearsal,  # noqa: E402
                                      manifest_at)
+from appending import lists_the_cell  # noqa: E402
 
 CELL = "q1_groupby_string_keys"
 UPDATE, PRE, EVAL = "jit__traced", "jit__pre_project", "jit__evaluate"
@@ -63,7 +64,7 @@ NOT_THE_CELLS = ["agg_stage_roofline", "join_busy_share",
                  "join_sizing_dispatches"]
 
 
-def manifest_names_the_cell_and_its_configuration(doc):
+def test_the_manifest_names_the_cell_and_its_configuration(doc):
     cell = {w["name"]: w for w in doc["workloads"]}[CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("tpch-q1", "closed1", 1)
@@ -72,26 +73,9 @@ def manifest_names_the_cell_and_its_configuration(doc):
     assert "clause 2.4.1 Q1" in config["source"] and not config["reduced"]
 
 
-def manifest_lists_the_cell(doc, metric):
-    """A metric the cell reports lists it; one the cell brought lists it
-    FIRST; one it has nothing to read for does not list it."""
-    entry = {e["name"]: e for e in doc["per_layer"]}[metric]
-    if metric in NOT_THE_CELLS:
-        assert CELL not in entry["workloads"]
-    elif metric in ITS_OWN:
-        assert entry["workloads"][0] == CELL and entry["moves"] == "query_s"
-    else:
-        assert CELL in entry["workloads"]
-        assert entry["workloads"][0] != CELL        # an older cell's metric
-
-
-def test_the_manifest_names_the_cell_and_its_configuration():
-    manifest_names_the_cell_and_its_configuration(Manifest().doc)
-
-
 @pytest.mark.parametrize("metric", REPORTS + ITS_OWN + NOT_THE_CELLS)
-def test_the_cell_is_appended_to_the_lists_of_the_metrics_it_reports(metric):
-    manifest_lists_the_cell(Manifest().doc, metric)
+def test_the_cell_is_appended_to_the_lists_of_the_metrics_it_reports(doc, metric):
+    lists_the_cell(doc, CELL, metric, ITS_OWN, NOT_THE_CELLS)
 
 
 # -- the generator -------------------------------------------------------------

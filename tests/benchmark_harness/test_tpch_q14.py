@@ -19,6 +19,7 @@ if str(ROOT) not in sys.path:
 from benchmarks.lib import datagen, dbgen, dbgen_part, peaks, trace  # noqa: E402
 from benchmarks.lib.manifest import (Manifest, apply_rehearsal,  # noqa: E402
                                      manifest_at)
+from appending import lists_the_cell  # noqa: E402
 
 CELL = "q14_join_like_ratio"
 PROBE, DECODE, CONCAT = "jit__ja_spec_body", "jit__decode", "jit__concat_pair"
@@ -33,6 +34,42 @@ def config():
     m = Manifest()
     cfg = apply_rehearsal(m.config(m.cell(CELL)["config"]))
     return cfg, m.config_module(cfg, "reference"), m.config_module(cfg, "query")
+
+
+# -- the manifest --------------------------------------------------------------
+
+# The per-layer metrics by what this cell has to do with them. Nothing here
+# says where a list ends: a later cell is appended after this one
+# (`test_manifest_append_only.py` holds what was accepted in its place).
+REPORTS = ["ingest_dispatches", "ingest_stall_ms", "concat_busy_share",
+           "stage_dispatches", "compile_s", "window_compiles",
+           "query_hbm_share", "device_idle_share", "hbm_peak_gib",
+           "plan_span_ms", "scan_decode_ms", "upload_ms", "device_wait_ms",
+           "ingest_busy_share", "labelled_busy_share", "direct_pack_share",
+           "host_syncs", "host_sync_ms"]
+ITS_OWN = ["join_busy_share", "join_probe_roofline", "join_build_ms",
+           "join_sizing_dispatches", "decode_busy_share"]
+# no group-by, sort, top-N or exchange runs in Q14; the other cells' kernels
+NOT_THE_CELLS = ["agg_stage_roofline", "groupby_busy_share",
+                 "groupby_update_roofline", "groupby_ms", "sort_ms",
+                 "groupby_fallbacks", "groupby_lane_share",
+                 "groupby_mask_share", "join_groupby_roofline",
+                 "topn_busy_share", "exchange_ms", "exchange_busy_share",
+                 "exchange_roofline", "exchange_spread_share"]
+
+
+def test_the_manifest_names_the_cell_and_its_configuration(doc):
+    cell = {w["name"]: w for w in doc["workloads"]}[CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("tpch-q14", "closed1", 1)
+    config = {c["name"]: c for c in doc["configs"]}["tpch-q14"]
+    assert config["file"] == "benchmarks/configs/tpch-q14/config.json"
+    assert "clause 2.4.14 Q14" in config["source"] and not config["reduced"]
+
+
+@pytest.mark.parametrize("metric", REPORTS + ITS_OWN + NOT_THE_CELLS)
+def test_the_cell_is_on_the_list_of_every_metric_it_reports(doc, metric):
+    lists_the_cell(doc, CELL, metric, ITS_OWN, NOT_THE_CELLS)
 
 
 # -- the generator -------------------------------------------------------------

@@ -23,6 +23,7 @@ from benchmarks.lib.manifest import (Manifest, apply_rehearsal,  # noqa: E402
                                      manifest_at)
 from benchmarks.lib.q3_programs import (JOIN_GROUPBY_LABELS,  # noqa: E402
                                         TOPN_LABELS)
+from appending import lists_the_cell  # noqa: E402
 
 CELL = "q3_join_groupby_topn"
 
@@ -52,8 +53,7 @@ NOT_THE_CELLS = ["agg_stage_roofline", "join_probe_roofline",
                  "groupby_update_roofline", "groupby_lane_share"]
 
 
-def test_the_manifest_names_the_cell_and_its_configuration():
-    doc = Manifest().doc
+def test_the_manifest_names_the_cell_and_its_configuration(doc):
     cell = {w["name"]: w for w in doc["workloads"]}[CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("tpch-q3", "closed1", 1)
@@ -63,15 +63,8 @@ def test_the_manifest_names_the_cell_and_its_configuration():
 
 
 @pytest.mark.parametrize("metric", REPORTS + ITS_OWN + NOT_THE_CELLS)
-def test_the_cell_is_on_the_list_of_every_metric_it_reports(metric):
-    entry = {e["name"]: e for e in Manifest().doc["per_layer"]}[metric]
-    if metric in NOT_THE_CELLS:
-        assert CELL not in entry["workloads"]
-    elif metric in ITS_OWN:
-        assert entry["workloads"] == [CELL] and entry["moves"] == "query_s"
-    else:
-        assert CELL in entry["workloads"]
-        assert entry["workloads"][0] != CELL        # an older cell's metric
+def test_the_cell_is_on_the_list_of_every_metric_it_reports(doc, metric):
+    lists_the_cell(doc, CELL, metric, ITS_OWN, NOT_THE_CELLS)
 
 
 # -- the generator -------------------------------------------------------------

@@ -20,6 +20,7 @@ if str(ROOT) not in sys.path:
 from benchmarks.lib import datagen, peaks, trace  # noqa: E402
 from benchmarks.lib.exchange_programs import EXCHANGE_PREFIX  # noqa: E402
 from benchmarks.lib.manifest import Manifest, apply_rehearsal  # noqa: E402
+from appending import lists_the_cell  # noqa: E402
 
 CELL = "q3_join_x4"
 
@@ -68,26 +69,27 @@ REPORTS = ["ingest_dispatches", "ingest_stall_ms", "concat_busy_share",
            "plan_span_ms", "scan_decode_ms", "upload_ms", "device_wait_ms",
            "ingest_busy_share", "labelled_busy_share", "direct_pack_share",
            "host_syncs", "host_sync_ms", "join_busy_share", "join_build_ms",
-           "groupby_ms", "sort_ms", "groupby_fallbacks", "decode_busy_share"]
-ITS_OWN = ["exchange_ms", "exchange_busy_share", "exchange_roofline"]
+           "groupby_ms", "sort_ms", "groupby_fallbacks", "decode_busy_share",
+           # the speculation scope and the top-N run here as in the one-chip
+           # cell, whose metrics these are
+           "plan_reruns", "topn_busy_share"]
+ITS_OWN = ["exchange_ms", "exchange_busy_share", "exchange_roofline",
+           "exchange_spread_share"]
 NOT_THE_CELLS = ["agg_stage_roofline", "join_probe_roofline",
                  "join_sizing_dispatches", "groupby_busy_share",
                  "groupby_update_roofline", "groupby_lane_share",
                  "groupby_mask_share",
-                 # these three would read here too (the cell runs the
-                 # speculation scope, HashJoinExec's build/counts/probe and
-                 # the top-N), but `tests/benchmark_harness/test_tpch_q3.py`
-                 # (ITS_OWN) holds their lists to `q3_join_groupby_topn`: a
-                 # benchmark PR appends this cell to all three
-                 "plan_reruns", "join_groupby_roofline", "topn_busy_share"]
+                 # its programs run here, but the reader sets ONE chip's HBM
+                 # peak against the time of the MEAN chip, while the query's
+                 # bytes are spread over four (as `query_hbm_share` does): a
+                 # four-chip cell reads it once the reader counts the chips
+                 "join_groupby_roofline"]
 
 
-def test_the_manifest_names_the_cell_on_four_chips_and_its_configuration():
-    doc = Manifest().doc
+def test_the_manifest_names_the_cell_on_four_chips_and_its_configuration(doc):
     cell = {w["name"]: w for w in doc["workloads"]}[CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("tpch-q3-x4", "closed1", 4)
-    assert doc["workloads"][-1]["name"] == CELL
     config = {c["name"]: c for c in doc["configs"]}["tpch-q3-x4"]
     assert config["file"] == "benchmarks/configs/tpch-q3-x4/config.json"
     assert "clause 2.4.3 Q3" in config["source"] and not config["reduced"]
@@ -95,14 +97,8 @@ def test_the_manifest_names_the_cell_on_four_chips_and_its_configuration():
 
 
 @pytest.mark.parametrize("metric", REPORTS + ITS_OWN + NOT_THE_CELLS)
-def test_the_cell_is_on_the_list_of_every_metric_it_reports(metric):
-    entry = {e["name"]: e for e in Manifest().doc["per_layer"]}[metric]
-    if metric in NOT_THE_CELLS:
-        assert CELL not in entry["workloads"]
-    elif metric in ITS_OWN:
-        assert entry["workloads"] == [CELL] and entry["moves"] == "query_s"
-    else:
-        assert entry["workloads"][-1] == CELL
+def test_the_cell_is_on_the_list_of_every_metric_it_reports(doc, metric):
+    lists_the_cell(doc, CELL, metric, ITS_OWN, NOT_THE_CELLS)
 
 
 def test_the_configuration_is_tpch_q3_on_four_partitions(config):
